@@ -190,7 +190,11 @@ class VanillaExecutor(Executor):
         cfg, spec, tables = sess.hgnn_cfg, sess.spec, _lookup_tables(sess)
 
         def loss(bundle, arrs):
-            return hgnn_loss(cfg, bundle, tables, arrs, spec)
+            # the oracle is float32 throughout: without this a TPU runs f32
+            # matmuls in reduced precision, loosening every comparison
+            # against it
+            with jax.default_matmul_precision("highest"):
+                return hgnn_loss(cfg, bundle, tables, arrs, spec)
 
         return SimpleNamespace(
             to_arrays=batch_to_arrays,
@@ -303,9 +307,8 @@ class RafSimExecutor(Executor):
 @register("raf_spmd")
 class RafSpmdExecutor(Executor):
     def build_plan(self, sess):
-        import jax
-
         from repro.core import raf_spmd
+        from repro.launch.mesh import make_mesh
 
         run = sess.config.run
         assignment = sess.assignment
@@ -314,7 +317,7 @@ class RafSpmdExecutor(Executor):
             # (p % shards) — meta-locality is preserved (BranchAssignment.fold)
             assignment = assignment.fold(run.mesh_shape[1], sess.spec)
         plan = raf_spmd.build_plan(sess.spec, assignment, sess.hgnn_cfg, sess.feat_dims)
-        mesh = jax.make_mesh(run.mesh_shape, ("data", "model"))
+        mesh = make_mesh(run.mesh_shape, ("data", "model"))
         local_combine = sess.config.partition.placement == "meta"
         learn = (bool(sess.engine.learnable_types)
                  and sess.config.model.train_learnable)

@@ -45,7 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.compat import shard_map_nocheck
 from repro.core.hgnn import HGNNConfig, Params, rel_context
 from repro.core.raf import BranchAssignment
 from repro.core.relmod import SCOPE_CONTAINER, storage_key
@@ -64,7 +63,6 @@ __all__ = [
     "make_train_step",
     "make_grad_step",
     "make_apply_step",
-    "shard_map_nocheck",
 ]
 
 
@@ -504,7 +502,7 @@ def _build_loss_fn(
                 kernels,
             )
 
-        return shard_map_nocheck(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
@@ -513,6 +511,7 @@ def _build_loss_fn(
                 {k2: arr_specs[k2] for k2 in rest},
             ),
             out_specs=P(da, None),
+            check_vma=False,
         )(rel_stacks, feats, rest)
 
     def loss_fn(stacks, feats, rest):

@@ -90,9 +90,12 @@ SCOPE_CONTAINER = {
 
 
 def masked_mean(h: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
-    """h [..., f, d], mask [..., f] -> [..., d]; empty groups give zeros."""
+    """h [..., f, d], mask [..., f] -> [..., d]; empty groups give zeros.
+
+    A masked sum, not a contraction — the same formulation the fused
+    ``stacked_mean_linear`` kernel uses, so the two agree bit for bit."""
     w = mask.astype(h.dtype)
-    s = jnp.einsum("...fd,...f->...d", h, w)
+    s = jnp.sum(h * w[..., None], axis=-2)
     return s / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1.0)
 
 

@@ -648,11 +648,22 @@ def run_dp_fit(sess, steps: int, timeout_s: float = _TIMEOUT_S) -> Dict:
     Updates the session books (losses, step/host times, step position)
     exactly like the in-process fit, so ``results()``, ``evaluate()``
     and ``save()`` keep working afterwards."""
+    import jax
+
     from repro.api.session import HetaStageError
 
     cfg = sess.config
     sc = cfg.scale
     N = sc.num_trainers
+    if jax.default_backend() == "tpu":
+        # this process holds the chip; every spawned trainer would rebuild
+        # a session and wait for it.  One process drives every chip of a
+        # host: data parallelism there is the mesh's data axis
+        raise HetaStageError(
+            f"scale.num_trainers={N} spawns trainer processes that each need "
+            f"the accelerator, and a TPU belongs to one process; train "
+            f"data-parallel in this process over the mesh data axis instead "
+            f"(--mesh DATAxMODEL, run.mesh_shape) with --num-trainers 1")
     if getattr(sess.plan, "learn_feats", False) or (
             sc.mode == "local" and cfg.model.train_learnable):
         raise HetaStageError(

@@ -192,13 +192,13 @@ def measured_cost_us(op: str, n: int, f: int, d_in: int, d_out: int,
     if op == "stacked_mean_linear":
         h = jnp.asarray(r.standard_normal((rb, n, f, d_in)), jnp.float32)
         w = jnp.asarray(r.standard_normal((U, d_in, d_out)), jnp.float32)
-        b = jnp.zeros((U, d_out), jnp.float32)
+        b = jnp.zeros((U, 1, d_out), jnp.float32)
         hp = pad_axes(h, {1: bn, 3: bc})
         wp = pad_axes(w, {1: bc, 2: bo})
 
         def call():
             return stacked_mean_linear_pallas(
-                hp, pad_to(mask, 1, bn), wp, pad_to(b, 1, bo), u,
+                hp, pad_to(mask, 1, bn), wp, pad_to(b, 2, bo), u,
                 block_n=bn, block_out=bo, block_in=bc, interpret=interpret)
     elif op == "stacked_attn_epilogue":
         nh, dh = _heads_of(d_out)
@@ -217,13 +217,13 @@ def measured_cost_us(op: str, n: int, f: int, d_in: int, d_out: int,
                 interpret=interpret)
     elif op == "stacked_softmax_combine":
         nh, dh = _heads_of(d_out)
-        e = jnp.asarray(r.standard_normal((rb, n, f, nh)), jnp.float32)
+        e = jnp.asarray(r.standard_normal((rb, n, f, nh * dh)), jnp.float32)
         v = jnp.asarray(r.standard_normal((rb, n, f, nh * dh)), jnp.float32)
 
         def call():
             return stacked_softmax_combine_pallas(
                 pad_to(e, 1, bn), pad_to(mask, 1, bn), pad_to(v, 1, bn),
-                num_heads=nh, head_dim=dh, block_n=bn, interpret=interpret)
+                block_n=bn, interpret=interpret)
     else:
         raise ValueError(f"unknown autotune op {op!r}; ops: {OPS}")
 

@@ -36,16 +36,20 @@ def gather_rows_pallas(
     interpret: bool = True,
 ) -> jnp.ndarray:
     n = idx.shape[0]
-    d = table.shape[1]
+    num_rows, d = table.shape
+    # rows travel as [1, 1, d] blocks of a [rows, 1, d] view: a block's last
+    # two dims must be (8k, 128k) or the array's own, and (1, d) is the
+    # array's own only with the unit axis in the middle
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
-        in_specs=[pl.BlockSpec((1, d), lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i, idx_ref: (i, 0)),
+        in_specs=[pl.BlockSpec((1, 1, d), lambda i, idx_ref: (idx_ref[i], 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, d), lambda i, idx_ref: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), table.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), table)
+    )(idx.astype(jnp.int32), table.reshape(num_rows, 1, d))
+    return out.reshape(n, d)

@@ -34,15 +34,20 @@ Three kernels:
     neither the projected logits/values *nor* a gathered weight copy ever
     round-trips through HBM on the forward.  Optional per-slot
     ``[nh, dh, dh]`` transforms (HGT's ``w_att``/``w_msg``) apply in the
-    epilogue.  With ``with_residuals`` the pre-transform projections are
-    written out once for the backward.
+    epilogue, as block-diagonal ``[H, H]`` matrices.  With
+    ``with_residuals`` the pre-transform projections are written out once
+    for the backward.
   * :func:`stacked_attn_dh_pallas` — the backward w.r.t. the neighbor
     activations: ``dh = dz @ we[slot]ᵀ (+ dv @ wv[slot]ᵀ)``, weight blocks
     again read via scalar prefetch.
 
 All shapes arrive pre-padded to block multiples (``ops.py`` owns padding
 and slicing); fanout ``f`` stays whole — sampled fanouts are 3–25, so the
-reduction never crosses blocks.
+reduction never crosses blocks.  Inside the kernels every array keeps
+``H = nh·dh`` (or a d-chunk) on the lane axis: per-head quantities are
+*head-expanded* to all ``dh`` lanes of their head, and per-head sums are a
+matmul with a 0/1 head-sum matrix.  Mosaic lowers neither an einsum that
+keeps a batch dimension nor a reshape that splits the lane axis into heads.
 """
 
 from __future__ import annotations
@@ -78,9 +83,10 @@ def _mean_linear_kernel(u_ref, h_ref, m_ref, w_ref, b_ref, out_ref, acc_ref,
 
     h = h_ref[0]  # [bn, f, bc]
     m = m_ref[0].astype(h.dtype)  # [bn, f]
-    # identical formulation to relmod.masked_mean (operand order included),
-    # so the interpret-mode forward is bit-equal to the vmap oracle
-    s = jnp.einsum("nfd,nf->nd", h, m)
+    # identical formulation to relmod.masked_mean (a masked sum over the
+    # fanout, not a contraction: Mosaic lowers no dot whose rhs keeps a
+    # batch dim), so the interpret-mode forward is bit-equal to the oracle
+    s = jnp.sum(h * m[:, :, None], axis=1)
     cnt = jnp.maximum(jnp.sum(m, axis=-1, keepdims=True), 1.0)
     mean = s / cnt
     acc_ref[...] += jax.lax.dot(
@@ -90,7 +96,7 @@ def _mean_linear_kernel(u_ref, h_ref, m_ref, w_ref, b_ref, out_ref, acc_ref,
     @pl.when(c == n_chunks - 1)
     def _done():
         out_ref[0] = (
-            acc_ref[...] + b_ref[0].astype(jnp.float32)[None, :]
+            acc_ref[...] + b_ref[0].astype(jnp.float32)
         ).astype(out_ref.dtype)
 
 
@@ -101,7 +107,7 @@ def stacked_mean_linear_pallas(
     h: jnp.ndarray,  # [rb, n, f, d_in]   (n, d_in pre-padded to blocks)
     mask: jnp.ndarray,  # [rb, n, f]
     w: jnp.ndarray,  # [U, d_in, d_out]
-    b: jnp.ndarray,  # [U, d_out]
+    b: jnp.ndarray,  # [U, 1, d_out]  (unit axis: a (1, bo) block is tile-legal)
     slot_u: jnp.ndarray,  # [rb] int32 — slot -> stack row (scalar prefetch)
     block_n: int = 128,
     block_out: int = 128,
@@ -119,7 +125,7 @@ def stacked_mean_linear_pallas(
             pl.BlockSpec((1, bn, f, bc), lambda s, i, o, c, u: (s, i, 0, c)),
             pl.BlockSpec((1, bn, f), lambda s, i, o, c, u: (s, i, 0)),
             pl.BlockSpec((1, bc, bo), lambda s, i, o, c, u: (u[s], c, o)),
-            pl.BlockSpec((1, bo), lambda s, i, o, c, u: (u[s], o)),
+            pl.BlockSpec((1, 1, bo), lambda s, i, o, c, u: (u[s], 0, o)),
         ],
         out_specs=pl.BlockSpec((1, bn, bo), lambda s, i, o, c, u: (s, i, o)),
         scratch_shapes=[pltpu.VMEM((bn, bo), jnp.float32)],
@@ -203,47 +209,44 @@ def stacked_mean_linear_dh_pallas(
 # --------------------------------------------------------------------------
 
 
-def _softmax_combine_kernel(e_ref, m_ref, v_ref, out_ref, *, num_heads: int,
-                            head_dim: int):
-    e = e_ref[0]  # [bn, f, nh]
-    m = m_ref[0]  # [bn, f] bool
-    v = v_ref[0]  # [bn, f, nh*dh]
-    # identical numerics to relmod.masked_softmax
+def _masked_softmax_combine(e, m, v):
+    """Masked softmax over the fanout axis + weighted sum of ``v``.
+
+    ``e`` and ``v`` are ``[bn, f, H]`` with the logits *head-expanded*: lane
+    ``(h, d)`` of ``e`` holds head ``h``'s logit, so the softmax runs per
+    lane and the combine is an elementwise product — no ``[.., nh, dh]``
+    reshape, which Mosaic cannot lay out.  Numerics per lane are those of
+    ``relmod.masked_softmax``."""
+    mm = m.astype(e.dtype)[:, :, None]  # [bn, f, 1]; Mosaic reshapes no i1
     neg = jnp.asarray(jnp.finfo(e.dtype).min, e.dtype)
-    em = jnp.where(m[:, :, None], e, neg)
+    em = jnp.where(mm > 0, e, neg)
     em = em - jnp.max(em, axis=1, keepdims=True)
-    z = jnp.exp(em) * m[:, :, None].astype(e.dtype)
+    z = jnp.exp(em) * mm
     alpha = z / jnp.maximum(jnp.sum(z, axis=1, keepdims=True), 1e-9)
-    bn, f, nh = alpha.shape
-    ar = jnp.broadcast_to(
-        alpha[:, :, :, None], (bn, f, nh, head_dim)
-    ).reshape(bn, f, nh * head_dim)
-    out_ref[0] = jnp.sum(ar * v.astype(ar.dtype), axis=1).astype(out_ref.dtype)
+    return jnp.sum(alpha * v.astype(alpha.dtype), axis=1)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("num_heads", "head_dim", "block_n", "interpret")
-)
+def _softmax_combine_kernel(e_ref, m_ref, v_ref, out_ref):
+    out_ref[0] = _masked_softmax_combine(
+        e_ref[0], m_ref[0], v_ref[0]).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def stacked_softmax_combine_pallas(
-    e: jnp.ndarray,  # [rb, n, f, nh]
+    e: jnp.ndarray,  # [rb, n, f, nh*dh]  logits head-expanded to H lanes
     mask: jnp.ndarray,  # [rb, n, f]
     v: jnp.ndarray,  # [rb, n, f, nh*dh]
-    num_heads: int,
-    head_dim: int,
     block_n: int = 128,
     interpret: bool = True,
 ) -> jnp.ndarray:
-    rb, n, f, nh = e.shape
-    H = v.shape[3]
+    rb, n, f, H = v.shape
     bn = block_n
     grid = (rb, pl.cdiv(n, bn))
     return pl.pallas_call(
-        functools.partial(
-            _softmax_combine_kernel, num_heads=num_heads, head_dim=head_dim
-        ),
+        _softmax_combine_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bn, f, nh), lambda s, i: (s, i, 0, 0)),
+            pl.BlockSpec((1, bn, f, H), lambda s, i: (s, i, 0, 0)),
             pl.BlockSpec((1, bn, f), lambda s, i: (s, i, 0)),
             pl.BlockSpec((1, bn, f, H), lambda s, i: (s, i, 0, 0)),
         ],
@@ -256,6 +259,27 @@ def stacked_softmax_combine_pallas(
 # --------------------------------------------------------------------------
 # fully fused attention AGG_r: stack-streamed projections + softmax+combine
 # --------------------------------------------------------------------------
+
+
+_EPILOGUE_VMEM_LIMIT = 64 * 2**20
+
+
+def _lane_matmul(x, w):
+    """``[bn, f, H] @ [H, K]`` over the lane axis, float32 at full precision."""
+    bn, f, H = x.shape
+    y = jax.lax.dot(x.reshape(bn * f, H), w.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+    return y.reshape(bn, f, w.shape[1])
+
+
+def _head_sum_matrix(nh: int, dh: int):
+    """``[H, H]`` 0/1 matrix summing each head's ``dh`` lanes and writing the
+    sum back to all of them (``kron(I_nh, ones(dh, dh))``)."""
+    H = nh * dh
+    row = jax.lax.broadcasted_iota(jnp.int32, (H, H), 0) // dh
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, H), 1) // dh
+    return (row == col).astype(jnp.float32)
 
 
 def _attn_epilogue_kernel(u_ref, *refs, n_chunks, num_heads, head_dim, scale,
@@ -298,29 +322,20 @@ def _attn_epilogue_kernel(u_ref, *refs, n_chunks, num_heads, head_dim, scale,
     def _done():
         z0 = acc_z[...]  # [bn, f, nh*dh] float32
         v0 = z0 if acc_v is None else acc_v[...]
-        z4 = z0.reshape(bn, f, nh, dh)
-        v4 = v0.reshape(bn, f, nh, dh)
         if has_post:
-            zt = jnp.einsum("bfhd,hde->bfhe", z4,
-                            pe_ref[0].astype(jnp.float32))
-            vt = jnp.einsum("bfhd,hde->bfhe", v4,
-                            pv_ref[0].astype(jnp.float32))
+            # per-head [dh, dh] transforms as one block-diagonal [H, H]
+            zt = _lane_matmul(z0, pe_ref[0])
+            vt = _lane_matmul(v0, pv_ref[0])
         else:
-            zt, vt = z4, v4
-        qv = qv_ref[0].reshape(bn, nh, dh).astype(jnp.float32)
-        e = jnp.einsum("bfhe,bhe->bfh", zt, qv) * scale
+            zt, vt = z0, v0
+        qv = qv_ref[0].astype(jnp.float32)  # [bn, H]
+        # per-head logit sum, broadcast back over the head's dh lanes
+        e = _lane_matmul(zt * qv[:, None, :], _head_sum_matrix(nh, dh)) * scale
         if has_eb:
             e = e + eb_ref[0].astype(jnp.float32)[:, None, :]
         if slope is not None:
             e = jax.nn.leaky_relu(e, negative_slope=slope)
-        # identical numerics to relmod.masked_softmax
-        m = m_ref[0]  # [bn, f] bool
-        neg = jnp.asarray(jnp.finfo(e.dtype).min, e.dtype)
-        em = jnp.where(m[:, :, None], e, neg)
-        em = em - jnp.max(em, axis=1, keepdims=True)
-        z = jnp.exp(em) * m[:, :, None].astype(e.dtype)
-        alpha = z / jnp.maximum(jnp.sum(z, axis=1, keepdims=True), 1e-9)
-        out = jnp.einsum("bfh,bfhd->bhd", alpha, vt).reshape(bn, nh * dh)
+        out = _masked_softmax_combine(e, m_ref[0], vt)
         out_ref[0] = out.astype(out_ref.dtype)
         if z_ref is not None:
             z_ref[0] = z0.astype(z_ref.dtype)
@@ -337,11 +352,11 @@ def stacked_attn_epilogue_pallas(
     h: jnp.ndarray,  # [rb, n, f, d_in]  (n, d_in pre-padded to blocks)
     mask: jnp.ndarray,  # [rb, n, f]
     qv: jnp.ndarray,  # [rb, n, nh*dh]
-    eb,  # [rb, n, nh] or None
+    eb,  # [rb, n, nh*dh] head-expanded additive logits, or None
     we: jnp.ndarray,  # [Ue, d_in, nh*dh]
     wv,  # [Uv, d_in, nh*dh] or None (shares we)
-    pe,  # [Ua, nh, dh, dh] or None
-    pv,  # [Ua, nh, dh, dh] or None
+    pe,  # [Ua, nh*dh, nh*dh] block-diagonal logits transform, or None
+    pv,  # [Ua, nh*dh, nh*dh] block-diagonal values transform, or None
     us: jnp.ndarray,  # [3, rb] int32 — rows (ue, uv, ua) (scalar prefetch)
     num_heads: int,
     head_dim: int,
@@ -366,7 +381,7 @@ def stacked_attn_epilogue_pallas(
     ]
     operands = [h, mask, qv]
     if has_eb:
-        in_specs.append(pl.BlockSpec((1, bn, nh), lambda s, i, c, u: (s, i, 0)))
+        in_specs.append(pl.BlockSpec((1, bn, H), lambda s, i, c, u: (s, i, 0)))
         operands.append(eb)
     in_specs.append(
         pl.BlockSpec((1, bc, H), lambda s, i, c, u: (u[0, s], c, 0)))
@@ -377,9 +392,9 @@ def stacked_attn_epilogue_pallas(
         operands.append(wv)
     if has_post:
         in_specs.append(
-            pl.BlockSpec((1, nh, dh, dh), lambda s, i, c, u: (u[2, s], 0, 0, 0)))
+            pl.BlockSpec((1, H, H), lambda s, i, c, u: (u[2, s], 0, 0)))
         in_specs.append(
-            pl.BlockSpec((1, nh, dh, dh), lambda s, i, c, u: (u[2, s], 0, 0, 0)))
+            pl.BlockSpec((1, H, H), lambda s, i, c, u: (u[2, s], 0, 0)))
         operands.extend([pe, pv])
 
     out_specs = [pl.BlockSpec((1, bn, H), lambda s, i, c, u: (s, i, 0))]
@@ -412,6 +427,10 @@ def stacked_attn_epilogue_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        # the [bn, f, H] float32 accumulators and residual tiles (lane-padded
+        # to 128) overflow the 16 MiB default scoped VMEM once HGT carries
+        # separate K and V projections; v5e has 128 MiB per core
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_EPILOGUE_VMEM_LIMIT),
         interpret=interpret,
     )(us.astype(jnp.int32), *operands)
     return out if with_residuals else out[0]

@@ -16,9 +16,11 @@ module's ``fused`` declaration and the resolved backend
     Otherwise the oracle factoring: projections via the module's
     ``attn_parts`` (vmapped, XLA autodiff over gathered weights) + the
     Pallas masked softmax+combine epilogue.
-  * anything else, or a non-TPU backend without forced interpret ->
+  * ``fused is None`` (the module declares no kernel), or a non-TPU
+    backend without forced interpret ->
     :func:`~repro.kernels.stacked_relation_agg.ref.stacked_agg_ref`, the
-    gather-then-vmap oracle.
+    gather-then-vmap oracle.  A module that declares a family but breaks
+    its contract raises rather than falling back.
 
 Both Pallas ops carry a ``jax.custom_vjp``:
 
@@ -140,7 +142,7 @@ def _ml_fwd_impl(cfg, h, mask, w, b, slot_u):
     hp = pad_axes(h, {1: cfg.bn, 3: cfg.bc})
     mp = pad_to(mask, 1, cfg.bn)
     wp = pad_axes(w, {1: cfg.bc, 2: cfg.bo})
-    bp = pad_to(b, 1, cfg.bo)
+    bp = pad_to(b, 1, cfg.bo)[:, None, :]
     out = stacked_mean_linear_pallas(
         hp, mp, wp, bp, slot_u,
         block_n=cfg.bn, block_out=cfg.bo, block_in=cfg.bc, interpret=cfg.interpret,
@@ -170,7 +172,7 @@ def _ml_vjp_bwd(cfg, res, g):
     # exactly like autodiff of the dict-form forward sums occurrences)
     mw = mask.astype(h.dtype)
     cnt = jnp.maximum(mw.sum(-1, keepdims=True), 1.0)
-    mean = jnp.einsum("rnfd,rnf->rnd", h, mw) / cnt
+    mean = jnp.sum(h * mw[..., None], axis=2) / cnt
     pw = jnp.einsum("rnd,rno->rdo", mean, g)
     dw = jax.ops.segment_sum(pw, slot_u, num_segments=U)
     db = jax.ops.segment_sum(jnp.sum(g, axis=1), slot_u, num_segments=U)
@@ -220,14 +222,27 @@ def _stacked_sc(cfg: _SCCfg, e, mask, v):
 def _sc_fwd_impl(cfg, e, mask, v):
     rb, n, f, nh = e.shape
     vf = v.reshape(rb, n, f, nh * cfg.head_dim)
-    ep = pad_to(e, 1, cfg.bn)
+    ep = pad_to(_head_expand(e, cfg.head_dim), 1, cfg.bn)
     mp = pad_to(mask, 1, cfg.bn)
     vp = pad_to(vf, 1, cfg.bn)
     out = stacked_softmax_combine_pallas(
-        ep, mp, vp, num_heads=nh, head_dim=cfg.head_dim,
-        block_n=cfg.bn, interpret=cfg.interpret,
+        ep, mp, vp, block_n=cfg.bn, interpret=cfg.interpret,
     )
     return out[:, :n]
+
+
+def _head_expand(x, dh: int):
+    """``[..., nh] -> [..., nh*dh]``: each head's value on all its lanes."""
+    return jnp.repeat(x, dh, axis=-1)
+
+
+def _block_diag(p):
+    """Per-head transforms ``[U, nh, dh, dh]`` -> block-diagonal
+    ``[U, nh*dh, nh*dh]`` (``x @ bd`` applies head ``h``'s matrix to lanes
+    ``h*dh:(h+1)*dh``)."""
+    U, nh, dh, _ = p.shape
+    eye = jnp.eye(nh, dtype=p.dtype)
+    return jnp.einsum("uhde,hk->uhdke", p, eye).reshape(U, nh * dh, nh * dh)
 
 
 def _sc_alpha(e, mask):
@@ -289,15 +304,23 @@ class _AECfg:
     interpret: bool
 
 
+# the attention kernels fold [bn, f, .] blocks into [bn*f, .] matmul rows;
+# with the fanout padded to whole sublane tiles that fold is free for Mosaic
+# (an unaligned one compiles to relayouts, slowly and into more VMEM).
+# Padded slots are masked out, so they change nothing.
+_F_TILE = 8
+
+
 def _ae_fwd_impl(cfg, h, mask, qv, eb, we, wv, pe, pv, us, with_residuals):
     rb, n, f, d_in = h.shape
-    hp = pad_axes(h, {1: cfg.bn, 3: cfg.bc})
-    mp = pad_to(mask, 1, cfg.bn)
+    hp = pad_axes(h, {1: cfg.bn, 2: _F_TILE, 3: cfg.bc})
+    mp = pad_axes(mask, {1: cfg.bn, 2: _F_TILE})
     qp = pad_to(qv, 1, cfg.bn)
-    ebp = pad_to(eb, 1, cfg.bn) if cfg.has_eb else None
+    ebp = pad_to(_head_expand(eb, cfg.dh), 1, cfg.bn) if cfg.has_eb else None
     wep = pad_to(we, 1, cfg.bc)
     wvp = None if cfg.shared_v else pad_to(wv, 1, cfg.bc)
-    pe_, pv_ = (pe, pv) if cfg.has_post else (None, None)
+    pe_, pv_ = ((_block_diag(pe), _block_diag(pv)) if cfg.has_post
+                else (None, None))
     res = stacked_attn_epilogue_pallas(
         hp, mp, qp, ebp, wep, wvp, pe_, pv_, us,
         num_heads=cfg.nh, head_dim=cfg.dh, scale=cfg.scale, slope=cfg.slope,
@@ -307,8 +330,8 @@ def _ae_fwd_impl(cfg, h, mask, qv, eb, we, wv, pe, pv, us, with_residuals):
     if not with_residuals:
         return res[:, :n]
     out = res[0][:, :n]
-    z0 = res[1][:, :n]
-    v0 = z0 if cfg.shared_v else res[2][:, :n]
+    z0 = res[1][:, :n, :f]
+    v0 = z0 if cfg.shared_v else res[2][:, :n, :f]
     return out, z0, v0
 
 
@@ -379,7 +402,7 @@ def _ae_vjp_bwd(cfg, res, g):
             jnp.einsum("rnfc,rnfk->rck", h, dcomb), us[0],
             num_segments=we.shape[0])
         dwv = jnp.zeros_like(wv)
-        dzp, dvp = pad_to(dcomb, 1, cfg.bn), None
+        dzp, dvp = pad_axes(dcomb, {1: cfg.bn, 2: _F_TILE}), None
     else:
         dwe = jax.ops.segment_sum(
             jnp.einsum("rnfc,rnfk->rck", h, dz), us[0],
@@ -387,14 +410,15 @@ def _ae_vjp_bwd(cfg, res, g):
         dwv = jax.ops.segment_sum(
             jnp.einsum("rnfc,rnfk->rck", h, dv), us[1],
             num_segments=wv.shape[0])
-        dzp, dvp = pad_to(dz, 1, cfg.bn), pad_to(dv, 1, cfg.bn)
+        dzp = pad_axes(dz, {1: cfg.bn, 2: _F_TILE})
+        dvp = pad_axes(dv, {1: cfg.bn, 2: _F_TILE})
     # dh through the scalar-prefetch transpose kernel — weight blocks read
     # from the stack, same indirection as the forward
     dh_ = stacked_attn_dh_pallas(
         dzp, dvp, pad_to(we, 1, cfg.bc),
         None if cfg.shared_v else pad_to(wv, 1, cfg.bc), us,
         block_n=cfg.bn, block_in=cfg.bc, interpret=cfg.interpret,
-    )[:, :n, :, :d_in]
+    )[:, :n, :f, :d_in]
     return (dh_, zero_cotangent(mask), dqv, deb, dwe, dwv, dpe, dpv,
             zero_cotangent(us))
 
@@ -470,15 +494,19 @@ def stacked_agg(
 
     scope_of = {s.name: s.scope for s in module.specs}
     if use and module.fused == "mean_linear":
-        # the family contract is leaves named w/b sharing one scope; fall
-        # through to the oracle for exotic declarations rather than
-        # miscompute (or crash on a missing leaf)
-        if scope_of.get("w") is not None and scope_of.get("w") == scope_of.get("b"):
-            bn, bo, bc = _blocks("stacked_mean_linear", stacks["w"].shape[2])
-            return stacked_mean_linear(
-                h, mask, stacks["w"], stacks["b"], slot_u[scope_of["w"]],
-                block_n=bn, block_out=bo, block_in=bc, interpret=interp,
-            )
+        # the family contract is leaves named w/b sharing one scope; a module
+        # that declares the family without it is a bug, never a quiet detour
+        # through the oracle
+        if scope_of.get("w") is None or scope_of.get("w") != scope_of.get("b"):
+            raise ValueError(
+                f"relation module {module.name!r} declares fused='mean_linear' "
+                f"but not its contract (leaves 'w' and 'b' in one scope); "
+                f"leaves: {scope_of}")
+        bn, bo, bc = _blocks("stacked_mean_linear", stacks["w"].shape[2])
+        return stacked_mean_linear(
+            h, mask, stacks["w"], stacks["b"], slot_u[scope_of["w"]],
+            block_n=bn, block_out=bo, block_in=bc, interpret=interp,
+        )
     if use and module.fused == "softmax_combine":
         if getattr(opts, "fuse_epilogue", True):
             bn, bo, bc = _blocks("stacked_attn_epilogue",
@@ -503,6 +531,12 @@ def stacked_agg(
         )
         bias = module.attn_bias(p_slots)  # [rb, hidden] or None
         return out if bias is None else out + bias[:, None, :]
+    if use and module.fused is not None:
+        raise ValueError(
+            f"relation module {module.name!r} declares an unknown fused "
+            f"kernel family {module.fused!r}")
+    # no kernel chosen, or the module declares none (fused=None): its own
+    # aggregate, vmapped over the slots, is the computation
     return stacked_agg_ref(module, stacks, slot_u, h, q, mask)
 
 

@@ -71,7 +71,7 @@ def stacked_agg_grouped(module, stacks, slot_u_np, h, q, mask):
         # saved — the 0.93x mag_l1/mag_l2 regression in BENCH_kernels.json.
         mw = mask.astype(h.dtype)
         cnt = jnp.maximum(mw.sum(-1, keepdims=True), 1.0)
-        mean = jnp.einsum("rnfd,rnf->rnd", h, mw) / cnt
+        mean = jnp.sum(h * mw[..., None], axis=2) / cnt
         chunks, order = [], []
         for sig, slots in groups.items():
             u_of = dict(zip(module.scopes, sig))

@@ -201,7 +201,10 @@ def _serve_lm(args) -> None:
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
     args = _parser().parse_args()
+    enable_compile_cache()
     if args.arch:
         _serve_lm(args)
     else:

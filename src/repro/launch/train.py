@@ -67,6 +67,7 @@ def train_hgnn(
 
 def main():
     from repro.api import Heta, add_config_args, config_from_args, executors
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     add_config_args(ap)
@@ -79,6 +80,7 @@ def main():
                          "mmap stores left by crashed runs, then train as "
                          "usual")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.shm_cleanup:
         from repro.graph.mmap_store import cleanup_stale_stores
         from repro.graph.shm import cleanup_stale_segments

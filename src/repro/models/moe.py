@@ -158,8 +158,6 @@ def moe_block_ep(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map_nocheck
-
     E, K = cfg.moe_experts, cfg.moe_topk
     mp = mesh.shape[model_axis]
     assert E % mp == 0, (E, mp)
@@ -201,7 +199,7 @@ def moe_block_ep(
             contrib.reshape(E * C, D))
         return xs + out.reshape(b, s, D)
 
-    return shard_map_nocheck(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -213,6 +211,7 @@ def moe_block_ep(
             P(dp_axes, model_axis, None),  # x: batch→dp, seq→model
         ),
         out_specs=P(dp_axes, model_axis, None),
+        check_vma=False,
     )(p["w1"], p["w3"], p["w2"], p["router"], p["norm"], x)
 
 

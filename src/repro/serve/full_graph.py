@@ -486,12 +486,13 @@ def spmd_logits_for_batch(plan, stacks, batch, tables, kernels=None):
     from jax.sharding import PartitionSpec as P
 
     from repro.core import raf_spmd
+    from repro.launch.mesh import make_mesh
 
     if plan.num_shards != 1:
         raise ValueError(
             f"parity reference needs a 1-shard plan, got {plan.num_shards}")
     arrays = raf_spmd.stack_batch(plan, batch, tables)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rel_stacks = {k2: v for k2, v in stacks.items() if k2 != "head"}
     feats = {k2: v for k2, v in arrays.items() if "feat" in k2}
     rest = {k2: v for k2, v in arrays.items() if "feat" not in k2}
@@ -503,7 +504,7 @@ def spmd_logits_for_batch(plan, stacks, batch, tables, kernels=None):
     stack_specs = raf_spmd._stack_specs(plan)
     rel_specs = {k2: v for k2, v in stack_specs.items() if k2 != "head"}
     arr_specs = raf_spmd._array_specs(plan, ("data",), "model")
-    root = raf_spmd.shard_map_nocheck(
+    root = jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -512,6 +513,7 @@ def spmd_logits_for_batch(plan, stacks, batch, tables, kernels=None):
             {k2: arr_specs[k2] for k2 in rest},
         ),
         out_specs=P(("data",), None),
-    )(rel_stacks, feats, rest)
+        check_vma=False,
+    ))(rel_stacks, feats, rest)
     h = jax.nn.relu(root)
     return np.asarray(h @ stacks["head"]["w"] + stacks["head"]["b"])

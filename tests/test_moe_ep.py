@@ -18,6 +18,7 @@ import pytest
 
 import repro.configs.all_archs  # noqa: F401
 from repro.configs.base import ARCHS
+from repro.launch.mesh import make_mesh
 from repro.models.moe import moe_block, moe_block_ep, moe_params
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
@@ -28,7 +29,7 @@ def test_ep_single_shard_exact():
     rng = np.random.default_rng(0)
     p = moe_params(jax.random.PRNGKey(1), cfg, jnp.float32)
     x = jnp.asarray(rng.standard_normal((2, 16, cfg.d_model)), jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ref = moe_block(p, cfg, x)
     out = moe_block_ep(p, cfg, x, mesh, ("data",))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
@@ -41,7 +42,7 @@ def test_ep_grad_flows():
     rng = np.random.default_rng(1)
     p = moe_params(jax.random.PRNGKey(2), cfg, jnp.float32)
     x = jnp.asarray(rng.standard_normal((2, 16, cfg.d_model)), jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     g = jax.grad(lambda pp: jnp.sum(moe_block_ep(pp, cfg, x, mesh, ("data",)) ** 2))(p)
     gref = jax.grad(lambda pp: jnp.sum(moe_block(pp, cfg, x) ** 2))(p)
@@ -59,13 +60,14 @@ import dataclasses, json
 import numpy as np, jax, jax.numpy as jnp
 import repro.configs.all_archs
 from repro.configs.base import ARCHS
+from repro.launch.mesh import make_mesh
 from repro.models.moe import moe_block, moe_block_ep, moe_params
 
 cfg = dataclasses.replace(ARCHS["qwen3-moe-30b-a3b"].reduced(), capacity_factor=64.0)
 rng = np.random.default_rng(0)
 p = moe_params(jax.random.PRNGKey(1), cfg, jnp.float32)
 x = jnp.asarray(rng.standard_normal((4, 64, cfg.d_model)), jnp.float32)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ref = moe_block(p, cfg, x)
 out = jax.jit(lambda p_, x_: moe_block_ep(p_, cfg, x_, mesh, ("data",)))(p, x)
 d = float(jnp.abs(out - ref).max())

@@ -36,6 +36,7 @@ from repro.graph.sampler import SampleSpec, NeighborSampler
 from repro.core.hgnn import HGNNConfig, init_hgnn_params, init_embed_tables, hgnn_forward, batch_to_arrays
 from repro.core.raf import assign_branches
 from repro.core import raf_spmd
+from repro.launch.mesh import make_mesh
 from repro.optim.adam import AdamConfig, adam_init
 
 g = ogbn_mag_like(scale=0.002)
@@ -58,7 +59,7 @@ tables = {t: np.asarray(f) for t, f in g.features.items()}
 tables.update({t: np.asarray(v) for t, v in params["embed"].items()})
 arrays = raf_spmd.stack_batch(plan, batch, tables)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 arrays_s = raf_spmd.shard_arrays(plan, mesh, arrays)
 stacks_s = raf_spmd.shard_stacks(plan, mesh, stacks)
 step = raf_spmd.make_train_step(plan, mesh, AdamConfig(lr=5e-3), data_axes=("data",))
@@ -90,6 +91,7 @@ from repro.graph.sampler import SampleSpec, NeighborSampler
 from repro.core.hgnn import HGNNConfig, init_hgnn_params, init_embed_tables
 from repro.core.raf import assign_branches, random_branch_assignment
 from repro.core import raf_spmd
+from repro.launch.mesh import make_mesh
 from repro.optim.adam import AdamConfig, adam_init
 from repro.launch.dryrun import collective_bytes
 
@@ -106,7 +108,7 @@ params = init_hgnn_params(jax.random.PRNGKey(0), cfg, spec, feat_dims)
 params["embed"] = init_embed_tables(jax.random.PRNGKey(1), cfg, g.num_nodes, feat_dims)
 tables = {t: np.asarray(f) for t, f in g.features.items()}
 tables.update({t: np.asarray(v) for t, v in params["embed"].items()})
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 results = {}
 for mode, assignment, local in (

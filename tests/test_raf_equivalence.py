@@ -27,6 +27,7 @@ from repro.core.raf import (
 )
 from repro.graph.sampler import NeighborSampler, SampleSpec
 from repro.graph.synthetic import donor_like, ogbn_mag_like
+from repro.launch.mesh import make_mesh
 
 
 def _setup(graph, model, num_parts, fanouts=(4, 3), batch=16):
@@ -113,7 +114,7 @@ def test_prop1_spmd_stacked(model, kernels_on):
     tables_np.update({t: np.asarray(v) for t, v in params["embed"].items()})
     arrays = raf_spmd.stack_batch(plan, b, tables_np)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     from jax.sharding import PartitionSpec as P
 
     arr_specs = raf_spmd._array_specs(plan, ("data",), "model")
@@ -125,12 +126,13 @@ def test_prop1_spmd_stacked(model, kernels_on):
         return raf_spmd.raf_spmd_forward(plan, st, {**fe, **re_}, "model", True,
                                          kernels)
 
-    root = raf_spmd.shard_map_nocheck(
+    root = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(rel_specs, {k: arr_specs[k] for k in feats},
                   {k: arr_specs[k] for k in rest}),
         out_specs=P(("data",), None),
+        check_vma=False,
     )({k: v for k, v in stacks.items() if k != "head"}, feats, rest)
     logits = jax.nn.relu(root) @ stacks["head"]["w"] + stacks["head"]["b"]
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref), atol=2e-5)
@@ -161,7 +163,7 @@ def test_prop1_spmd_gradients_match_vanilla(model, kernels_on):
     tables_np.update({t: np.asarray(v) for t, v in params["embed"].items()})
     arrays = raf_spmd.stack_batch(plan, b, tables_np)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     loss_fn, split = raf_spmd._build_loss_fn(plan, mesh, "model", ("data",), True,
                                              kernels)
     feats, rest = split(arrays)

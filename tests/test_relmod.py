@@ -30,6 +30,7 @@ from repro.core.relmod import (
 )
 from repro.graph.sampler import NeighborSampler, SampleSpec
 from repro.graph.synthetic import ogbn_mag_like
+from repro.launch.mesh import make_mesh
 
 _GRAPH = ogbn_mag_like(scale=0.002)
 
@@ -232,7 +233,7 @@ def test_new_model_is_a_pure_declaration():
         tables_np = {t: np.asarray(f) for t, f in g.features.items()}
         tables_np.update({t: np.asarray(v) for t, v in params["embed"].items()})
         arrays = raf_spmd.stack_batch(plan, batch, tables_np)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         loss = raf_spmd.make_loss_fn(plan, mesh)
         logits_loss = float(loss(stacks, arrays))
         assert np.isfinite(logits_loss)

@@ -283,6 +283,21 @@ def test_dp_fit_local_mode_converges_identically_across_trainers():
     assert all(np.isfinite(dp.losses))
 
 
+def test_dp_fit_on_tpu_fails_fast_without_spawning(monkeypatch):
+    """On a TPU this process holds the chip: a multi-trainer fit must refuse
+    (pointing at the mesh data axis) before it starts any child."""
+    import jax
+
+    from repro.api.session import HetaStageError
+
+    sess = _built(_quick_cfg(steps=2, num_trainers=2, mode="global"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(HetaStageError, match="--mesh"):
+        sess.fit()
+    assert mp.active_children() == []
+    assert sess.losses == []
+
+
 def test_dp_fit_rejects_learnable_tables():
     from repro.api.session import HetaStageError
 

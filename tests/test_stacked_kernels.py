@@ -24,6 +24,7 @@ from repro.kernels.stacked_relation_agg import (
     stacked_mean_linear_vmem_bytes,
     stacked_softmax_combine,
 )
+from repro.launch.mesh import make_mesh
 
 rng = np.random.default_rng(7)
 OPTS_ON = KernelOptions(interpret=True)
@@ -341,6 +342,27 @@ def test_stacked_agg_disabled_is_oracle():
     np.testing.assert_array_equal(np.asarray(off), np.asarray(ref))
 
 
+def test_stacked_agg_broken_family_contract_raises():
+    """A module that declares the mean-linear kernel family without its
+    contract (``w`` and ``b`` in one scope) is refused, never silently run
+    through the oracle while the kernels are selected."""
+    from repro.core.relmod import ParamSpec, RelationModule
+
+    class Broken(RelationModule):
+        name = "_broken_mean_linear"
+        fused = "mean_linear"
+        specs = (
+            ParamSpec("w", "relation", lambda c: (c.d_src, c.hidden)),
+            ParamSpec("b", "dst_type", lambda c: (c.hidden,), init="zeros"),
+        )
+
+    h, q, mask, w, b, slot_u = _mean_linear_case(2, 8, 3, 10, 16, 2)
+    with pytest.raises(ValueError, match="contract"):
+        stacked_agg(Broken(), {"w": w, "b": b},
+                    {"relation": slot_u, "dst_type": slot_u}, h, q, mask,
+                    opts=OPTS_ON)
+
+
 def test_vmem_budget():
     """Static VMEM per grid step stays under the 16 MiB budget at the
     paper's largest shapes (IGB-HET feature width, fanout 25)."""
@@ -384,7 +406,7 @@ def test_raf_spmd_fused_forward_bit_equal_rgcn():
             tables[t] = np.zeros((g.num_nodes[t], cfg.learnable_dim), np.float32)
     arrays = raf_spmd.stack_batch(plan, b, tables)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     arr_specs = raf_spmd._array_specs(plan, ("data",), "model")
     rel_specs = {k: v for k, v in raf_spmd._stack_specs(plan).items() if k != "head"}
     feats = {k: v for k, v in arrays.items() if "feat" in k}
@@ -394,11 +416,12 @@ def test_raf_spmd_fused_forward_bit_equal_rgcn():
         def body(st, fe, re_):
             return raf_spmd.raf_spmd_forward(plan, st, {**fe, **re_}, "model",
                                              True, kernels)
-        return raf_spmd.shard_map_nocheck(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(rel_specs, {k: arr_specs[k] for k in feats},
                       {k: arr_specs[k] for k in rest}),
             out_specs=P(("data",), None),
+            check_vma=False,
         )({k: v for k, v in stacks.items() if k != "head"}, feats, rest)
 
     vmap_root = run(KernelOptions(enabled=False))

@@ -2,6 +2,7 @@
 accounting sanity, checkpoint round-trips, and the sharding rule tables."""
 
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -174,3 +175,35 @@ def test_cache_pspecs_long_context():
     # batch-1: sequence axis spread over (data, model)
     assert specs["k"][3] == ("data", "model")
     assert specs["ssm"][3] == "model"
+
+
+# --------------------------------------------------------------------------
+# the entry points' persistent compilation cache
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("env_dir", [None, "custom"])
+def test_compile_cache_dir_is_env_or_fixed_checkout_path(env_dir, tmp_path):
+    """``enable_compile_cache`` keeps ``JAX_COMPILATION_CACHE_DIR`` when set
+    (and sets no other), else the fixed ``.jax_cache`` of the checkout —
+    never a path built from a temp name, pid or time."""
+    import subprocess
+    import sys
+
+    from repro.launch.compile_cache import CHECKOUT_CACHE_DIR
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    want = str(CHECKOUT_CACHE_DIR)
+    if env_dir is not None:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    code = ("import jax; from repro.launch.compile_cache import "
+            "enable_compile_cache; d = enable_compile_cache(); "
+            "print(d); print(jax.config.jax_compilation_cache_dir)")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [want, want]
+    assert CHECKOUT_CACHE_DIR == Path(root).resolve() / ".jax_cache"

@@ -4,6 +4,7 @@ idempotence, no stray processes or shm segments)."""
 
 import dataclasses
 import os
+import sys
 import time
 
 import numpy as np
@@ -65,6 +66,24 @@ class BadSetupTask:
 
     def teardown(self):
         pass
+
+
+@dataclasses.dataclass
+class JaxProbeTask:
+    """Runs a real sampling task, then reports whether the worker process
+    has imported jax."""
+
+    inner: SampleStageTask
+
+    def setup(self):
+        self.inner.setup()
+
+    def __call__(self, i):
+        self.inner(i)
+        return "jax" in sys.modules
+
+    def teardown(self):
+        self.inner.teardown()
 
 
 @pytest.mark.parametrize("num_workers", [1, 2, 3])
@@ -160,6 +179,25 @@ def test_pool_batches_bit_identical_to_serial(num_workers):
     finally:
         store.unlink()
     assert not live_segments(store.handle.segment)
+
+
+def test_sampler_workers_never_import_jax():
+    """Workers are spawned while the consumer holds the accelerator, which
+    belongs to one process at a time: a worker that imported jax would
+    claim or wait for the chip.  Sampling and staging must stay jax-free."""
+    g, spec = _mag()
+    store = share_graph(g, include_features=False)
+    try:
+        task = SampleStageTask(
+            handle=store.handle, spec=spec, batch_size=8, sampler_seed=5,
+            schedule=EpochSchedule(77, NeighborSampler(
+                g, spec, 8, seed=5).steps_per_epoch()),
+        )
+        with WorkerPool(JaxProbeTask(task), num_workers=2, depth=1,
+                        num_items=4) as pool:
+            assert list(pool) == [False] * 4
+    finally:
+        store.unlink()
 
 
 def test_worker_staging_matches_consumer_staging():
