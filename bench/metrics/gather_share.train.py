@@ -1,0 +1,17 @@
+"""Share of the window's wall time the host spent in the padded numpy
+gathers of staging (span ``heta.stage.gather``: ``stack_batch_host``),
+summed over the window's steps from the program's own recorder."""
+
+from bench.harness import WARM_STEPS
+
+
+def read(ctx):
+    try:
+        from repro import obs
+    except ImportError:  # a program without the recorder
+        return None
+    spans = obs.window(WARM_STEPS, ctx.window.steps)
+    if spans is None:
+        return None
+    return sum(s.seconds for s in spans
+               if s.name == "heta.stage.gather") / ctx.window.wall_s

@@ -30,11 +30,13 @@ exposes graph / spec / assignment / engine / hgnn_cfg):
       the async pipeline (``repro.data``) runs it in a producer thread for
       batch *i+1* while batch *i* trains.
   ``step_staged(sess, plan, state, batch, arrays) -> (state, loss, step_time_s)``
-      the device step on pre-staged arrays; ``step_time_s`` times the
-      compute + sparse-update region only, so reported step times stay
-      comparable with the historical ``train_hgnn`` accounting.  Executors
-      with a sparse-update stage record its share in
-      ``plan.last_update_s`` (the breakdown benchmark's update column).
+      the device step on pre-staged arrays, inside the span
+      ``heta.device``; ``step_time_s`` is that span's duration (compute +
+      sparse update, staging excluded), so reported step times stay
+      comparable with the historical ``train_hgnn`` accounting.  Its child
+      spans split it (``repro.obs``): ``heta.device.wait`` (the host
+      blocked on the device), ``heta.update.pull`` and ``heta.update.adam``
+      (the sparse update of learnable rows).
   ``step(sess, plan, state, batch) -> (state, loss, step_time_s)``
       the serial composition ``step_staged(..., stage(...))`` — kept for
       callers that don't pipeline.
@@ -63,11 +65,12 @@ Register your own with ``@executors.register("name")``.
 
 from __future__ import annotations
 
-import time
 from types import SimpleNamespace
 from typing import Dict, Tuple, Type
 
 import numpy as np
+
+from repro import obs
 
 __all__ = ["Executor", "register", "get", "available", "apply_feature_grads"]
 
@@ -211,7 +214,8 @@ class VanillaExecutor(Executor):
         return {"bundle": bundle, "opt": adam_init(bundle)}
 
     def stage(self, sess, plan, batch):
-        return plan.to_arrays(batch)
+        with obs.span("heta.stage"):
+            return plan.to_arrays(batch)
 
     def step_staged(self, sess, plan, state, batch, arrays):
         return _bundle_step_staged(sess, plan, state, arrays)
@@ -226,11 +230,13 @@ def _bundle_step_staged(sess, plan, state, arrs):
     timed — mirrors the historical step-time accounting (staging excluded)."""
     from repro.optim.adam import adam_update
 
-    t0 = time.perf_counter()
-    loss, grads = plan.grad(state["bundle"], arrs)
-    bundle, opt = adam_update(sess.adam_cfg, state["bundle"], grads, state["opt"])
-    loss = float(loss)
-    return {"bundle": bundle, "opt": opt}, loss, time.perf_counter() - t0
+    with obs.span(obs.DEVICE) as dev:
+        loss, grads = plan.grad(state["bundle"], arrs)
+        bundle, opt = adam_update(sess.adam_cfg, state["bundle"], grads,
+                                  state["opt"])
+        with obs.span("heta.device.wait"):
+            loss = float(loss)
+    return {"bundle": bundle, "opt": opt}, loss, dev.seconds
 
 
 # --------------------------------------------------------------------------
@@ -289,7 +295,8 @@ class RafSimExecutor(Executor):
         return {"bundle": bundle, "opt": adam_init(bundle)}
 
     def stage(self, sess, plan, batch):
-        return plan.to_arrays(batch)
+        with obs.span("heta.stage"):
+            return plan.to_arrays(batch)
 
     def step_staged(self, sess, plan, state, batch, arrays):
         return _bundle_step_staged(sess, plan, state, arrays)
@@ -362,10 +369,11 @@ class RafSpmdExecutor(Executor):
             cached = getattr(plan, "_stage_cache", None)
             if cached is not None and cached[0] is batch:
                 return cached[1]
-        tables = sess.engine.tables_snapshot()
-        arrays = raf_spmd.shard_arrays(
-            plan.plan, plan.mesh, raf_spmd.stack_batch(plan.plan, batch, tables)
-        )
+        with obs.span("heta.stage"):
+            tables = sess.engine.tables_snapshot()
+            arrays = raf_spmd.shard_arrays(
+                plan.plan, plan.mesh,
+                raf_spmd.stack_batch(plan.plan, batch, tables))
         if not plan.learn_feats:
             plan._stage_cache = (batch, arrays)
         return arrays
@@ -405,20 +413,23 @@ class RafSpmdExecutor(Executor):
             return self.stage(sess, plan, batch)
         from repro.core import raf_spmd
 
-        return raf_spmd.shard_arrays(plan.plan, plan.mesh, host_arrays)
+        with obs.span("heta.stage"):
+            return raf_spmd.shard_arrays(plan.plan, plan.mesh, host_arrays)
 
     def step_staged(self, sess, plan, state, batch, arrays):
-        t0 = time.perf_counter()
-        if plan.learn_feats:
-            stacks, opt, loss, gf = plan.step(state["stacks"], state["opt"], arrays)
-            t1 = time.perf_counter()
-            apply_feature_grads(sess.engine, plan.plan, batch, gf)
-            plan.last_update_s = time.perf_counter() - t1
-        else:
-            stacks, opt, loss = plan.step(state["stacks"], state["opt"], arrays)
-            plan.last_update_s = 0.0
-        loss = float(loss)
-        return {"stacks": stacks, "opt": opt}, loss, time.perf_counter() - t0
+        """Dispatch the compiled step, wait for its loss, then (learnable
+        rows) pull the feature gradients and run the sparse Adam update:
+        ``loss`` and the gradients come out of the same compiled step, so
+        waiting for the loss first adds no sync and leaves the wait to
+        ``heta.device.wait`` alone."""
+        with obs.span(obs.DEVICE) as dev:
+            stacks, opt, loss, *gf = plan.step(state["stacks"], state["opt"],
+                                               arrays)
+            with obs.span("heta.device.wait"):
+                loss = float(loss)
+            if plan.learn_feats:
+                apply_feature_grads(sess.engine, plan.plan, batch, gf[0])
+        return {"stacks": stacks, "opt": opt}, loss, dev.seconds
 
     def loss_and_metrics(self, sess, plan, state, batch):
         loss = float(plan.loss(state["stacks"], self.stage(sess, plan, batch)))
@@ -500,7 +511,8 @@ def apply_feature_grads(engine, plan, batch, gf: Dict) -> None:
         ):
             if key not in gf:
                 continue
-            grad = np.asarray(gf[key])  # [P*rb, N, d_pad]
+            with obs.span("heta.update.pull"):
+                grad = np.asarray(gf[key])  # [P*rb, N, d_pad]
             grad = grad.reshape(plan.num_shards, lp.rb, *grad.shape[1:])
             per_type: Dict[str, list] = {}
             for p in range(plan.num_shards):

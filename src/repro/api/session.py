@@ -23,11 +23,13 @@ materialized store (``close_serving()`` releases both).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.api import executors as _executors
 from repro.api.config import HetaConfig
 
@@ -70,6 +72,21 @@ class CacheReport:
     engine: object  # EmbedEngine
 
 
+def _stage(fn):
+    """A session stage, timed by its span ``heta.setup.<stage>`` into
+    ``stage_times``."""
+    name = fn.__name__
+
+    @functools.wraps(fn)
+    def timed(self, *args, **kw):
+        with obs.span(f"heta.setup.{name}", session=self.obs_session) as sp:
+            out = fn(self, *args, **kw)
+        self.stage_times[name] = sp.seconds
+        return out
+
+    return timed
+
+
 class Heta:
     """Session over one :class:`HetaConfig` (see module docstring)."""
 
@@ -82,7 +99,10 @@ class Heta:
         from repro.optim.adam import AdamConfig
 
         self.adam_cfg = AdamConfig(lr=config.run.lr)
-        self.stage_times: Dict[str, float] = {}
+        # the recorder's serial of this session (repro.obs): every span of
+        # its stages and steps carries it
+        self.obs_session = obs.new_session()
+        self.stage_times: Dict[str, float] = {}  # heta.setup.<stage> spans
         # stage products
         self.graph = None
         self.hgnn_cfg = None
@@ -98,8 +118,10 @@ class Heta:
         self.state = None
         self.sampler = None
         self.losses: List[float] = []
+        # per step, from its spans: the device step (heta.device) and the
+        # host work before it (sampling and staging)
         self.step_times: List[float] = []
-        self.host_times: List[float] = []  # per-step sample+stage seconds
+        self.host_times: List[float] = []
         # fit-loop overlap accounting (wall vs serial sum; see results())
         self._fit_wall_s = 0.0
         self._fit_serial_s = 0.0
@@ -130,6 +152,7 @@ class Heta:
 
     # -- stage 1: data ------------------------------------------------------
 
+    @_stage
     def build_graph(self, graph=None):
         """Materialize the HetG and the model config derived from it.
 
@@ -140,7 +163,6 @@ class Heta:
 
         from repro.graph.synthetic import make_dataset
 
-        t0 = time.perf_counter()
         # shm janitor (DESIGN.md §12/§13): a hard-crashed prior run can leave
         # orphaned graph/arena segments the resource tracker never saw — and,
         # since the scale-out tier, on-disk mmap stores too; sweep both kinds
@@ -166,11 +188,11 @@ class Heta:
         }
         self.fixed_tables = {t: jnp.asarray(f) for t, f in self.graph.features.items()}
         self.hgnn_cfg = cfg.model.to_hgnn_config(cfg.num_layers, self.graph.num_classes)
-        self.stage_times["build_graph"] = time.perf_counter() - t0
         return self.graph
 
     # -- stage 2: §5 meta-partitioning ---------------------------------------
 
+    @_stage
     def partition(self) -> PartitionReport:
         """Meta-partition the graph and place relation branches."""
         from repro.core.meta_partition import meta_partition
@@ -178,7 +200,6 @@ class Heta:
         from repro.graph.sampler import SampleSpec
 
         self._require("graph", "build_graph", "partition")
-        t0 = time.perf_counter()
         cfg = self.config
         self.mp = meta_partition(self.graph, cfg.partition.num_partitions,
                                  num_layers=cfg.num_layers)
@@ -190,7 +211,6 @@ class Heta:
             else assign_branches(self.spec, self.mp)
         )
         self.meta_local = self.assignment.meta_local
-        self.stage_times["partition"] = time.perf_counter() - t0
         return PartitionReport(
             summary=self.mp.summary(),
             meta_local=self.meta_local,
@@ -284,6 +304,7 @@ class Heta:
 
     # -- stage 3: §6 profiling + cache ---------------------------------------
 
+    @_stage
     def profile_and_cache(self) -> CacheReport:
         """Pre-sample hotness, profile miss penalties, allocate the cache.
 
@@ -295,7 +316,6 @@ class Heta:
         from repro.embed.profiler import presample_hotness, presample_hotness_pooled
 
         self._require("spec", "partition", "profile_and_cache")
-        t0 = time.perf_counter()
         cfg = self.config
         if cfg.pipeline.enabled and cfg.pipeline.num_workers > 0:
             hotness = presample_hotness_pooled(
@@ -322,7 +342,6 @@ class Heta:
             num_shards=int(np.prod(cfg.run.mesh_shape)), seed=cfg.run.seed,
             kernels=cfg.kernels,
         )
-        self.stage_times["profile_and_cache"] = time.perf_counter() - t0
         return CacheReport(
             allocation_rows=dict(self.engine.allocation.rows),
             learnable_types=dict(self.engine.learnable_types),
@@ -333,12 +352,12 @@ class Heta:
 
     # -- stage 4: executor compilation ----------------------------------------
 
+    @_stage
     def compile(self, executor: Optional[str] = None) -> "Heta":
         """Build the executor plan + initial state via the registry."""
         from repro.graph.sampler import NeighborSampler
 
         self._require("engine", "profile_and_cache", "compile")
-        t0 = time.perf_counter()
         name = executor or self.config.run.executor
         self.executor = _executors.get(name)  # raises KeyError w/ available list
         self.plan = self.executor.build_plan(self)
@@ -347,7 +366,6 @@ class Heta:
             self.graph, self.spec, self.config.data.batch_size,
             seed=self.config.run.seed + 1,
         )
-        self.stage_times["compile"] = time.perf_counter() - t0
         return self
 
     # -- stage 5: training / evaluation ---------------------------------------
@@ -355,28 +373,25 @@ class Heta:
     def step(self, batch=None) -> float:
         """One optimization step (samples the next batch when none given).
 
-        Recorded step times come from the executor's own timed region —
-        compute + sparse update, host staging excluded — matching the
-        historical ``train_hgnn`` accounting.  Host sample+stage time is
-        recorded separately in ``host_times``."""
+        The step is the span ``heta.step``.  Its recorded step time is the
+        executor's device step (span ``heta.device``: compute + sparse
+        update, host staging excluded) — matching the historical
+        ``train_hgnn`` accounting — and its host time, in ``host_times``,
+        is the time ``heta.step`` spent before it (sampling + staging)."""
         self._require("state", "compile", "step")
-        t0 = time.perf_counter()
-        if batch is None:
-            batch = self._next_batch()
-        if not self._staged_protocol():
-            # legacy executor: only the composed step() is overridden
-            host_s = time.perf_counter() - t0
-            self.state, loss, dt = self.executor.step(
-                self, self.plan, self.state, batch)
-            self.host_times.append(host_s)
-            self.step_times.append(dt)
-            self.losses.append(loss)
-            self._steps_done += 1
-            self._maybe_rebalance()
-            self._maybe_checkpoint()
-            return loss
-        arrays = self.executor.stage(self, self.plan, batch)
-        return self._consume(batch, arrays, time.perf_counter() - t0)
+        with obs.span(obs.STEP, step=self._steps_done,
+                      session=self.obs_session) as root:
+            if batch is None:
+                batch = self._next_batch()
+            if not self._staged_protocol():
+                # legacy executor: only the composed step() is overridden
+                host_s = root.child_s
+                self.state, loss, dt = self.executor.step(
+                    self, self.plan, self.state, batch)
+                self._record(loss, host_s, dt)
+                return loss
+            arrays = self.executor.stage(self, self.plan, batch)
+            return self._consume(batch, arrays, root)
 
     def _staged_protocol(self) -> bool:
         """Whether the executor implements the staged-step seam (custom
@@ -384,17 +399,27 @@ class Heta:
         composed ``step``; they keep working on the serial path)."""
         return type(self.executor).stage is not _executors.Executor.stage
 
-    def _consume(self, batch, arrays, host_s: float) -> float:
-        """Run the device step on pre-staged arrays and record the books."""
+    def _consume(self, batch, arrays, root, host_s: Optional[float] = None) -> float:
+        """Run the device step on pre-staged arrays inside the open step
+        span ``root`` and record the books.  ``host_s`` is the host time of
+        a batch staged ahead (the pipeline); on the serial path it is the
+        time ``root`` spent before the executor's ``heta.device`` span."""
         self.state, loss, dt = self.executor.step_staged(
             self, self.plan, self.state, batch, arrays)
+        if host_s is None:
+            dev = root.last
+            host_s = (dev.t0 - root.t0 if dev is not None and dev.name == obs.DEVICE
+                      else root.child_s)
+        self._record(loss, host_s, dt)
+        return loss
+
+    def _record(self, loss: float, host_s: float, dt: float) -> None:
         self.host_times.append(host_s)
         self.step_times.append(dt)
         self.losses.append(loss)
         self._steps_done += 1
         self._maybe_rebalance()
         self._maybe_checkpoint()
-        return loss
 
     def _maybe_rebalance(self) -> None:
         """Online §6 re-admission: every ``cache.readmit_every`` consumed
@@ -477,10 +502,14 @@ class Heta:
                     lambda i: self._batch_for_step(start + i),
                     lambda b: self.executor.stage(self, self.plan, b),
                     num_steps=steps, depth=pcfg.depth, defer_stage=defer,
+                    first_step=start, session=self.obs_session,
                     **stream_kw,
                 ) as stream:
                     for batch, arrays, host_s in stream:
-                        logged(self._consume(batch, arrays, host_s))
+                        with obs.span(obs.STEP, step=self._steps_done,
+                                      session=self.obs_session) as root:
+                            loss = self._consume(batch, arrays, root, host_s)
+                        logged(loss)
                         if self._pool_cache is not None and stream_kw:
                             self._pool_cache[3] += 1  # pool stays in sync
                         if republish:
@@ -597,6 +626,7 @@ class Heta:
 
     # -- stage 6: the online inference tier (repro.serve) ----------------------
 
+    @_stage
     def infer_all(self, node_block: Optional[int] = None,
                   shm: Optional[bool] = None):
         """Materialize top-layer embeddings for every node of every type via
@@ -615,7 +645,6 @@ class Heta:
                 f"{self.executor.name!r} does not expose one; "
                 "compile(executor='raf_spmd') first"
             )
-        t0 = time.perf_counter()
         scfg = self.config.serve
         store = _infer_all(
             self.graph, plan, stacks, self.engine.tables_snapshot(),
@@ -626,7 +655,6 @@ class Heta:
         if self.embedding_store is not None:
             self.close_serving()
         self.embedding_store = store
-        self.stage_times["infer_all"] = time.perf_counter() - t0
         return store
 
     def serve(self, **overrides):
@@ -880,6 +908,10 @@ class Heta:
             "meta_local": self.meta_local,
             "cache_allocation": dict(self.engine.allocation.rows),
             "executor": self.executor.name if self.executor else None,
+            # per span name of this session: count, seconds, self seconds
+            # (repro.obs), and the counters its spans kept
+            "spans": obs.totals(self.obs_session),
+            "counters": obs.counters(self.obs_session),
         }
 
     # -- internal ---------------------------------------------------------------
@@ -906,8 +938,9 @@ class Heta:
         ``(config seed, s)``, so the serial loop and the async stream (which
         materializes batches ahead, possibly out of thread or out of
         process) see identical data."""
-        epoch_seed, i = self._schedule().seed_and_index(s)
-        return self.sampler.batch_at(i, epoch_seed=epoch_seed)
+        with obs.span("heta.sample", step=s, session=self.obs_session):
+            epoch_seed, i = self._schedule().seed_and_index(s)
+            return self.sampler.batch_at(i, epoch_seed=epoch_seed)
 
     def _acquire_pool(self, start_step: int):
         """The persistent sampler pool positioned at ``start_step``.

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.hgnn import HGNNConfig, Params, rel_context
 from repro.core.raf import BranchAssignment
 from repro.core.relmod import SCOPE_CONTAINER, storage_key
@@ -315,10 +316,14 @@ def stack_batch(
     The host-side gather work is the shared numpy core
     :func:`repro.data.staging.stack_batch_host` — the multi-worker sampling
     pool runs the same function inside worker processes, so worker-staged
-    and consumer-staged batches are bit-identical by construction.
+    and consumer-staged batches are bit-identical by construction.  Putting
+    its arrays on the device is the span ``heta.stage.put`` (counter
+    ``heta.stage.put_bytes``: their bytes).
     """
     host = stack_batch_host(stack_recipe(plan), batch, tables)
-    return {k: jnp.asarray(v) for k, v in host.items()}
+    with obs.span("heta.stage.put"):
+        obs.count("heta.stage.put_bytes", sum(v.nbytes for v in host.values()))
+        return {k: jnp.asarray(v) for k, v in host.items()}
 
 
 # --------------------------------------------------------------------------
@@ -646,12 +651,18 @@ def make_apply_step(plan: StackedPlan, adam_cfg):
 
 def shard_arrays(plan: StackedPlan, mesh: Mesh, arrays: Dict, data_axes=("data",),
                  model_axis: str = "model") -> Dict:
-    """Device-put stacked batch arrays with their production shardings."""
+    """Device-put stacked batch arrays with their production shardings
+    (span ``heta.stage.put``; counter ``heta.stage.put_bytes``: the bytes of
+    the host arrays among them, which travel host -> device)."""
     da = data_axes if isinstance(data_axes, tuple) else (data_axes,)
     specs = _array_specs(plan, da, model_axis)
-    return {
-        k2: jax.device_put(v, NamedSharding(mesh, specs[k2])) for k2, v in arrays.items()
-    }
+    with obs.span("heta.stage.put"):
+        obs.count("heta.stage.put_bytes", sum(
+            v.nbytes for v in arrays.values() if isinstance(v, np.ndarray)))
+        return {
+            k2: jax.device_put(v, NamedSharding(mesh, specs[k2]))
+            for k2, v in arrays.items()
+        }
 
 
 def shard_stacks(plan: StackedPlan, mesh: Mesh, stacks: Dict) -> Dict:

@@ -43,17 +43,20 @@ of two engines (see the ``repro.data`` package docstring and DESIGN.md §9):
 In both modes ``host_seconds`` is the sample+stage time actually spent on
 the item, measured where it ran, so the consumer can compute the overlap
 fraction (host work that ran concurrently with the device step costs no
-wall time).  Exceptions raised in any producer — thread or process —
-surface in the consumer's ``__next__``; ``close()`` joins everything and is
-idempotent.
+wall time).  In this process it is the summed duration of the item's
+spans (``repro.obs``: ``heta.sample``, ``heta.stage``), which carry the
+item's global step ``first_step + i`` and the caller's ``session``; the
+consumer's wait for an item is the span ``heta.pipeline.wait``.
+Exceptions raised in any producer — thread or process — surface in the
+consumer's ``__next__``; ``close()`` joins everything and is idempotent.
 """
 
 from __future__ import annotations
 
 import pickle
-import time
 from typing import Callable, List, Optional, Tuple
 
+from repro import obs
 from repro.data.prefetch import Prefetcher
 
 __all__ = ["SampleStream"]
@@ -73,6 +76,8 @@ class SampleStream:
         pool: Optional[object] = None,
         arena: Optional[object] = None,
         spec: Optional[object] = None,
+        first_step: int = 0,
+        session: Optional[int] = None,
     ):
         if num_workers < 0:
             raise ValueError(f"num_workers must be >= 0, got {num_workers}")
@@ -81,12 +86,15 @@ class SampleStream:
         self._finish = finish_stage
         self._pool = None
         self._owns_pool = True
-        self._remaining = None
+        self._num_steps = num_steps
         self._prefetcher = None
         self._arena = arena
         self._spec = spec
         self._pending_release = None  # (slot, use) alive through the step
         self._legacy_item_bytes = None  # measured once; tuple items are big
+        self._first_step = first_step
+        self._session = session
+        self._drawn = 0  # items handed to the consumer
         self.queue_bytes: List[int] = []  # pickled size of each queue item
         if arena is not None and spec is None:
             raise ValueError("arena mode requires the sampler spec")
@@ -96,10 +104,10 @@ class SampleStream:
                 raise ValueError("thread mode requires make_batch and stage")
 
             def produce(i: int) -> Tuple[object, object, float]:
-                t0 = time.perf_counter()
-                batch = make_batch(i)
-                arrays = None if defer_stage else stage(batch)
-                return batch, arrays, time.perf_counter() - t0
+                with obs.scope(first_step + i, session) as item:
+                    batch = make_batch(i)
+                    arrays = None if defer_stage else stage(batch)
+                return batch, arrays, item.child_s
 
             self._prefetcher = Prefetcher(produce, depth=depth,
                                           num_items=num_steps,
@@ -117,7 +125,6 @@ class SampleStream:
                 # leave it running on close
                 self._pool = pool
                 self._owns_pool = False
-                self._remaining = num_steps
             else:
                 if worker_task is None:
                     raise ValueError(
@@ -145,13 +152,24 @@ class SampleStream:
             self._arena.release(slot, use)
 
     def __next__(self) -> Tuple[object, object, float]:
+        if self._num_steps is not None and self._drawn >= self._num_steps:
+            raise StopIteration  # without a wait for an item never made
+        with obs.scope(self._first_step + self._drawn, self._session) as here:
+            batch, arrays, host_s, wait = self._next_item()
+            self._drawn += 1
+            # the consumer-side part of the item's host time: its spans
+            # here, less the wait for the item (not host work)
+            return batch, arrays, host_s + here.child_s - wait.seconds
+
+    @staticmethod
+    def _draw(source):
+        with obs.span("heta.pipeline.wait") as wait:
+            return next(source), wait
+
+    def _next_item(self) -> Tuple[object, object, float, obs.span]:
         if self._pool is not None:
-            if self._remaining is not None:
-                if self._remaining <= 0:
-                    raise StopIteration
-                self._remaining -= 1
             try:
-                item = next(self._pool)
+                item, wait = self._draw(self._pool)
             except BaseException:
                 self._release_pending()
                 raise
@@ -165,10 +183,9 @@ class SampleStream:
                 self.queue_bytes.append(len(pickle.dumps(item)))
                 views = self._arena.resolve(item.slot, item.use)
                 batch, host = unpack_slot(views, self._spec)
-                t0 = time.perf_counter()
                 arrays = self._finish(batch, host)
                 self._pending_release = (item.slot, item.use)
-                return batch, arrays, item.host_s + time.perf_counter() - t0
+                return batch, arrays, item.host_s, wait
             batch, host, host_s = item
             if self._legacy_item_bytes is None:
                 # tuple payloads are ~MBs of pickled ndarrays; measure once
@@ -178,17 +195,13 @@ class SampleStream:
             # consumer-side completion: device placement of worker-staged
             # arrays, or full (fresh) staging when workers only sampled —
             # either way this slice of host time is NOT overlapped
-            t0 = time.perf_counter()
-            arrays = self._finish(batch, host)
-            return batch, arrays, host_s + time.perf_counter() - t0
-        batch, arrays, host_s = next(self._prefetcher)
+            return batch, self._finish(batch, host), host_s, wait
+        (batch, arrays, host_s), wait = self._draw(self._prefetcher)
         if self._defer:
             # "fresh" snapshot policy: stage on the consumer, against the
             # current tables (this part of the host time is NOT overlapped)
-            t0 = time.perf_counter()
             arrays = self._stage(batch)
-            host_s += time.perf_counter() - t0
-        return batch, arrays, host_s
+        return batch, arrays, host_s, wait
 
     def close(self) -> None:
         self._release_pending()

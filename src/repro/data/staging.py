@@ -29,6 +29,8 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from repro import obs
+
 __all__ = [
     "StackRecipe",
     "stack_batch_host",
@@ -125,58 +127,61 @@ def stack_batch_host(
     those views.  Both paths run the same fill loop over pre-zeroed
     destinations, so a worker-staged slot is bit-identical to a
     consumer-staged allocation.
+
+    The whole fill is the span ``heta.stage.gather``.
     """
-    k, dp, P = recipe.num_layers, recipe.d_pad, recipe.num_shards
-    B = batch.batch_size
+    with obs.span("heta.stage.gather"):
+        k, dp, P = recipe.num_layers, recipe.d_pad, recipe.num_shards
+        B = batch.batch_size
 
-    res: Dict[str, np.ndarray] = {}
+        res: Dict[str, np.ndarray] = {}
 
-    def _dest(name: str, shape: Tuple[int, ...], dtype) -> np.ndarray:
-        if out is None:
-            arr = np.zeros(shape, dtype)
-        else:
-            arr = out[prefix + name].reshape(shape)
-            arr[...] = 0
-        return arr
+        def _dest(name: str, shape: Tuple[int, ...], dtype) -> np.ndarray:
+            if out is None:
+                arr = np.zeros(shape, dtype)
+            else:
+                arr = out[prefix + name].reshape(shape)
+                arr[...] = 0
+            return arr
 
-    for name, src in (("seeds", np.asarray(batch.seeds)),
-                      ("labels", np.asarray(batch.labels))):
-        if out is None:
-            res[name] = src
-        else:
-            np.copyto(out[prefix + name], src, casting="no")
-            res[name] = out[prefix + name]
+        for name, src in (("seeds", np.asarray(batch.seeds)),
+                          ("labels", np.asarray(batch.labels))):
+            if out is None:
+                res[name] = src
+            else:
+                np.copyto(out[prefix + name], src, casting="no")
+                res[name] = out[prefix + name]
 
-    n_prev = B
-    for d in range(1, k + 1):
-        sb = recipe.slot_branch[d - 1]
-        rb = sb.shape[1]
-        lv = batch.levels[d - 1]
-        n_d = lv.nids.shape[1]
-        mask = _dest(f"mask{d}", (P, rb, n_d), bool)
-        qfeat = _dest(f"qfeat{d}", (P, rb, n_prev, dp), np.float32)
-        hfeat = _dest(f"hfeat{d}", (P, rb, n_d, dp), np.float32) if d == k else None
-        for p in range(P):
-            for s in range(rb):
-                b = int(sb[p, s])
-                if b < 0:
-                    continue
-                mask[p, s] = lv.mask[b]
-                parent_nids = (
-                    batch.seeds if d == 1
-                    else batch.levels[d - 2].nids[recipe.parents[d - 1][b]]
-                )
-                _gather_into(qfeat[p, s],
-                             tables[recipe.dst_types[d - 1][b]], parent_nids)
-                if d == k:
-                    _gather_into(hfeat[p, s],
-                                 tables[recipe.src_types[d - 1][b]], lv.nids[b])
-        res[f"mask{d}"] = mask.reshape(P * rb, n_d)
-        res[f"qfeat{d}"] = qfeat.reshape(P * rb, n_prev, dp)
-        if d == k:
-            res[f"hfeat{d}"] = hfeat.reshape(P * rb, n_d, dp)
-        n_prev = n_d
-    return res
+        n_prev = B
+        for d in range(1, k + 1):
+            sb = recipe.slot_branch[d - 1]
+            rb = sb.shape[1]
+            lv = batch.levels[d - 1]
+            n_d = lv.nids.shape[1]
+            mask = _dest(f"mask{d}", (P, rb, n_d), bool)
+            qfeat = _dest(f"qfeat{d}", (P, rb, n_prev, dp), np.float32)
+            hfeat = _dest(f"hfeat{d}", (P, rb, n_d, dp), np.float32) if d == k else None
+            for p in range(P):
+                for s in range(rb):
+                    b = int(sb[p, s])
+                    if b < 0:
+                        continue
+                    mask[p, s] = lv.mask[b]
+                    parent_nids = (
+                        batch.seeds if d == 1
+                        else batch.levels[d - 2].nids[recipe.parents[d - 1][b]]
+                    )
+                    _gather_into(qfeat[p, s],
+                                 tables[recipe.dst_types[d - 1][b]], parent_nids)
+                    if d == k:
+                        _gather_into(hfeat[p, s],
+                                     tables[recipe.src_types[d - 1][b]], lv.nids[b])
+            res[f"mask{d}"] = mask.reshape(P * rb, n_d)
+            res[f"qfeat{d}"] = qfeat.reshape(P * rb, n_prev, dp)
+            if d == k:
+                res[f"hfeat{d}"] = hfeat.reshape(P * rb, n_d, dp)
+            n_prev = n_d
+        return res
 
 
 # --------------------------------------------------------------------------

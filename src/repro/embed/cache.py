@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.embed.profiler import (
     ADAM_STATE_MULT,
     HotnessProfile,
@@ -211,7 +212,12 @@ class FeatureCache:
 
     def fetch(self, ntype: str, nids: np.ndarray) -> jnp.ndarray:
         """Gather rows for ``nids``; cache hits read device memory, misses
-        transfer from host.  Returns a device array [len(nids), d]."""
+        transfer from host.  Returns a device array [len(nids), d].  The
+        span ``heta.cache.fetch``."""
+        with obs.span("heta.cache.fetch"):
+            return self._fetch(ntype, nids)
+
+    def _fetch(self, ntype: str, nids: np.ndarray) -> jnp.ndarray:
         with self._stats_lock:
             np.add.at(self._access[ntype], nids, 1.0)
         c = self.caches.get(ntype)
@@ -250,18 +256,21 @@ class FeatureCache:
         }
 
     def fetch_states(self, ntype: str, nids: np.ndarray):
-        """(rows, m, v) for a learnable type (row-aligned Adam states)."""
-        rows = self.fetch(ntype, nids)
-        c = self.caches.get(ntype)
-        if c is None or c.m is None:
-            return rows, jnp.asarray(self.host_m[ntype][nids]), jnp.asarray(self.host_v[ntype][nids])
-        slots = c.slot_of[nids]
-        hit = slots >= 0
-        m = np.asarray(self.host_m[ntype][nids])
-        v = np.asarray(self.host_v[ntype][nids])
-        m[hit] = np.asarray(c.m[jnp.asarray(slots[hit])])
-        v[hit] = np.asarray(c.v[jnp.asarray(slots[hit])])
-        return rows, jnp.asarray(m), jnp.asarray(v)
+        """(rows, m, v) for a learnable type (row-aligned Adam states); the
+        span ``heta.cache.fetch``."""
+        with obs.span("heta.cache.fetch"):
+            rows = self._fetch(ntype, nids)
+            c = self.caches.get(ntype)
+            if c is None or c.m is None:
+                return (rows, jnp.asarray(self.host_m[ntype][nids]),
+                        jnp.asarray(self.host_v[ntype][nids]))
+            slots = c.slot_of[nids]
+            hit = slots >= 0
+            m = np.asarray(self.host_m[ntype][nids])
+            v = np.asarray(self.host_v[ntype][nids])
+            m[hit] = np.asarray(c.m[jnp.asarray(slots[hit])])
+            v[hit] = np.asarray(c.v[jnp.asarray(slots[hit])])
+            return rows, jnp.asarray(m), jnp.asarray(v)
 
     # -- writes (learnable rows + optimizer states) ---------------------------
 

@@ -19,6 +19,7 @@ from typing import Dict, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.embed.cache import CacheAllocation, FeatureCache, allocate_cache
 from repro.embed.profiler import HotnessProfile, MissPenaltyProfile
 from repro.graph.hetgraph import HetGraph
@@ -101,9 +102,14 @@ class EmbedEngine:
     def tables_snapshot(self) -> Dict[str, np.ndarray]:
         """Coherent snapshot of every table — atomic w.r.t. concurrent
         :meth:`apply_row_grads` (the async pipeline's "stale" policy means a
-        snapshot may *lag*, never interleave a half-applied update)."""
-        with self.lock:
-            return {t: self.table(t) for t in self.graph.num_nodes}
+        snapshot may *lag*, never interleave a half-applied update).  The
+        span ``heta.stage.snapshot`` holds the copies and the device
+        read-backs; counter ``heta.stage.snapshot_bytes`` their bytes."""
+        with obs.span("heta.stage.snapshot"), self.lock:
+            tables = {t: self.table(t) for t in self.graph.num_nodes}
+            obs.count("heta.stage.snapshot_bytes",
+                      sum(tab.nbytes for tab in tables.values()))
+            return tables
 
     def fetch(self, ntype: str, nids: np.ndarray) -> jnp.ndarray:
         return self.cache.fetch(ntype, np.asarray(nids))
@@ -115,21 +121,23 @@ class EmbedEngine:
 
         ``nids`` may contain duplicates (multiple branches sample the same
         node); duplicates are summed into unique rows first, matching dense
-        autodiff semantics.
+        autodiff semantics.  The whole update is the span ``heta.update.adam``.
         """
         if ntype not in self.learnable_types:
             raise ValueError(f"{ntype} has fixed features")
-        nids = np.asarray(nids)
-        uniq, inv = np.unique(nids, return_inverse=True)
-        g = np.zeros((len(uniq), grads.shape[-1]), np.float32)
-        np.add.at(g, inv, np.asarray(grads, np.float32).reshape(len(nids), -1))
-        with self.lock:
-            rows, m, v = self.cache.fetch_states(ntype, uniq)
-            new_rows, new_m, new_v = sparse_adam_rows(
-                self.adam, rows, jnp.asarray(g), m, v, jnp.asarray(self.steps[ntype])
-            )
-            self.steps[ntype] += 1
-            self.cache.write_learnable(ntype, uniq, new_rows, new_m, new_v)
+        with obs.span("heta.update.adam"):
+            nids = np.asarray(nids)
+            uniq, inv = np.unique(nids, return_inverse=True)
+            g = np.zeros((len(uniq), grads.shape[-1]), np.float32)
+            np.add.at(g, inv,
+                      np.asarray(grads, np.float32).reshape(len(nids), -1))
+            with self.lock:
+                rows, m, v = self.cache.fetch_states(ntype, uniq)
+                new_rows, new_m, new_v = sparse_adam_rows(
+                    self.adam, rows, jnp.asarray(g), m, v,
+                    jnp.asarray(self.steps[ntype]))
+                self.steps[ntype] += 1
+                self.cache.write_learnable(ntype, uniq, new_rows, new_m, new_v)
 
     # -- checkpoint support (DESIGN.md §12) -------------------------------------
 

@@ -137,5 +137,6 @@ def flash_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="flash_attention_pallas",
         interpret=interpret,
     )(q, k, v)

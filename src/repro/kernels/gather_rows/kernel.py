@@ -50,6 +50,7 @@ def gather_rows_pallas(
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1, d), table.dtype),
+        name="gather_rows_pallas",
         interpret=interpret,
     )(idx.astype(jnp.int32), table.reshape(num_rows, 1, d))
     return out.reshape(n, d)

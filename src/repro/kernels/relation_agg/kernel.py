@@ -90,5 +90,6 @@ def relation_agg_pallas(
         out_specs=pl.BlockSpec((bn, bo), lambda i, o, c: (i, o)),
         out_shape=jax.ShapeDtypeStruct((n, d_out), h.dtype),
         scratch_shapes=[pltpu.VMEM((bn, bo), jnp.float32)],
+        name="relation_agg_pallas",
         interpret=interpret,
     )(h, mask, w, b)

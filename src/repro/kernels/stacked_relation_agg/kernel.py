@@ -134,6 +134,7 @@ def stacked_mean_linear_pallas(
         functools.partial(_mean_linear_kernel, n_chunks=grid[3]),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rb, n, d_out), h.dtype),
+        name="stacked_mean_linear_pallas",
         interpret=interpret,
     )(slot_u.astype(jnp.int32), h, mask, w, b)
 
@@ -200,6 +201,7 @@ def stacked_mean_linear_dh_pallas(
         functools.partial(_mean_linear_dh_kernel, n_chunks=grid[3]),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rb, n, f, d_in), g.dtype),
+        name="stacked_mean_linear_dh_pallas",
         interpret=interpret,
     )(slot_u.astype(jnp.int32), g, mask, w)
 
@@ -252,6 +254,7 @@ def stacked_softmax_combine_pallas(
         ],
         out_specs=pl.BlockSpec((1, bn, H), lambda s, i: (s, i, 0)),
         out_shape=jax.ShapeDtypeStruct((rb, n, H), e.dtype),
+        name="stacked_softmax_combine_pallas",
         interpret=interpret,
     )(e, mask, v)
 
@@ -431,6 +434,7 @@ def stacked_attn_epilogue_pallas(
         # to 128) overflow the 16 MiB default scoped VMEM once HGT carries
         # separate K and V projections; v5e has 128 MiB per core
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_EPILOGUE_VMEM_LIMIT),
+        name="stacked_attn_epilogue_pallas",
         interpret=interpret,
     )(us.astype(jnp.int32), *operands)
     return out if with_residuals else out[0]
@@ -506,5 +510,6 @@ def stacked_attn_dh_pallas(
         functools.partial(_attn_dh_kernel, shared_v=shared_v),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rb, n, f, d_in), dz.dtype),
+        name="stacked_attn_dh_pallas",
         interpret=interpret,
     )(us.astype(jnp.int32), *operands)
