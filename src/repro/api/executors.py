@@ -26,7 +26,9 @@ exposes graph / spec / assignment / engine / hgnn_cfg):
   ``stage(sess, plan, batch) -> arrays``
       host-side staging: turn a :class:`SampledBatch` into the device-ready
       arrays the step consumes (table snapshot / stack / shard for the SPMD
-      executor, ``batch_to_arrays`` for the dense ones).  Pure host work —
+      executor, or node ids for its compiled step to gather from a
+      device cache that holds every row; ``batch_to_arrays`` for the dense
+      ones).  Pure host work —
       the async pipeline (``repro.data``) runs it in a producer thread for
       batch *i+1* while batch *i* trains.
   ``step_staged(sess, plan, state, batch, arrays) -> (state, loss, step_time_s)``
@@ -75,6 +77,14 @@ from repro import obs
 __all__ = ["Executor", "register", "get", "available", "apply_feature_grads"]
 
 _REGISTRY: Dict[str, Type["Executor"]] = {}
+
+#: Backends whose device arrays are host memory.  The SPMD executor keeps
+#: host staging on them even over a cache that holds every row, so that CPU
+#: runs of the benchmark's tiny cells read the host path's spans and
+#: counters (the path a partial cache or a multi-device mesh takes on a
+#: chip).  The resident gather is for an accelerator, where it spares the
+#: cached rows a round trip over the host link every step.
+HOST_MEMORY_PLATFORMS = frozenset({"cpu"})
 
 
 def register(name: str):
@@ -353,39 +363,96 @@ class RafSpmdExecutor(Executor):
         )
         return {"stacks": stacks, "opt": adam_init(stacks)}
 
-    def stage(self, sess, plan, batch):
-        """Snapshot tables, stack the batch to branch-major arrays, shard.
-
-        When the pipeline pre-stages in a producer thread and learnable
-        tables are training, the snapshot may lag the device step by up to
-        ``pipeline.depth + 1`` steps — the documented ``"stale"`` policy
-        (``stage_reads_tables`` tells the stream when this applies)."""
+    def _resident(self, sess, plan):
+        """Each table staging reads as its cache's device rows, in node-id
+        order, when every one holds its whole table and the mesh is the one
+        accelerator they live on (not a backend of
+        :data:`HOST_MEMORY_PLATFORMS`); else None.  Observed afresh at
+        every stage and step: ``EmbedEngine.rebalance`` can change
+        residency, and learnable writes replace the rows.  The
+        multi-process tier's grad step (``dp_trainer``) takes staged
+        features, so it keeps host staging."""
         from repro.core import raf_spmd
 
+        if sess.config.scale.enabled or plan.mesh.devices.size != 1:
+            return None
+        dev = plan.mesh.devices.flat[0]
+        if dev.platform in HOST_MEMORY_PLATFORMS:
+            return None
+        caches = sess.engine.cache.resident(
+            raf_spmd.stack_recipe(plan.plan).table_types())
+        if caches is None:
+            return None
+        if any(c.data.devices() != {dev} for c in caches.values()):
+            return None
+        return {t: c.data for t, c in caches.items()}
+
+    def stage(self, sess, plan, batch):
+        """Stack the batch to branch-major arrays and shard them, by one of
+        two paths, chosen from what the session holds at this call:
+
+        * resident — every table the plan reads is wholly in the device
+          cache and the mesh is that one accelerator (:meth:`_resident`):
+          the host writes the rows' int32 node ids
+          (``staging.stack_batch_nids``) and the compiled
+          step gathers the feature rows from the cache's device arrays,
+          passed at step time (:meth:`_inputs`), so a rebalance or a
+          learnable write is seen by the next step.  No table snapshot is
+          taken.
+        * host — otherwise (a partial cache, no cache, a multi-device
+          mesh, the CPU backend): snapshot the tables and gather the
+          feature rows on the host (``raf_spmd.stack_batch``).  When the pipeline pre-stages
+          in a producer thread and learnable tables are training, the
+          snapshot may lag the device step by up to ``pipeline.depth + 1``
+          steps — the documented ``"stale"`` policy
+          (``stage_reads_tables`` tells the stream when this applies)."""
+        from repro.core import raf_spmd
+        from repro.data.staging import stack_batch_nids
+
+        resident = self._resident(sess, plan) is not None
         if not plan.learn_feats:
             # tables are static when features are frozen -> re-staging the
             # same batch (fixed-batch timing loops) would rebuild identical
             # arrays; memoize the last one
             cached = getattr(plan, "_stage_cache", None)
-            if cached is not None and cached[0] is batch:
-                return cached[1]
+            if cached is not None and cached[0] is batch and cached[1] == resident:
+                return cached[2]
         with obs.span("heta.stage"):
-            tables = sess.engine.tables_snapshot()
-            arrays = raf_spmd.shard_arrays(
-                plan.plan, plan.mesh,
-                raf_spmd.stack_batch(plan.plan, batch, tables))
+            if resident:
+                host = stack_batch_nids(raf_spmd.stack_recipe(plan.plan), batch)
+            else:
+                host = raf_spmd.stack_batch(plan.plan, batch,
+                                            sess.engine.tables_snapshot())
+            arrays = raf_spmd.shard_arrays(plan.plan, plan.mesh, host)
         if not plan.learn_feats:
-            plan._stage_cache = (batch, arrays)
+            plan._stage_cache = (batch, resident, arrays)
         return arrays
 
+    def _inputs(self, sess, plan, batch, arrays) -> tuple:
+        """The compiled step's arguments after the state: host-staged
+        feature arrays as they are; node ids (resident staging) with each
+        table's device rows as the cache holds them now.  A
+        batch staged as node ids whose tables have since left the device
+        (a rebalance to a partial cache) is staged again, on the host."""
+        if "qnid1" not in arrays:
+            return (arrays,)
+        tables = self._resident(sess, plan)
+        if tables is None:
+            return (self.stage(sess, plan, batch),)
+        return arrays, tables
+
     def stage_reads_tables(self, sess, plan) -> bool:
-        return bool(plan.learn_feats)
+        """Host staging reads the learnable tables while they train;
+        resident staging reads no table."""
+        return bool(plan.learn_feats) and self._resident(sess, plan) is None
 
     def worker_stage_recipe(self, sess, plan):
-        """With frozen tables the whole host side of :meth:`stage` — the
-        padded feature gathers of ``stack_batch`` — can run inside sampler
-        workers against tables exported into the shm store or batch arena;
-        the consumer only device-puts.
+        """On the resident path (see :meth:`stage`) None: staging only
+        writes node ids and stays with the consumer, so workers only
+        sample.  Otherwise, with frozen tables the whole host side of
+        :meth:`stage` — the padded feature gathers of ``stack_batch`` — can
+        run inside sampler workers against tables exported into the shm
+        store or batch arena; the consumer only device-puts.
 
         While learnable tables train, workers normally cannot see the
         trainer's row updates, so staging stays consumer-side (None) —
@@ -394,6 +461,8 @@ class RafSpmdExecutor(Executor):
         seqlock'd table region after every step, so workers stage against
         bounded-stale snapshots (staleness ≤ ring depth, DESIGN.md §11 —
         the same contract the thread pipeline's ``"stale"`` policy makes)."""
+        if self._resident(sess, plan) is not None:
+            return None
         if plan.learn_feats:
             p = sess.config.pipeline
             if not (p.arena and p.num_workers > 0 and p.snapshot == "stale"):
@@ -423,8 +492,9 @@ class RafSpmdExecutor(Executor):
         waiting for the loss first adds no sync and leaves the wait to
         ``heta.device.wait`` alone."""
         with obs.span(obs.DEVICE) as dev:
-            stacks, opt, loss, *gf = plan.step(state["stacks"], state["opt"],
-                                               arrays)
+            stacks, opt, loss, *gf = plan.step(
+                state["stacks"], state["opt"],
+                *self._inputs(sess, plan, batch, arrays))
             with obs.span("heta.device.wait"):
                 loss = float(loss)
             if plan.learn_feats:
@@ -432,7 +502,9 @@ class RafSpmdExecutor(Executor):
         return {"stacks": stacks, "opt": opt}, loss, dev.seconds
 
     def loss_and_metrics(self, sess, plan, state, batch):
-        loss = float(plan.loss(state["stacks"], self.stage(sess, plan, batch)))
+        staged = self.stage(sess, plan, batch)
+        loss = float(plan.loss(state["stacks"],
+                               *self._inputs(sess, plan, batch, staged)))
         return loss, {"loss": loss, "hit_rates": sess.engine.cache.hit_rates()}
 
 

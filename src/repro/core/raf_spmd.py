@@ -58,6 +58,7 @@ __all__ = [
     "stack_params_from_dict",
     "stack_batch",
     "stack_recipe",
+    "gather_resident",
     "raf_spmd_forward",
     "sync_stack_grads",
     "make_loss_fn",
@@ -319,11 +320,50 @@ def stack_batch(
     and consumer-staged batches are bit-identical by construction.  Putting
     its arrays on the device is the span ``heta.stage.put`` (counter
     ``heta.stage.put_bytes``: their bytes).
+
+    This is the host path.  Where every table is wholly in the device cache
+    on a one-accelerator mesh, the training executor stages node ids instead
+    (:func:`repro.data.staging.stack_batch_nids`) and the compiled step
+    gathers the rows (:func:`gather_resident`); full-graph inference keeps
+    this path.
     """
     host = stack_batch_host(stack_recipe(plan), batch, tables)
     with obs.span("heta.stage.put"):
         obs.count("heta.stage.put_bytes", sum(v.nbytes for v in host.values()))
         return {k: jnp.asarray(v) for k, v in host.items()}
+
+
+def gather_resident(plan: StackedPlan, arrays: Dict, tables=None) -> Dict:
+    """The device half of resident staging (traceable; inside the compiled
+    step).  ``arrays`` carry the int32 node ids ``qnid{d}``/``hnid{k}`` of
+    ``repro.data.staging.stack_batch_nids``; ``tables`` maps each node type
+    to its cache's device rows, which a cache that holds every row keeps in
+    node-id order.  Builds ``qfeat{d}``/``hfeat{k}`` with XLA's gather, one
+    ``take`` per branch slot from the slot's static source type, widths
+    below ``d_pad`` zero-padded and padding slots zero: the arrays
+    ``stack_batch_host`` builds on the host, bit for bit.  With ``tables=None`` the arrays already hold the features
+    and pass through unchanged."""
+    if tables is None:
+        return arrays
+    out = dict(arrays)
+    k = plan.spec.num_layers
+    for d in range(1, k + 1):
+        sb = plan.levels[d - 1].slot_branch.reshape(-1)
+        for kind, types in (("q", plan.dst_types[d - 1]),
+                            ("h", plan.src_types[d - 1])):
+            if kind == "h" and d != k:
+                continue
+            nids = out.pop(f"{kind}nid{d}")  # [P*rb, n]
+            rows = []
+            for i, b in enumerate(sb):
+                if b < 0:
+                    rows.append(jnp.zeros((nids.shape[1], plan.d_pad), jnp.float32))
+                    continue
+                tab = tables[types[b]]
+                r = jnp.take(tab, nids[i], axis=0, mode="clip").astype(jnp.float32)
+                rows.append(jnp.pad(r, ((0, 0), (0, plan.d_pad - tab.shape[1]))))
+            out[f"{kind}feat{d}"] = jnp.stack(rows)
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -460,8 +500,10 @@ def _array_specs(plan: StackedPlan, data_axes, model_axis):
     for d in range(1, k + 1):
         specs[f"mask{d}"] = P(model_axis, data_axes)
         specs[f"qfeat{d}"] = P(model_axis, data_axes, None)
+        specs[f"qnid{d}"] = P(model_axis, data_axes)
         if d == k:
             specs[f"hfeat{d}"] = P(model_axis, data_axes, None)
+            specs[f"hnid{d}"] = P(model_axis, data_axes)
     return specs
 
 
@@ -539,13 +581,15 @@ def make_loss_fn(
     local_combine: bool = True,
     kernels=None,
 ):
-    """Jitted evaluation-only loss: ``loss(stacks, arrays) -> scalar``."""
+    """Jitted evaluation-only loss: ``loss(stacks, arrays[, tables]) ->
+    scalar``; with ``tables`` the arrays are resident staging's node ids
+    (:func:`gather_resident`)."""
     loss_fn, split_arrays = _build_loss_fn(plan, mesh, model_axis, data_axes,
                                            local_combine, kernels)
 
     @jax.jit
-    def eval_loss(stacks, arrays):
-        feats, rest = split_arrays(arrays)
+    def eval_loss(stacks, arrays, tables=None):
+        feats, rest = split_arrays(gather_resident(plan, arrays, tables))
         return loss_fn(stacks, feats, rest)
 
     return eval_loss
@@ -563,7 +607,13 @@ def make_train_step(
 ):
     """Build the jitted SPMD RAF train step.
 
-    ``step(stacks, opt_state, arrays) -> (stacks, opt_state, loss[, feat_grads])``
+    ``step(stacks, opt_state, arrays[, tables]) -> (stacks, opt_state, loss[, feat_grads])``
+
+    ``arrays`` hold the staged features (``stack_batch``), or with
+    ``tables`` — each node type's device cache rows, a jit argument,
+    neither baked in nor donated — resident staging's node ids,
+    which the step gathers into the same feature arrays first
+    (:func:`gather_resident`, outside the differentiated function).
 
     The shard_map body computes the root embedding (ending in the RAF psum);
     the classifier head + loss run outside under GSPMD, so gradients of the
@@ -584,8 +634,8 @@ def make_train_step(
         grad_fn = jax.value_and_grad(loss_fn)
 
         @partial(jax.jit, donate_argnums=(0, 1))
-        def step(stacks, opt_state, arrays):
-            feats, rest = split_arrays(arrays)
+        def step(stacks, opt_state, arrays, tables=None):
+            feats, rest = split_arrays(gather_resident(plan, arrays, tables))
             loss, grads = grad_fn(stacks, feats, rest)
             grads = sync_stack_grads(plan, grads)
             stacks, opt_state = adam_update(adam_cfg, stacks, grads, opt_state)
@@ -596,8 +646,8 @@ def make_train_step(
     grad_fn2 = jax.value_and_grad(loss_fn, argnums=(0, 1))
 
     @partial(jax.jit, donate_argnums=(0, 1))
-    def step_feats(stacks, opt_state, arrays):
-        feats, rest = split_arrays(arrays)
+    def step_feats(stacks, opt_state, arrays, tables=None):
+        feats, rest = split_arrays(gather_resident(plan, arrays, tables))
         loss, (gs, gf) = grad_fn2(stacks, feats, rest)
         gs = sync_stack_grads(plan, gs)
         stacks, opt_state = adam_update(adam_cfg, stacks, gs, opt_state)

@@ -3,8 +3,21 @@
 ``repro.core.raf_spmd.stack_batch`` assembles the stacked device arrays for
 one sampled batch — masks, padded parent-feature gathers (``qfeat``), and
 leaf-feature gathers (``hfeat``) laid out branch-major per shard.  All of
-that work is pure numpy; only the final device placement needs jax.  This
-module holds the numpy core so that:
+that work is pure numpy; only the final device placement needs jax.
+
+Staging takes one of two forms, both filled by one loop (``_fill``):
+
+  * host — :func:`stack_batch_host` gathers the feature rows from host
+    tables (a snapshot of the cache engine's tables) into float32 arrays;
+  * resident — when every table the recipe reads is wholly in the device
+    cache and the mesh is that one accelerator (the SPMD executor checks
+    this at every stage; the CPU backend keeps the host form),
+    :func:`stack_batch_nids` writes each row's int32 node id instead, and
+    the compiled step gathers the rows from the cache's device arrays
+    (``raf_spmd.gather_resident``).  No table is read on the host, and the
+    put shrinks by the feature width.
+
+This module holds the numpy core so that:
 
   * the SPMD executor's ``stage`` and the multi-worker sampling pool
     (``repro.data.worker_pool``, DESIGN.md §9) run the **same** code — a
@@ -34,6 +47,7 @@ from repro import obs
 __all__ = [
     "StackRecipe",
     "stack_batch_host",
+    "stack_batch_nids",
     "BATCH_PREFIX",
     "HOST_PREFIX",
     "pack_batch_arrays",
@@ -105,6 +119,48 @@ def _gather_into(dst: np.ndarray, tab: np.ndarray, nids: np.ndarray) -> None:
     dst[:, : tab.shape[1]] = tab[nids]
 
 
+def _fill(recipe: StackRecipe, batch, res: Dict[str, np.ndarray], dest,
+          q_name: str, h_name: str, row: Tuple[int, ...], dtype, write) -> int:
+    """The fill loop both staging forms share, into ``res``: per level
+    ``mask{d}``, the parents' rows (``q_name``) and, at the leaf level, the
+    sampled neighbors' rows (``h_name``), each ``[P*rb, n] + row`` of
+    ``dtype``.  ``dest(name, shape, dtype)`` gives a zeroed destination;
+    ``write(dst, ntype, nids)`` fills one branch slot.  Padding slots
+    (``slot_branch`` -1) stay zero.  Returns the number of rows written."""
+    k, P = recipe.num_layers, recipe.num_shards
+    rows = 0
+    n_prev = batch.batch_size
+    for d in range(1, k + 1):
+        sb = recipe.slot_branch[d - 1]
+        rb = sb.shape[1]
+        lv = batch.levels[d - 1]
+        n_d = lv.nids.shape[1]
+        mask = dest(f"mask{d}", (P, rb, n_d), bool)
+        q = dest(f"{q_name}{d}", (P, rb, n_prev) + row, dtype)
+        h = dest(f"{h_name}{d}", (P, rb, n_d) + row, dtype) if d == k else None
+        for p in range(P):
+            for s in range(rb):
+                b = int(sb[p, s])
+                if b < 0:
+                    continue
+                mask[p, s] = lv.mask[b]
+                parent_nids = (
+                    batch.seeds if d == 1
+                    else batch.levels[d - 2].nids[recipe.parents[d - 1][b]]
+                )
+                write(q[p, s], recipe.dst_types[d - 1][b], parent_nids)
+                rows += len(parent_nids)
+                if d == k:
+                    write(h[p, s], recipe.src_types[d - 1][b], lv.nids[b])
+                    rows += len(lv.nids[b])
+        res[f"mask{d}"] = mask.reshape(P * rb, n_d)
+        res[f"{q_name}{d}"] = q.reshape((P * rb, n_prev) + row)
+        if d == k:
+            res[f"{h_name}{d}"] = h.reshape((P * rb, n_d) + row)
+        n_prev = n_d
+    return rows
+
+
 def stack_batch_host(
     recipe: StackRecipe,
     batch,
@@ -128,22 +184,19 @@ def stack_batch_host(
     destinations, so a worker-staged slot is bit-identical to a
     consumer-staged allocation.
 
-    The whole fill is the span ``heta.stage.gather``.
+    The whole fill is the span ``heta.stage.gather``; counter
+    ``heta.stage.host_rows``: the feature rows gathered here.
     """
+
+    def dest(name, shape, dtype):
+        if out is None:
+            return np.zeros(shape, dtype)
+        arr = out[prefix + name].reshape(shape)
+        arr[...] = 0
+        return arr
+
     with obs.span("heta.stage.gather"):
-        k, dp, P = recipe.num_layers, recipe.d_pad, recipe.num_shards
-        B = batch.batch_size
-
         res: Dict[str, np.ndarray] = {}
-
-        def _dest(name: str, shape: Tuple[int, ...], dtype) -> np.ndarray:
-            if out is None:
-                arr = np.zeros(shape, dtype)
-            else:
-                arr = out[prefix + name].reshape(shape)
-                arr[...] = 0
-            return arr
-
         for name, src in (("seeds", np.asarray(batch.seeds)),
                           ("labels", np.asarray(batch.labels))):
             if out is None:
@@ -151,36 +204,39 @@ def stack_batch_host(
             else:
                 np.copyto(out[prefix + name], src, casting="no")
                 res[name] = out[prefix + name]
+        rows = _fill(recipe, batch, res, dest, "qfeat", "hfeat",
+                     (recipe.d_pad,), np.float32,
+                     lambda dst, t, nids: _gather_into(dst, tables[t], nids))
+        obs.count("heta.stage.host_rows", rows)
+        return res
 
-        n_prev = B
-        for d in range(1, k + 1):
-            sb = recipe.slot_branch[d - 1]
-            rb = sb.shape[1]
-            lv = batch.levels[d - 1]
-            n_d = lv.nids.shape[1]
-            mask = _dest(f"mask{d}", (P, rb, n_d), bool)
-            qfeat = _dest(f"qfeat{d}", (P, rb, n_prev, dp), np.float32)
-            hfeat = _dest(f"hfeat{d}", (P, rb, n_d, dp), np.float32) if d == k else None
-            for p in range(P):
-                for s in range(rb):
-                    b = int(sb[p, s])
-                    if b < 0:
-                        continue
-                    mask[p, s] = lv.mask[b]
-                    parent_nids = (
-                        batch.seeds if d == 1
-                        else batch.levels[d - 2].nids[recipe.parents[d - 1][b]]
-                    )
-                    _gather_into(qfeat[p, s],
-                                 tables[recipe.dst_types[d - 1][b]], parent_nids)
-                    if d == k:
-                        _gather_into(hfeat[p, s],
-                                     tables[recipe.src_types[d - 1][b]], lv.nids[b])
-            res[f"mask{d}"] = mask.reshape(P * rb, n_d)
-            res[f"qfeat{d}"] = qfeat.reshape(P * rb, n_prev, dp)
-            if d == k:
-                res[f"hfeat{d}"] = hfeat.reshape(P * rb, n_d, dp)
-            n_prev = n_d
+
+def stack_batch_nids(recipe: StackRecipe, batch) -> Dict[str, np.ndarray]:
+    """The resident form of :func:`stack_batch_host`, for when every table
+    the recipe reads is wholly in the device cache: the same ``seeds``,
+    ``labels`` and ``mask{d}``, and in place of ``qfeat{d}``/``hfeat{k}``
+    the int32 node ids of those rows, ``qnid{d}``/``hnid{k}``, each
+    ``[P*rb, n]`` in the layout of the features it replaces.  A cache that
+    holds every row keeps it in node-id order, so the compiled step
+    (``raf_spmd.gather_resident``) gathers the rows by node id on the
+    device, padding slots zero, and its arrays equal this function's host
+    twin bit for bit.
+
+    The fill is the span ``heta.stage.gather``; counter
+    ``heta.stage.device_rows``: the rows the step will gather on the
+    device.
+    """
+
+    def write(dst, t, nids):
+        dst[:] = nids
+
+    with obs.span("heta.stage.gather"):
+        res = {"seeds": np.asarray(batch.seeds),
+               "labels": np.asarray(batch.labels)}
+        rows = _fill(recipe, batch, res,
+                     lambda name, shape, dtype: np.zeros(shape, dtype),
+                     "qnid", "hnid", (), np.int32, write)
+        obs.count("heta.stage.device_rows", rows)
         return res
 
 

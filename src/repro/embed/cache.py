@@ -142,6 +142,16 @@ class _TypeCache:
     misses: int = 0
 
 
+def _slot_order(ids: np.ndarray, num_nodes: int) -> np.ndarray:
+    """The order a type's cached rows take on the device: a cache that holds
+    every row keeps them in node-id order, so a node id is its own slot and
+    a compiled step gathers rows by node id (resident staging,
+    ``raf_spmd.gather_resident``); a partial cache keeps ``ids``' order."""
+    if len(ids) == num_nodes:
+        return np.arange(num_nodes, dtype=np.int64)
+    return ids
+
+
 class FeatureCache:
     """Device cache over host tables with per-type budgets.
 
@@ -188,7 +198,7 @@ class FeatureCache:
         for t, n_rows in allocation.rows.items():
             if n_rows <= 0 or t not in self.host:
                 continue
-            ids = hotness.hottest(t, n_rows)
+            ids = _slot_order(hotness.hottest(t, n_rows), self.host[t].shape[0])
             slot_of = np.full(self.host[t].shape[0], -1, dtype=np.int64)
             slot_of[ids] = np.arange(len(ids))
             self.caches[t] = _TypeCache(
@@ -201,6 +211,18 @@ class FeatureCache:
             )
 
     # -- reads --------------------------------------------------------------
+
+    def resident(self, types) -> Optional[Dict[str, _TypeCache]]:
+        """The caches of ``types`` when each holds every row of its host
+        table, so the whole table is on the device in node-id order
+        (:func:`_slot_order`); else None."""
+        out = {}
+        for t in types:
+            c = self.caches.get(t)
+            if c is None or len(c.ids) != self.host[t].shape[0]:
+                return None
+            out[t] = c
+        return out
 
     def _device_gather(self, data: jnp.ndarray, slots: np.ndarray) -> jnp.ndarray:
         """Device-side row gather of cached rows — the paper-§6 cache fetch
@@ -341,10 +363,10 @@ class FeatureCache:
             old = self.caches.get(t)
             if n_rows <= 0 and old is None:
                 continue
-            new_ids = (
+            new_ids = _slot_order(
                 np.asarray(hotness.hottest(t, n_rows), np.int64)
-                if n_rows > 0 else np.zeros(0, np.int64)
-            )
+                if n_rows > 0 else np.zeros(0, np.int64),
+                self.host[t].shape[0])
             old_slots = (
                 old.slot_of[new_ids] if old is not None
                 else np.full(len(new_ids), -1, np.int64)
@@ -441,7 +463,7 @@ class FeatureCache:
         for t, ids in ids_by_type.items():
             if t not in self.host:
                 continue
-            ids = np.asarray(ids, np.int64)
+            ids = _slot_order(np.asarray(ids, np.int64), self.host[t].shape[0])
             slot_of = np.full(self.host[t].shape[0], -1, dtype=np.int64)
             slot_of[ids] = np.arange(len(ids))
             learn = t in self.learnable

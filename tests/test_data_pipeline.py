@@ -324,6 +324,29 @@ def test_sample_stream_facade_validates_modes():
 # --------------------------------------------------------------------------
 
 
+# On the CPU backend raf_spmd stages on the host, in the workers where the
+# executor lets them.  With the resident gather let engage there
+# (``device_gather_on_cpu``), the default 4 MiB cache holds the whole tiny
+# graph, so raf_spmd stages node ids on the consumer and the workers only
+# sample.
+
+
+def _staged_in_workers(sess) -> bool:
+    """Whether the pool's workers staged the batches: the executor gave
+    them a staging recipe and the consumer gathered no row itself."""
+    return (sess.executor.worker_stage_recipe(sess, sess.plan) is not None
+            and "heta.stage.gather" not in sess.results()["spans"])
+
+
+def _only_sampled_in_workers(sess) -> bool:
+    """Whether the pool's workers only sampled: no staging recipe, and the
+    consumer staged node ids for the device gather."""
+    c = sess.results()["counters"]
+    return (sess.executor.worker_stage_recipe(sess, sess.plan) is None
+            and c.get("heta.stage.device_rows", 0) > 0
+            and "heta.stage.host_rows" not in c)
+
+
 @pytest.mark.parametrize("executor", ["vanilla", "raf", "raf_spmd"])
 def test_worker_pool_parity_all_executors(executor):
     from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
@@ -342,7 +365,10 @@ def test_worker_pool_parity_all_executors(executor):
             c = c.updated(pipeline=dict(enabled=True, num_workers=workers))
         sess = Heta(c)
         try:
-            return sess.run()
+            r = sess.run()
+            if workers and executor == "raf_spmd":
+                assert _staged_in_workers(sess), workers
+            return r
         finally:
             sess.close_pipeline()
 
@@ -362,31 +388,58 @@ def test_worker_pool_legacy_pickle_path_still_bit_identical():
     """``pipeline.arena=False`` keeps the PR-5 pickle transport: same
     batches, same losses, megabyte queue items (the cost the arena
     removes)."""
-    from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
-                           PartitionConfig, RunConfig)
     from repro.graph.shm import live_segments
 
-    def run(arena):
-        c = HetaConfig(
-            data=DataConfig(dataset="ogbn-mag", scale=0.002, fanouts=(3, 2),
-                            batch_size=16),
-            partition=PartitionConfig(num_partitions=2),
-            model=ModelConfig(hidden=32, train_learnable=False),
-            run=RunConfig(executor="raf_spmd", steps=3, lr=1e-2, seed=0),
-        ).updated(pipeline=dict(enabled=True, num_workers=2, arena=arena))
-        sess = Heta(c)
-        try:
-            return sess.run()
-        finally:
-            sess.close_pipeline()
-
-    on, off = run(True), run(False)
+    (on, on_staged), (off, off_staged) = (_pickle_or_arena_run(True),
+                                          _pickle_or_arena_run(False))
+    assert on_staged and off_staged
     assert on["losses"] == off["losses"]
     assert on["queue_bytes_per_step"] < 1024 < off["queue_bytes_per_step"]
     assert not live_segments()
 
 
+def _pickle_or_arena_run(arena, check=_staged_in_workers):
+    from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
+                           PartitionConfig, RunConfig)
+
+    c = HetaConfig(
+        data=DataConfig(dataset="ogbn-mag", scale=0.002, fanouts=(3, 2),
+                        batch_size=16),
+        partition=PartitionConfig(num_partitions=2),
+        model=ModelConfig(hidden=32, train_learnable=False),
+        run=RunConfig(executor="raf_spmd", steps=3, lr=1e-2, seed=0),
+    ).updated(pipeline=dict(enabled=True, num_workers=2, arena=arena))
+    sess = Heta(c)
+    try:
+        return sess.run(), check(sess)
+    finally:
+        sess.close_pipeline()
+
+
+def test_worker_pool_resident_cache_pickle_and_arena_bit_identical(
+        device_gather_on_cpu):
+    """With a cache that holds every row the workers only sample, by either
+    transport, and both train as the serial loop does."""
+    from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
+                           PartitionConfig, RunConfig)
+
+    serial = Heta(HetaConfig(
+        data=DataConfig(dataset="ogbn-mag", scale=0.002, fanouts=(3, 2),
+                        batch_size=16),
+        partition=PartitionConfig(num_partitions=2),
+        model=ModelConfig(hidden=32, train_learnable=False),
+        run=RunConfig(executor="raf_spmd", steps=3, lr=1e-2, seed=0),
+    )).run()
+    for arena in (True, False):
+        r, sampled_only = _pickle_or_arena_run(arena,
+                                               check=_only_sampled_in_workers)
+        assert sampled_only, arena
+        assert r["losses"] == serial["losses"], arena
+
+
 def _learnable_run(workers, snapshot="stale", arena=True):
+    """A learnable raf_spmd fit; its result gains ``staged_in_workers`` and
+    ``only_sampled_in_workers``."""
     from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
                            PartitionConfig, RunConfig)
 
@@ -402,7 +455,10 @@ def _learnable_run(workers, snapshot="stale", arena=True):
                                     snapshot=snapshot, arena=arena))
     sess = Heta(c)
     try:
-        return sess.run()
+        r = sess.run()
+        r["staged_in_workers"] = _staged_in_workers(sess)
+        r["only_sampled_in_workers"] = _only_sampled_in_workers(sess)
+        return r
     finally:
         sess.close_pipeline()
 
@@ -424,7 +480,20 @@ def test_worker_pool_learnable_stale_stages_in_workers_bounded():
     (SlotRef descriptors only)."""
     serial = _learnable_run(None)
     stale = _learnable_run(2, snapshot="stale")
+    assert stale["staged_in_workers"]
     assert np.allclose(serial["losses"], stale["losses"], atol=5e-2)
+    assert 0 < stale["queue_bytes_per_step"] < 1024  # descriptors, not arrays
+
+
+def test_worker_pool_learnable_stale_resident_cache_is_bit_exact(
+        device_gather_on_cpu):
+    """With a cache that holds every row, staging reads no table (node ids;
+    the step gathers the rows it updates), so under the "stale" policy the
+    workers only sample and pooled losses are bit-exact."""
+    serial = _learnable_run(None)
+    stale = _learnable_run(2, snapshot="stale")
+    assert stale["only_sampled_in_workers"]
+    assert serial["losses"] == stale["losses"]
     assert 0 < stale["queue_bytes_per_step"] < 1024  # descriptors, not arrays
 
 
@@ -432,9 +501,25 @@ def test_pool_persists_across_fits_and_stays_bit_identical():
     """Consecutive fit() calls reuse one pool + shm store (spawn amortized)
     and the two-fit pooled trajectory equals one serial fit of the same
     length; a serial step() in between forces a clean respawn."""
+    from repro.graph.shm import live_segments
+
+    sess, serial = _two_fits_pooled()
+    # the workers staged every pooled batch; the consumer only the serial one
+    assert sess.executor.worker_stage_recipe(sess, sess.plan) is not None
+    assert sess.results()["spans"]["heta.stage.gather"]["count"] == 1
+    assert sess.losses == serial["losses"]
+    sess.close_pipeline()
+    assert sess._pool_cache is None
+    sess.close_pipeline()  # idempotent
+    assert not live_segments()
+
+
+def _two_fits_pooled():
+    """Two pooled fits, a serial step, and a pooled fit again (the pool
+    reused, then respawned) on a session, and the serial run of as many
+    steps; the session is returned open."""
     from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
                            PartitionConfig, RunConfig)
-    from repro.graph.shm import live_segments
 
     def cfg(workers=None):
         c = HetaConfig(
@@ -442,7 +527,7 @@ def test_pool_persists_across_fits_and_stays_bit_identical():
                             batch_size=16),
             partition=PartitionConfig(num_partitions=2),
             model=ModelConfig(hidden=32, train_learnable=False),
-            run=RunConfig(executor="raf_spmd", steps=6, lr=1e-2, seed=0),
+                run=RunConfig(executor="raf_spmd", steps=6, lr=1e-2, seed=0),
         )
         if workers is not None:
             c = c.updated(pipeline=dict(enabled=True, num_workers=workers))
@@ -459,11 +544,18 @@ def test_pool_persists_across_fits_and_stays_bit_identical():
     sess.step()  # serial step desyncs the stripe position...
     sess.fit(1)
     assert sess._pool_cache[2] is not pool_a  # ...so the pool respawned
-    assert sess.losses == serial["losses"]
-    sess.close_pipeline()
-    assert sess._pool_cache is None
-    sess.close_pipeline()  # idempotent
-    assert not live_segments()
+    return sess, serial
+
+
+def test_pool_persists_across_fits_on_the_resident_path(device_gather_on_cpu):
+    """The persistent pool on a cache that holds every row: the workers
+    only sample, and the pooled trajectory still equals the serial one."""
+    sess, serial = _two_fits_pooled()
+    try:
+        assert _only_sampled_in_workers(sess)
+        assert sess.losses == serial["losses"]
+    finally:
+        sess.close_pipeline()
 
 
 def test_evaluate_with_workers_matches_serial():

@@ -225,6 +225,33 @@ def test_session_books_on_the_thread_pipeline():
         assert sess.host_times[s] == pytest.approx(sum(r.seconds for r in host))
 
 
+@pytest.mark.parametrize("cache_mb,path", [(6, "device"), (2, "host")],
+                         ids=["resident", "partial"])
+def test_staging_rows_are_counted_once_a_step_on_the_path_taken(
+        cache_mb, path, device_gather_on_cpu):
+    """A cache that holds every row gives device rows (the step gathers
+    them from the cache); a partial one host rows (``stack_batch_host``).
+    Each step counts its rows once, in its one ``heta.stage.gather``."""
+    sess = Heta(_tiny().updated(cache=dict(cache_mb=cache_mb)))
+    sess.build_graph()
+    sess.partition()
+    sess.profile_and_cache()
+    sess.compile()
+    m = sess.fit(steps=3)
+    recs = _mine(sess.obs_session)
+    other = {"device": "host", "host": "device"}[path]
+    per_step = []
+    for s in range(3):
+        gathers = [r for r in recs if r.step == s and r.name == "heta.stage.gather"]
+        assert len(gathers) == 1
+        counts = gathers[0].counts
+        assert f"heta.stage.{other}_rows" not in counts
+        per_step.append(counts[f"heta.stage.{path}_rows"])
+    assert len(set(per_step)) == 1 and per_step[0] > 0
+    assert m["counters"][f"heta.stage.{path}_rows"] == sum(per_step)
+    assert ("heta.stage.snapshot" in m["spans"]) == (path == "host")
+
+
 def test_learnable_steps_split_wait_pull_and_adam():
     sess = Heta(_tiny().updated(model=dict(hidden=32, train_learnable=True)))
     sess.build_graph()
