@@ -126,7 +126,11 @@ def _fill(recipe: StackRecipe, batch, res: Dict[str, np.ndarray], dest,
     sampled neighbors' rows (``h_name``), each ``[P*rb, n] + row`` of
     ``dtype``.  ``dest(name, shape, dtype)`` gives a zeroed destination;
     ``write(dst, ntype, nids)`` fills one branch slot.  Padding slots
-    (``slot_branch`` -1) stay zero.  Returns the number of rows written."""
+    (``slot_branch`` -1) stay zero.  Returns the number of rows written.
+
+    Counters: ``heta.stage.edge_slots``, the sampled edge slots the levels'
+    aggregations take (each level's ``R_d x N_d`` mask as staged), and
+    ``heta.stage.valid_slots``, those whose mask is true."""
     k, P = recipe.num_layers, recipe.num_shards
     rows = 0
     n_prev = batch.batch_size
@@ -153,6 +157,8 @@ def _fill(recipe: StackRecipe, batch, res: Dict[str, np.ndarray], dest,
                 if d == k:
                     write(h[p, s], recipe.src_types[d - 1][b], lv.nids[b])
                     rows += len(lv.nids[b])
+        obs.count("heta.stage.edge_slots", mask.size)
+        obs.count("heta.stage.valid_slots", int(np.count_nonzero(mask)))
         res[f"mask{d}"] = mask.reshape(P * rb, n_d)
         res[f"{q_name}{d}"] = q.reshape((P * rb, n_prev) + row)
         if d == k:
@@ -185,7 +191,8 @@ def stack_batch_host(
     consumer-staged allocation.
 
     The whole fill is the span ``heta.stage.gather``; counter
-    ``heta.stage.host_rows``: the feature rows gathered here.
+    ``heta.stage.host_rows``: the feature rows gathered here (and the edge
+    slot counters of ``_fill``).
     """
 
     def dest(name, shape, dtype):
@@ -224,7 +231,7 @@ def stack_batch_nids(recipe: StackRecipe, batch) -> Dict[str, np.ndarray]:
 
     The fill is the span ``heta.stage.gather``; counter
     ``heta.stage.device_rows``: the rows the step will gather on the
-    device.
+    device (and the edge slot counters of ``_fill``).
     """
 
     def write(dst, t, nids):

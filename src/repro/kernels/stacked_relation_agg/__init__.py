@@ -12,6 +12,8 @@ from repro.kernels.stacked_relation_agg.ops import (  # noqa: F401
     stacked_agg,
     stacked_agg_grouped,
     stacked_agg_ref,
+    stacked_attn_bwd_block,
+    stacked_attn_bwd_vmem_bytes,
     stacked_attn_epilogue,
     stacked_attn_epilogue_vmem_bytes,
     stacked_mean_linear,

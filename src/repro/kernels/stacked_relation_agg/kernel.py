@@ -11,7 +11,7 @@ the gather-then-vmap path pays every step ("Characterizing and
 Understanding HGNN Training on GPUs" finds exactly this redundant parameter
 movement dominating HGNN kernels; HiHGNN builds on the same reusability).
 
-Three kernels:
+The kernels:
 
   * :func:`stacked_mean_linear_pallas` — the rgcn-family AGG_r: masked-mean
     over the fanout fused with the output projection.  Grid (slot, node
@@ -32,14 +32,16 @@ Three kernels:
     indirection, accumulate across d_in chunks in float32 VMEM scratch,
     and feed the masked softmax + combine epilogue in the same grid step —
     neither the projected logits/values *nor* a gathered weight copy ever
-    round-trips through HBM on the forward.  Optional per-slot
-    ``[nh, dh, dh]`` transforms (HGT's ``w_att``/``w_msg``) apply in the
-    epilogue, as block-diagonal ``[H, H]`` matrices.  With
-    ``with_residuals`` the pre-transform projections are written out once
-    for the backward.
-  * :func:`stacked_attn_dh_pallas` — the backward w.r.t. the neighbor
-    activations: ``dh = dz @ we[slot]ᵀ (+ dv @ wv[slot]ᵀ)``, weight blocks
-    again read via scalar prefetch.
+    round-trips through HBM.  Optional per-slot ``[nh, dh, dh]`` transforms
+    (HGT's ``w_att``/``w_msg``), as block-diagonal ``[H, H]`` matrices, act
+    once per destination row: on the query before the logits and on the
+    combined values after the softmax.
+  * :func:`stacked_attn_bwd_pallas` — its backward: per (slot, node block)
+    it recomputes the block's projections, logits and softmax in VMEM and
+    writes the query (and additive-logit) gradients, where they are needed
+    the neighbor-row gradients, and accumulates the slot's weight gradients
+    across its node blocks.  Nothing with a row per edge slot is saved by
+    the forward or built in HBM by the backward.
 
 All shapes arrive pre-padded to block multiples (``ops.py`` owns padding
 and slicing); fanout ``f`` stays whole — sampled fanouts are 3–25, so the
@@ -64,7 +66,7 @@ __all__ = [
     "stacked_mean_linear_dh_pallas",
     "stacked_softmax_combine_pallas",
     "stacked_attn_epilogue_pallas",
-    "stacked_attn_dh_pallas",
+    "stacked_attn_bwd_pallas",
 ]
 
 
@@ -211,20 +213,23 @@ def stacked_mean_linear_dh_pallas(
 # --------------------------------------------------------------------------
 
 
-def _masked_softmax_combine(e, m, v):
-    """Masked softmax over the fanout axis + weighted sum of ``v``.
-
-    ``e`` and ``v`` are ``[bn, f, H]`` with the logits *head-expanded*: lane
-    ``(h, d)`` of ``e`` holds head ``h``'s logit, so the softmax runs per
-    lane and the combine is an elementwise product — no ``[.., nh, dh]``
-    reshape, which Mosaic cannot lay out.  Numerics per lane are those of
-    ``relmod.masked_softmax``."""
+def _masked_softmax(e, m):
+    """Masked softmax over the fanout axis of ``[bn, f, H]`` logits that are
+    *head-expanded* — lane ``(h, d)`` of ``e`` holds head ``h``'s logit, so
+    the softmax runs per lane with no ``[.., nh, dh]`` reshape, which Mosaic
+    cannot lay out.  Numerics per lane are those of
+    ``relmod.masked_softmax``; an all-masked row gives zeros."""
     mm = m.astype(e.dtype)[:, :, None]  # [bn, f, 1]; Mosaic reshapes no i1
     neg = jnp.asarray(jnp.finfo(e.dtype).min, e.dtype)
     em = jnp.where(mm > 0, e, neg)
     em = em - jnp.max(em, axis=1, keepdims=True)
     z = jnp.exp(em) * mm
-    alpha = z / jnp.maximum(jnp.sum(z, axis=1, keepdims=True), 1e-9)
+    return z / jnp.maximum(jnp.sum(z, axis=1, keepdims=True), 1e-9)
+
+
+def _masked_softmax_combine(e, m, v):
+    """:func:`_masked_softmax` + the weighted sum of ``v`` ``[bn, f, H]``."""
+    alpha = _masked_softmax(e, m)
     return jnp.sum(alpha * v.astype(alpha.dtype), axis=1)
 
 
@@ -267,26 +272,61 @@ def stacked_softmax_combine_pallas(
 _EPILOGUE_VMEM_LIMIT = 64 * 2**20
 
 
-def _lane_matmul(x, w):
-    """``[bn, f, H] @ [H, K]`` over the lane axis, float32 at full precision."""
-    bn, f, H = x.shape
-    y = jax.lax.dot(x.reshape(bn * f, H), w.astype(jnp.float32),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32)
-    return y.reshape(bn, f, w.shape[1])
+def _rows_matmul(x, w, transpose_w: bool = False):
+    """``[m, H] @ w`` (or ``@ wᵀ``), float32 at full precision: the per-head
+    transforms, applied once per destination row."""
+    dims = (((1,), (1,)) if transpose_w else ((1,), (0,)), ((), ()))
+    return jax.lax.dot_general(x, w.astype(jnp.float32), dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
-def _head_sum_matrix(nh: int, dh: int):
-    """``[H, H]`` 0/1 matrix summing each head's ``dh`` lanes and writing the
-    sum back to all of them (``kron(I_nh, ones(dh, dh))``)."""
+def _rows_outer(a, b, precision=jax.lax.Precision.HIGHEST):
+    """``aᵀ @ b`` over the rows of ``[m, K]`` and ``[m, N]``, float32."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _head_group(nh: int, dh: int) -> int:
+    """Lane width of one head-sum block: whole 128-lane groups where the
+    heads tile them, else all of ``H``."""
     H = nh * dh
-    row = jax.lax.broadcasted_iota(jnp.int32, (H, H), 0) // dh
-    col = jax.lax.broadcasted_iota(jnp.int32, (H, H), 1) // dh
-    return (row == col).astype(jnp.float32)
+    return 128 if H % 128 == 0 and 128 % dh == 0 else H
+
+
+def _head_sum(x, nh: int, dh: int):
+    """``[m, H]`` -> each head's sum over its ``dh`` lanes, written back to
+    all of them: a matmul with the 0/1 matrix ``kron(I, ones(dh, dh))`` per
+    group of lanes (a block-diagonal sum, so a group of 128 lanes needs only
+    its own 128x128 block), float32 at full precision."""
+    G = _head_group(nh, dh)
+    row = jax.lax.broadcasted_iota(jnp.int32, (G, G), 0) // dh
+    col = jax.lax.broadcasted_iota(jnp.int32, (G, G), 1) // dh
+    s = (row == col).astype(jnp.float32)
+    parts = [jax.lax.dot(x[:, g:g + G], s, precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+             for g in range(0, x.shape[1], G)]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _attn_probs(z0, qk, m, eb, *, nh, dh, scale, slope):
+    """Logits and masked softmax of one block, all ``[bn, f, H]`` float32
+    with per-head values head-expanded: ``e0`` before the leaky ReLU and
+    ``alpha``.  ``qk`` ``[bn, H]`` is the query with the logits transform
+    already applied (``qv @ W_ATTᵀ``), so no per-edge transform is needed:
+    ``(z0 @ W_ATT) . qv == z0 . (qv @ W_ATTᵀ)`` head by head."""
+    bn, f, H = z0.shape
+    e0 = _head_sum((z0 * qk[:, None, :]).reshape(bn * f, H), nh, dh)
+    e0 = e0.reshape(bn, f, H) * scale
+    if eb is not None:
+        e0 = e0 + eb[:, None, :]
+    e = e0 if slope is None else jax.nn.leaky_relu(e0, negative_slope=slope)
+    return e0, _masked_softmax(e, m)
 
 
 def _attn_epilogue_kernel(u_ref, *refs, n_chunks, num_heads, head_dim, scale,
-                          slope, has_eb, has_post, shared_v, with_res):
+                          slope, has_eb, has_post, shared_v):
     nh, dh = num_heads, head_dim
     it = iter(refs)
     h_ref, m_ref, qv_ref = next(it), next(it), next(it)
@@ -296,8 +336,6 @@ def _attn_epilogue_kernel(u_ref, *refs, n_chunks, num_heads, head_dim, scale,
     pe_ref = next(it) if has_post else None
     pv_ref = next(it) if has_post else None
     out_ref = next(it)
-    z_ref = next(it) if with_res else None
-    v_ref = next(it) if (with_res and not shared_v) else None
     acc_z = next(it)
     acc_v = None if shared_v else next(it)
 
@@ -325,30 +363,52 @@ def _attn_epilogue_kernel(u_ref, *refs, n_chunks, num_heads, head_dim, scale,
     def _done():
         z0 = acc_z[...]  # [bn, f, nh*dh] float32
         v0 = z0 if acc_v is None else acc_v[...]
-        if has_post:
-            # per-head [dh, dh] transforms as one block-diagonal [H, H]
-            zt = _lane_matmul(z0, pe_ref[0])
-            vt = _lane_matmul(v0, pv_ref[0])
-        else:
-            zt, vt = z0, v0
         qv = qv_ref[0].astype(jnp.float32)  # [bn, H]
-        # per-head logit sum, broadcast back over the head's dh lanes
-        e = _lane_matmul(zt * qv[:, None, :], _head_sum_matrix(nh, dh)) * scale
-        if has_eb:
-            e = e + eb_ref[0].astype(jnp.float32)[:, None, :]
-        if slope is not None:
-            e = jax.nn.leaky_relu(e, negative_slope=slope)
-        out = _masked_softmax_combine(e, m_ref[0], vt)
+        # the per-head [dh, dh] transforms, as block-diagonal [H, H]
+        # matrices, act per destination row: on the query before the logits
+        # and on the combined values after the softmax
+        qk = _rows_matmul(qv, pe_ref[0], transpose_w=True) if has_post else qv
+        eb = eb_ref[0].astype(jnp.float32) if has_eb else None
+        _, alpha = _attn_probs(z0, qk, m_ref[0], eb, nh=nh, dh=dh,
+                               scale=scale, slope=slope)
+        ov = jnp.sum(alpha * v0, axis=1)  # [bn, H]
+        out = _rows_matmul(ov, pv_ref[0]) if has_post else ov
         out_ref[0] = out.astype(out_ref.dtype)
-        if z_ref is not None:
-            z_ref[0] = z0.astype(z_ref.dtype)
-        if v_ref is not None:
-            v_ref[0] = v0.astype(v_ref.dtype)
+
+
+def _attn_in_specs(bn, f, d_blk, H, has_eb, shared_v, has_post, *, chunked,
+                   with_g=False):
+    """Block specs of the attention kernels' inputs, in their order: the
+    neighbor rows, the mask, the queries (and the output's cotangent), the
+    additive logits, the projection stacks and the per-head transforms, each
+    weight block read from its stack row by scalar prefetch.  The forward's
+    grid is (slot, node block, d_in chunk) (``chunked``), the backward's
+    (slot, node block) with ``d_in`` whole."""
+    if chunked:
+        rows = lambda s, i, c, u: (s, i, 0, c)
+        row = lambda s, i, c, u: (s, i, 0)
+        weight = lambda r: lambda s, i, c, u: (u[r, s], c, 0)
+    else:
+        rows = lambda s, i, u: (s, i, 0, 0)
+        row = lambda s, i, u: (s, i, 0)
+        weight = lambda r: lambda s, i, u: (u[r, s], 0, 0)
+    specs = [pl.BlockSpec((1, bn, f, d_blk), rows),
+             pl.BlockSpec((1, bn, f), row),
+             pl.BlockSpec((1, bn, H), row)]
+    specs += [pl.BlockSpec((1, bn, H), row)] * (int(with_g) + int(has_eb))
+    specs.append(pl.BlockSpec((1, d_blk, H), weight(0)))
+    if not shared_v:
+        specs.append(pl.BlockSpec((1, d_blk, H), weight(1)))
+    if has_post:  # the transforms are whole [H, H] blocks: no d_in chunk
+        transform = (lambda s, i, c, u: (u[2, s], 0, 0)) if chunked else (
+            lambda s, i, u: (u[2, s], 0, 0))
+        specs += [pl.BlockSpec((1, H, H), transform)] * 2
+    return specs
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_heads", "head_dim", "scale", "slope", "with_residuals",
+    static_argnames=("num_heads", "head_dim", "scale", "slope",
                      "block_n", "block_in", "interpret"),
 )
 def stacked_attn_epilogue_pallas(
@@ -365,7 +425,6 @@ def stacked_attn_epilogue_pallas(
     head_dim: int,
     scale: float = 1.0,
     slope=None,
-    with_residuals: bool = False,
     block_n: int = 128,
     block_in: int = 512,
     interpret: bool = True,
@@ -376,140 +435,203 @@ def stacked_attn_epilogue_pallas(
     bn, bc = block_n, block_in
     has_eb, has_post, shared_v = eb is not None, pe is not None, wv is None
     grid = (rb, pl.cdiv(n, bn), pl.cdiv(d_in, bc))
-
-    in_specs = [
-        pl.BlockSpec((1, bn, f, bc), lambda s, i, c, u: (s, i, 0, c)),
-        pl.BlockSpec((1, bn, f), lambda s, i, c, u: (s, i, 0)),
-        pl.BlockSpec((1, bn, H), lambda s, i, c, u: (s, i, 0)),
-    ]
-    operands = [h, mask, qv]
-    if has_eb:
-        in_specs.append(pl.BlockSpec((1, bn, H), lambda s, i, c, u: (s, i, 0)))
-        operands.append(eb)
-    in_specs.append(
-        pl.BlockSpec((1, bc, H), lambda s, i, c, u: (u[0, s], c, 0)))
-    operands.append(we)
-    if not shared_v:
-        in_specs.append(
-            pl.BlockSpec((1, bc, H), lambda s, i, c, u: (u[1, s], c, 0)))
-        operands.append(wv)
-    if has_post:
-        in_specs.append(
-            pl.BlockSpec((1, H, H), lambda s, i, c, u: (u[2, s], 0, 0)))
-        in_specs.append(
-            pl.BlockSpec((1, H, H), lambda s, i, c, u: (u[2, s], 0, 0)))
-        operands.extend([pe, pv])
-
-    out_specs = [pl.BlockSpec((1, bn, H), lambda s, i, c, u: (s, i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((rb, n, H), h.dtype)]
-    if with_residuals:
-        out_specs.append(
-            pl.BlockSpec((1, bn, f, H), lambda s, i, c, u: (s, i, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((rb, n, f, H), h.dtype))
-        if not shared_v:
-            out_specs.append(
-                pl.BlockSpec((1, bn, f, H), lambda s, i, c, u: (s, i, 0, 0)))
-            out_shape.append(jax.ShapeDtypeStruct((rb, n, f, H), h.dtype))
-
+    in_specs = _attn_in_specs(bn, f, bc, H, has_eb, shared_v, has_post,
+                              chunked=True)
+    operands = [h, mask, qv] + [x for x in (eb, we, wv, pe, pv) if x is not None]
     scratch = [pltpu.VMEM((bn, f, H), jnp.float32)]
     if not shared_v:
         scratch.append(pltpu.VMEM((bn, f, H), jnp.float32))
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=in_specs,
-        out_specs=out_specs,
+        out_specs=pl.BlockSpec((1, bn, H), lambda s, i, c, u: (s, i, 0)),
         scratch_shapes=scratch,
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
             _attn_epilogue_kernel, n_chunks=grid[2], num_heads=nh, head_dim=dh,
             scale=scale, slope=slope, has_eb=has_eb, has_post=has_post,
-            shared_v=shared_v, with_res=with_residuals,
+            shared_v=shared_v,
         ),
         grid_spec=grid_spec,
-        out_shape=out_shape,
-        # the [bn, f, H] float32 accumulators and residual tiles (lane-padded
-        # to 128) overflow the 16 MiB default scoped VMEM once HGT carries
-        # separate K and V projections; v5e has 128 MiB per core
+        out_shape=jax.ShapeDtypeStruct((rb, n, H), h.dtype),
+        # the [bn, f, H] float32 accumulators (lane-padded to 128) overflow
+        # the 16 MiB default scoped VMEM once HGT carries separate K and V
+        # projections; v5e has 128 MiB per core
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_EPILOGUE_VMEM_LIMIT),
         name="stacked_attn_epilogue_pallas",
         interpret=interpret,
     )(us.astype(jnp.int32), *operands)
-    return out if with_residuals else out[0]
 
 
 # --------------------------------------------------------------------------
-# fused attention backward w.r.t. the neighbor activations
+# fused attention backward: recomputed per block, nothing saved per edge
 # --------------------------------------------------------------------------
 
 
-def _attn_dh_kernel(u_ref, *refs, shared_v):
+def _attn_bwd_kernel(u_ref, *refs, num_heads, head_dim, scale, slope, has_eb,
+                     has_post, shared_v, h_grad):
+    nh, dh = num_heads, head_dim
     it = iter(refs)
-    dz_ref = next(it)
-    dv_ref = None if shared_v else next(it)
+    h_ref, m_ref, qv_ref, g_ref = next(it), next(it), next(it), next(it)
+    eb_ref = next(it) if has_eb else None
     we_ref = next(it)
     wv_ref = None if shared_v else next(it)
-    dh_ref = next(it)
+    pe_ref = next(it) if has_post else None
+    pv_ref = next(it) if has_post else None
+    dqv_ref = next(it)
+    deb_ref = next(it) if has_eb else None
+    dwe_ref = next(it)
+    dwv_ref = None if shared_v else next(it)
+    dpe_ref = next(it) if has_post else None
+    dpv_ref = next(it) if has_post else None
+    dh_ref = next(it) if h_grad else None
 
-    dz = dz_ref[0]  # [bn, f, H]
-    bn, f, H = dz.shape
-    we = we_ref[0]  # [bc, H]
-    acc = jax.lax.dot_general(
-        dz.reshape(bn * f, H).astype(we.dtype), we, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    if dv_ref is not None:
+    # the slot's weight gradients accumulate in their output blocks, which
+    # stay in VMEM while the node blocks of one slot go by
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        for r in (dwe_ref, dwv_ref, dpe_ref, dpv_ref):
+            if r is not None:
+                r[...] = jnp.zeros_like(r)
+
+    h = h_ref[0]  # [bn, f, d_in]
+    bn, f, d_in = h.shape
+    H = nh * dh
+    hf = h.reshape(bn * f, d_in)
+    we = we_ref[0]
+    # the forward's projections, recomputed in VMEM
+    z0 = jax.lax.dot(hf.astype(we.dtype), we, preferred_element_type=jnp.float32)
+    if shared_v:
+        v0 = z0
+    else:
         wv = wv_ref[0]
-        acc += jax.lax.dot_general(
-            dv_ref[0].reshape(bn * f, H).astype(wv.dtype), wv,
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-    dh_ref[0] = acc.reshape(bn, f, -1).astype(dh_ref.dtype)
+        v0 = jax.lax.dot(hf.astype(wv.dtype), wv,
+                         preferred_element_type=jnp.float32)
+    z3, v3 = z0.reshape(bn, f, H), v0.reshape(bn, f, H)
+    qv = qv_ref[0].astype(jnp.float32)  # [bn, H]
+    g = g_ref[0].astype(jnp.float32)  # [bn, H]
+    qk = _rows_matmul(qv, pe_ref[0], transpose_w=True) if has_post else qv
+    dov = _rows_matmul(g, pv_ref[0], transpose_w=True) if has_post else g
+    eb = eb_ref[0].astype(jnp.float32) if has_eb else None
+    e0, alpha = _attn_probs(z3, qk, m_ref[0], eb, nh=nh, dh=dh, scale=scale,
+                            slope=slope)
+    # closed-form softmax Jacobian (ops._sc_vjp_bwd's), head-expanded
+    dalpha = _head_sum((v3 * dov[:, None, :]).reshape(bn * f, H), nh, dh)
+    dalpha = dalpha.reshape(bn, f, H)
+    de = alpha * (dalpha - jnp.sum(alpha * dalpha, axis=1, keepdims=True))
+    if slope is not None:
+        de = de * jnp.where(e0 >= 0, 1.0, slope).astype(de.dtype)
+    if has_eb:
+        deb_ref[0] = jnp.sum(de, axis=1).astype(deb_ref.dtype)
+    des = de * scale
+    dqk = jnp.sum(des * z3, axis=1)  # [bn, H]
+    dz0 = (des * qk[:, None, :]).reshape(bn * f, H)
+    dv0 = (alpha * dov[:, None, :]).reshape(bn * f, H)
+    if has_post:
+        dqv_ref[0] = _rows_matmul(dqk, pe_ref[0]).astype(dqv_ref.dtype)
+        dpe_ref[0] += _rows_outer(dqk, qv)
+        ov = jnp.sum(alpha * v3, axis=1)
+        dpv_ref[0] += _rows_outer(ov, g)
+    else:
+        dqv_ref[0] = dqk.astype(dqv_ref.dtype)
+    hx = hf.astype(jnp.float32)
+    prec = jax.lax.Precision.DEFAULT
+    if shared_v:
+        dz0 = dz0 + dv0
+        dwe_ref[0] += _rows_outer(hx, dz0, prec)
+    else:
+        dwe_ref[0] += _rows_outer(hx, dz0, prec)
+        dwv_ref[0] += _rows_outer(hx, dv0, prec)
+    if h_grad:
+        dims = (((1,), (1,)), ((), ()))
+        dh = jax.lax.dot_general(dz0.astype(we.dtype), we, dims,
+                                 preferred_element_type=jnp.float32)
+        if not shared_v:
+            dh = dh + jax.lax.dot_general(dv0.astype(wv.dtype), wv, dims,
+                                          preferred_element_type=jnp.float32)
+        dh_ref[0] = dh.reshape(bn, f, d_in).astype(dh_ref.dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_n", "block_in", "interpret")
+    jax.jit,
+    static_argnames=("num_heads", "head_dim", "scale", "slope", "h_grad",
+                     "block_n", "interpret"),
 )
-def stacked_attn_dh_pallas(
-    dz: jnp.ndarray,  # [rb, n, f, H]
-    dv,  # [rb, n, f, H] or None (shared projection)
+def stacked_attn_bwd_pallas(
+    h: jnp.ndarray,  # [rb, n, f, d_in]  (n pre-padded to block_n)
+    mask: jnp.ndarray,  # [rb, n, f]
+    qv: jnp.ndarray,  # [rb, n, H]
+    g: jnp.ndarray,  # [rb, n, H] cotangent of the epilogue's output
+    eb,  # [rb, n, H] head-expanded, or None
     we: jnp.ndarray,  # [Ue, d_in, H]
-    wv,  # [Uv, d_in, H] or None
-    us: jnp.ndarray,  # [3, rb] int32
+    wv,  # [Uv, d_in, H] or None (shares we)
+    pe,  # [Ua, H, H] block-diagonal, or None
+    pv,  # [Ua, H, H] block-diagonal, or None
+    us: jnp.ndarray,  # [3, rb] int32 (scalar prefetch)
+    num_heads: int,
+    head_dim: int,
+    scale: float = 1.0,
+    slope=None,
+    h_grad: bool = True,
     block_n: int = 128,
-    block_in: int = 512,
     interpret: bool = True,
-) -> jnp.ndarray:
-    rb, n, f, H = dz.shape
-    d_in = we.shape[1]
-    bn, bc = block_n, block_in
-    shared_v = dv is None
-    grid = (rb, pl.cdiv(n, bn), pl.cdiv(d_in, bc))
-    in_specs = [pl.BlockSpec((1, bn, f, H), lambda s, i, c, u: (s, i, 0, 0))]
-    operands = [dz]
+):
+    """The backward of :func:`stacked_attn_epilogue_pallas`, one grid step
+    per (slot, node block), ``d_in`` whole.  Each step recomputes its
+    block's projections, logits and softmax in VMEM from the forward's
+    inputs and writes the gradients of the queries (``dqv``, ``[rb, n,
+    H]``), of the additive logits (``deb``, head-expanded, where there are
+    any) and, where ``h_grad``, of the neighbor rows (``dh``).  The weight
+    gradients are per slot — ``dwe``/``dwv`` ``[rb, d_in, H]``, ``dpe``/
+    ``dpv`` ``[rb, H, H]`` (only the diagonal blocks are meaningful) —
+    summed over the slot's node blocks.  Returns a dict of those arrays."""
+    rb, n, f, d_in = h.shape
+    nh, dh = num_heads, head_dim
+    H = nh * dh
+    bn = block_n
+    has_eb, has_post, shared_v = eb is not None, pe is not None, wv is None
+    grid = (rb, pl.cdiv(n, bn))
+    in_specs = _attn_in_specs(bn, f, d_in, H, has_eb, shared_v, has_post,
+                              chunked=False, with_g=True)
+    operands = [h, mask, qv, g] + [x for x in (eb, we, wv, pe, pv) if x is not None]
+
+    row = pl.BlockSpec((1, bn, H), lambda s, i, u: (s, i, 0))
+    outs = {"dqv": (row, (rb, n, H), h.dtype)}
+    if has_eb:
+        outs["deb"] = (row, (rb, n, H), h.dtype)
+    slot_w = pl.BlockSpec((1, d_in, H), lambda s, i, u: (s, 0, 0))
+    outs["dwe"] = (slot_w, (rb, d_in, H), jnp.float32)
     if not shared_v:
-        in_specs.append(
-            pl.BlockSpec((1, bn, f, H), lambda s, i, c, u: (s, i, 0, 0)))
-        operands.append(dv)
-    in_specs.append(
-        pl.BlockSpec((1, bc, H), lambda s, i, c, u: (u[0, s], c, 0)))
-    operands.append(we)
-    if not shared_v:
-        in_specs.append(
-            pl.BlockSpec((1, bc, H), lambda s, i, c, u: (u[1, s], c, 0)))
-        operands.append(wv)
+        outs["dwv"] = (slot_w, (rb, d_in, H), jnp.float32)
+    if has_post:
+        slot_p = pl.BlockSpec((1, H, H), lambda s, i, u: (s, 0, 0))
+        outs["dpe"] = (slot_p, (rb, H, H), jnp.float32)
+        outs["dpv"] = (slot_p, (rb, H, H), jnp.float32)
+    if h_grad:
+        outs["dh"] = (pl.BlockSpec((1, bn, f, d_in), lambda s, i, u: (s, i, 0, 0)),
+                      (rb, n, f, d_in), h.dtype)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bn, f, bc), lambda s, i, c, u: (s, i, 0, c)),
+        out_specs=[spec for spec, _, _ in outs.values()],
     )
-    return pl.pallas_call(
-        functools.partial(_attn_dh_kernel, shared_v=shared_v),
+    res = pl.pallas_call(
+        functools.partial(
+            _attn_bwd_kernel, num_heads=nh, head_dim=dh, scale=scale,
+            slope=slope, has_eb=has_eb, has_post=has_post, shared_v=shared_v,
+            h_grad=h_grad,
+        ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rb, n, f, d_in), dz.dtype),
-        name="stacked_attn_dh_pallas",
+        out_shape=[jax.ShapeDtypeStruct(shape, dt) for _, shape, dt in outs.values()],
+        # the node-block axis revisits the slot's weight-gradient blocks
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_EPILOGUE_VMEM_LIMIT),
+        name="stacked_attn_bwd_pallas",
         interpret=interpret,
     )(us.astype(jnp.int32), *operands)
+    return dict(zip(outs, res))

@@ -46,6 +46,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.custom_derivatives import SymbolicZero
 
 from repro.kernels.ops import (
     agg_blocks,
@@ -58,7 +59,8 @@ from repro.kernels.ops import (
     zero_cotangent,
 )
 from repro.kernels.stacked_relation_agg.kernel import (
-    stacked_attn_dh_pallas,
+    _EPILOGUE_VMEM_LIMIT,
+    stacked_attn_bwd_pallas,
     stacked_attn_epilogue_pallas,
     stacked_mean_linear_dh_pallas,
     stacked_mean_linear_pallas,
@@ -77,6 +79,8 @@ __all__ = [
     "stacked_mean_linear_vmem_bytes",
     "stacked_softmax_combine_vmem_bytes",
     "stacked_attn_epilogue_vmem_bytes",
+    "stacked_attn_bwd_vmem_bytes",
+    "stacked_attn_bwd_block",
 ]
 
 
@@ -102,6 +106,18 @@ def stacked_softmax_combine_vmem_bytes(
     return elems * bytes_per_elem
 
 
+# the attention kernels fold [bn, f, .] blocks into [bn*f, .] matmul rows;
+# with the fanout padded to whole sublane tiles that fold is free for Mosaic
+# (an unaligned one compiles to relayouts, slowly and into more VMEM).
+# Padded slots are masked out, so they change nothing.
+_F_TILE = 8
+
+
+def _lanes(x: int) -> int:
+    """A minor dim as VMEM lays it out: whole 128-lane tiles."""
+    return -(-x // 128) * 128
+
+
 def stacked_attn_epilogue_vmem_bytes(
     n: int, f: int, d_in: int, num_heads: int, head_dim: int,
     block_n: int = 128, block_in: int = 512,
@@ -116,6 +132,54 @@ def stacked_attn_epilogue_vmem_bytes(
     n_acc = 1 if shared_v else 2
     elems = bn * f * bc + bn * f + bn * H + n_acc * bc * H + bn * H
     return elems * bytes_per_elem + n_acc * bn * f * H * 4
+
+
+# float32 [bn*f, H] temporaries the attention backward holds at once: the
+# projections z0 and v0, the logits, the probabilities, their cotangent,
+# the logits' cotangent and the projections' cotangents dz0 and dv0
+_BWD_LIVE = 8
+
+
+def stacked_attn_bwd_vmem_bytes(
+    n: int, f: int, d_in: int, num_heads: int, head_dim: int,
+    block_n: int = 128, shared_v: bool = False, has_post: bool = True,
+    has_eb: bool = False, h_grad: bool = True, bytes_per_elem: int = 4,
+) -> int:
+    """Per-grid-step working set of :func:`stacked_attn_bwd_pallas`, minor
+    dims lane-padded: its input and output blocks, each double-buffered
+    (the neighbor rows and their gradient, mask, queries, cotangent,
+    projection and transform blocks, the slot's weight-gradient blocks),
+    plus the float32 per-edge temporaries of one node block.  ``f`` is the
+    fanout before its padding to whole sublane tiles."""
+    bn = clamp_block(block_n, n)
+    f8 = -(-f // _F_TILE) * _F_TILE
+    H, D = _lanes(num_heads * head_dim), _lanes(d_in)
+    rows = bn * f8
+    n_w = 1 if shared_v else 2
+    blocks = (rows * D * (2 if h_grad else 1) + bn * _lanes(f8)
+              + bn * H * (3 + 2 * has_eb)  # qv, g, dqv (+ eb, deb)
+              + 2 * n_w * D * H + 4 * has_post * H * H)
+    return 2 * blocks * bytes_per_elem + _BWD_LIVE * rows * H * 4
+
+
+# the backward's working-set budget: half the scoped VMEM both attention
+# kernels ask for, the rest left to the compiler's own temporaries
+_BWD_VMEM_BUDGET = _EPILOGUE_VMEM_LIMIT // 2
+
+
+def stacked_attn_bwd_block(
+    n: int, f: int, d_in: int, num_heads: int, head_dim: int,
+    block_n: int = 128, **kw,
+) -> int:
+    """The backward's node block: the forward's (``clamp_block(block_n,
+    n)``), halved while its working set (:func:`stacked_attn_bwd_vmem_bytes`,
+    with ``kw``) is over ``_BWD_VMEM_BUDGET`` and it stays a multiple of 8
+    that divides the forward's block."""
+    bb = clamp_block(block_n, n)
+    while (bb % 16 == 0 and stacked_attn_bwd_vmem_bytes(
+            n, f, d_in, num_heads, head_dim, block_n=bb, **kw) > _BWD_VMEM_BUDGET):
+        bb //= 2
+    return bb
 
 
 # --------------------------------------------------------------------------
@@ -294,6 +358,7 @@ def stacked_softmax_combine(
 class _AECfg:
     bn: int
     bc: int
+    bb: int  # node block of the backward
     nh: int
     dh: int
     scale: float
@@ -304,126 +369,87 @@ class _AECfg:
     interpret: bool
 
 
-# the attention kernels fold [bn, f, .] blocks into [bn*f, .] matmul rows;
-# with the fanout padded to whole sublane tiles that fold is free for Mosaic
-# (an unaligned one compiles to relayouts, slowly and into more VMEM).
-# Padded slots are masked out, so they change nothing.
-_F_TILE = 8
+def _ae_operands(cfg, h, mask, qv, eb, we, wv, pe, pv):
+    """The kernels' operands: padded to their blocks, the additive logits
+    head-expanded, the per-head transforms block-diagonal; None where the
+    module has no such operand."""
+    return (
+        pad_axes(h, {1: cfg.bn, 2: _F_TILE, 3: cfg.bc}),
+        pad_axes(mask, {1: cfg.bn, 2: _F_TILE}),
+        pad_to(qv, 1, cfg.bn),
+        pad_to(_head_expand(eb, cfg.dh), 1, cfg.bn) if cfg.has_eb else None,
+        pad_to(we, 1, cfg.bc),
+        None if cfg.shared_v else pad_to(wv, 1, cfg.bc),
+        _block_diag(pe) if cfg.has_post else None,
+        _block_diag(pv) if cfg.has_post else None,
+    )
 
 
-def _ae_fwd_impl(cfg, h, mask, qv, eb, we, wv, pe, pv, us, with_residuals):
-    rb, n, f, d_in = h.shape
-    hp = pad_axes(h, {1: cfg.bn, 2: _F_TILE, 3: cfg.bc})
-    mp = pad_axes(mask, {1: cfg.bn, 2: _F_TILE})
-    qp = pad_to(qv, 1, cfg.bn)
-    ebp = pad_to(_head_expand(eb, cfg.dh), 1, cfg.bn) if cfg.has_eb else None
-    wep = pad_to(we, 1, cfg.bc)
-    wvp = None if cfg.shared_v else pad_to(wv, 1, cfg.bc)
-    pe_, pv_ = ((_block_diag(pe), _block_diag(pv)) if cfg.has_post
-                else (None, None))
-    res = stacked_attn_epilogue_pallas(
-        hp, mp, qp, ebp, wep, wvp, pe_, pv_, us,
-        num_heads=cfg.nh, head_dim=cfg.dh, scale=cfg.scale, slope=cfg.slope,
-        with_residuals=with_residuals, block_n=cfg.bn, block_in=cfg.bc,
+def _ae_forward(cfg, ops, us):
+    return stacked_attn_epilogue_pallas(
+        *ops, us, num_heads=cfg.nh, head_dim=cfg.dh, scale=cfg.scale,
+        slope=cfg.slope, block_n=cfg.bn, block_in=cfg.bc,
         interpret=cfg.interpret,
     )
-    if not with_residuals:
-        return res[:, :n]
-    out = res[0][:, :n]
-    z0 = res[1][:, :n, :f]
-    v0 = z0 if cfg.shared_v else res[2][:, :n, :f]
-    return out, z0, v0
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _stacked_ae(cfg: _AECfg, h, mask, qv, eb, we, wv, pe, pv, us):
-    return _ae_fwd_impl(cfg, h, mask, qv, eb, we, wv, pe, pv, us, False)
+    n = h.shape[1]
+    return _ae_forward(cfg, _ae_operands(cfg, h, mask, qv, eb, we, wv, pe, pv),
+                       us)[:, :n]
 
 
 def _ae_vjp_fwd(cfg, h, mask, qv, eb, we, wv, pe, pv, us):
-    # the pre-transform projections z0/v0 come back as kernel residuals —
-    # the backward never re-runs the big matmuls nor gathers a weight copy
-    out, z0, v0 = _ae_fwd_impl(cfg, h, mask, qv, eb, we, wv, pe, pv, us, True)
-    return out, (h, mask, qv, eb, we, wv, pe, pv, us, z0, v0)
+    # the residuals are the kernel's own (padded) inputs: the backward
+    # recomputes the projections block by block, so nothing with a row per
+    # edge slot is kept.  The neighbor rows' gradient is written only where
+    # they are differentiated (``perturbed``; not the fixed rows of a leaf
+    # level)
+    h_grad = h.perturbed
+    h, mask, qv, eb, we, wv, pe, pv, us = (
+        x.value for x in (h, mask, qv, eb, we, wv, pe, pv, us))
+    ops = _ae_operands(cfg, h, mask, qv, eb, we, wv, pe, pv)
+    return (_ae_forward(cfg, ops, us)[:, :h.shape[1]],
+            (ops, us, h.shape, h_grad))
+
+
+def _diag_blocks(x, nh: int, dh: int):
+    """``[r, H, H]`` -> its ``nh`` diagonal ``[dh, dh]`` blocks ``[r, nh, dh, dh]``."""
+    r = x.shape[0]
+    return jnp.moveaxis(
+        jnp.diagonal(x.reshape(r, nh, dh, nh, dh), axis1=1, axis2=3), -1, 1)
 
 
 def _ae_vjp_bwd(cfg, res, g):
-    h, mask, qv, eb, we, wv, pe, pv, us, z0, v0 = res
-    rb, n, f, d_in = h.shape
-    nh, dh = cfg.nh, cfg.dh
-    H = nh * dh
-    z4 = z0.reshape(rb, n, f, nh, dh)
-    v4 = v0.reshape(rb, n, f, nh, dh)
-    ua = us[2]
+    ops, us, (rb, n, f, d_in), h_grad = res
+    hp, mp, qp, _, we, wv, pe, pv = ops
+    if isinstance(g, SymbolicZero):
+        g = jnp.zeros(g.shape, g.dtype)
+    grads = stacked_attn_bwd_pallas(
+        hp, mp, qp, pad_to(g, 1, cfg.bn), *ops[3:], us,
+        num_heads=cfg.nh, head_dim=cfg.dh, scale=cfg.scale, slope=cfg.slope,
+        h_grad=h_grad, block_n=cfg.bb, interpret=cfg.interpret,
+    )
+    # per-slot weight gradients straight into stack form (segment-summed
+    # over slot rows; cross-shard sharing stays sync_stack_grads' job)
+    seg = lambda x, u, like: jax.ops.segment_sum(
+        x, u, num_segments=like.shape[0]).astype(like.dtype)
+    dwe = seg(grads["dwe"][:, :d_in], us[0], we)
+    dwv = None if cfg.shared_v else seg(grads["dwv"][:, :d_in], us[1], wv)
+    dpe = dpv = None
     if cfg.has_post:
-        peg, pvg = pe[ua], pv[ua]  # [rb, nh, dh, dh] — tiny per-slot gathers
-        zt = jnp.einsum("rnfhd,rhde->rnfhe", z4, peg)
-        vt = jnp.einsum("rnfhd,rhde->rnfhe", v4, pvg)
-    else:
-        zt, vt = z4, v4
-    qv4 = qv.reshape(rb, n, nh, dh)
-    e0 = jnp.einsum("rnfhe,rnhe->rnfh", zt, qv4) * cfg.scale
-    if cfg.has_eb:
-        e0 = e0 + eb[:, :, None, :]
-    e = e0 if cfg.slope is None else jax.nn.leaky_relu(
-        e0, negative_slope=cfg.slope)
-    alpha = _sc_alpha(e, mask)  # [rb, n, f, nh]
-    gh = g.reshape(rb, n, nh, dh)
-    # closed-form softmax Jacobian (matches _sc_vjp_bwd)
-    dalpha = jnp.einsum("rnfhd,rnhd->rnfh", vt, gh)
-    tot = jnp.sum(alpha * dalpha, axis=2, keepdims=True)
-    de = alpha * (dalpha - tot)
-    dvt = jnp.einsum("rnfh,rnhd->rnfhd", alpha, gh)
-    if cfg.slope is not None:
-        de = de * jnp.where(e0 >= 0, 1.0, cfg.slope).astype(de.dtype)
-    deb = jnp.sum(de, axis=2) if cfg.has_eb else jnp.zeros_like(eb)
-    des = de * cfg.scale
-    dqv = jnp.einsum("rnfh,rnfhe->rnhe", des, zt).reshape(rb, n, H)
-    dzt = jnp.einsum("rnfh,rnhe->rnfhe", des, qv4)
-    if cfg.has_post:
-        dz4 = jnp.einsum("rnfhe,rhde->rnfhd", dzt, peg)
-        dv4 = jnp.einsum("rnfhe,rhde->rnfhd", dvt, pvg)
-        dpe = jax.ops.segment_sum(
-            jnp.einsum("rnfhd,rnfhe->rhde", z4, dzt), ua,
-            num_segments=pe.shape[0])
-        dpv = jax.ops.segment_sum(
-            jnp.einsum("rnfhd,rnfhe->rhde", v4, dvt), ua,
-            num_segments=pv.shape[0])
-    else:
-        dz4, dv4 = dzt, dvt
-        dpe, dpv = jnp.zeros_like(pe), jnp.zeros_like(pv)
-    dz = dz4.reshape(rb, n, f, H)
-    dv = dv4.reshape(rb, n, f, H)
-    # projection-weight grads straight into stack form (segment-summed over
-    # slot rows; cross-shard sharing stays sync_stack_grads' job)
-    if cfg.shared_v:
-        dcomb = dz + dv
-        dwe = jax.ops.segment_sum(
-            jnp.einsum("rnfc,rnfk->rck", h, dcomb), us[0],
-            num_segments=we.shape[0])
-        dwv = jnp.zeros_like(wv)
-        dzp, dvp = pad_axes(dcomb, {1: cfg.bn, 2: _F_TILE}), None
-    else:
-        dwe = jax.ops.segment_sum(
-            jnp.einsum("rnfc,rnfk->rck", h, dz), us[0],
-            num_segments=we.shape[0])
-        dwv = jax.ops.segment_sum(
-            jnp.einsum("rnfc,rnfk->rck", h, dv), us[1],
-            num_segments=wv.shape[0])
-        dzp = pad_axes(dz, {1: cfg.bn, 2: _F_TILE})
-        dvp = pad_axes(dv, {1: cfg.bn, 2: _F_TILE})
-    # dh through the scalar-prefetch transpose kernel — weight blocks read
-    # from the stack, same indirection as the forward
-    dh_ = stacked_attn_dh_pallas(
-        dzp, dvp, pad_to(we, 1, cfg.bc),
-        None if cfg.shared_v else pad_to(wv, 1, cfg.bc), us,
-        block_n=cfg.bn, block_in=cfg.bc, interpret=cfg.interpret,
-    )[:, :n, :f, :d_in]
-    return (dh_, zero_cotangent(mask), dqv, deb, dwe, dwv, dpe, dpv,
-            zero_cotangent(us))
+        dpe = seg(_diag_blocks(grads["dpe"], cfg.nh, cfg.dh), us[2], pe)
+        dpv = seg(_diag_blocks(grads["dpv"], cfg.nh, cfg.dh), us[2], pv)
+    deb = grads["deb"][:, :n, ::cfg.dh] if cfg.has_eb else None
+    dh_ = grads["dh"][:, :n, :f, :d_in] if h_grad else None
+    return (dh_, None, grads["dqv"][:, :n], deb, dwe, dwv, dpe, dpv, None)
 
 
-_stacked_ae.defvjp(_ae_vjp_fwd, _ae_vjp_bwd)
+_stacked_ae.defvjp(_ae_vjp_fwd, _ae_vjp_bwd, symbolic_zeros=True)
+# called through jit: shard_map's eager path does not take a custom_vjp with
+# symbolic zeros, a staged call does
+_stacked_ae_call = jax.jit(_stacked_ae, static_argnums=0)
 
 
 def stacked_attn_epilogue(
@@ -444,14 +470,19 @@ def stacked_attn_epilogue(
     ua = jnp.zeros_like(ue) if epi.ua is None else epi.ua.astype(jnp.int32)
     us = jnp.stack([ue, uv, ua])
     dummy = jnp.zeros((1, 1, 1), h.dtype)
+    bn = clamp_block(block_n, n)
     cfg = _AECfg(
-        bn=clamp_block(block_n, n), bc=clamp_block(block_in, d_in),
+        bn=bn, bc=clamp_block(block_in, d_in),
+        # sized for the larger working set, the rows' gradient included
+        bb=stacked_attn_bwd_block(
+            n, f, d_in, nh, dh, block_n=bn, shared_v=shared_v,
+            has_post=has_post, has_eb=epi.eb is not None),
         nh=nh, dh=dh, scale=float(epi.scale),
         slope=None if epi.slope is None else float(epi.slope),
         has_eb=epi.eb is not None, has_post=has_post, shared_v=shared_v,
         interpret=bool(interpret),
     )
-    out = _stacked_ae(
+    out = _stacked_ae_call(
         cfg, h, mask, epi.qv,
         dummy if epi.eb is None else epi.eb,
         epi.we,

@@ -332,6 +332,57 @@ def test_session_3step_loss_parity_fused_vs_attn_parts(model):
     np.testing.assert_allclose(fused, parts, atol=1e-5, rtol=1e-6)
 
 
+def test_hgt_published_widths_fit_matches_vanilla():
+    """HGT at its published widths (hidden 256, 8 heads) on a tiny ogbn-mag
+    graph, through ``Heta.fit`` on ``raf_spmd`` with the Pallas kernels in
+    interpret mode (the new backward among them), against the ``vanilla``
+    oracle: the losses of three steps, and the first gradient of every
+    weight, read back from Adam's first moment after one step."""
+    from repro.api import DataConfig, Heta, HetaConfig, ModelConfig
+    from repro.api import PartitionConfig, RunConfig
+    from repro.core.relmod import SCOPE_CONTAINER
+
+    def session(executor):
+        cfg = HetaConfig(
+            data=DataConfig(dataset="ogbn-mag", scale=0.002, fanouts=(3, 2),
+                            batch_size=16),
+            partition=PartitionConfig(num_partitions=2),
+            model=ModelConfig(model="hgt", hidden=256, num_heads=8,
+                              train_learnable=False),
+            run=RunConfig(executor=executor, steps=3, lr=1e-3, seed=0),
+        ).updated(kernels=dict(interpret=True))
+        sess = Heta(cfg)
+        sess.build_graph()
+        sess.partition()
+        sess.profile_and_cache()
+        sess.compile()
+        sess.fit(steps=1)
+        m1 = jax.tree.map(np.asarray, sess.state["opt"]["m"])
+        sess.fit(steps=2)
+        return sess, m1
+
+    spmd, m_spmd = session("raf_spmd")
+    vanilla, m_van = session("vanilla")
+    np.testing.assert_allclose(spmd.losses, vanilla.losses, atol=1e-5, rtol=1e-5)
+
+    plan = spmd.plan.plan
+    checked = 0
+    for layer in plan.layers:
+        for spec_ in plan.module.specs:
+            for p, row in enumerate(plan.scope_keys[(spec_.scope, layer)]):
+                for u, key in enumerate(row):
+                    want = m_van[SCOPE_CONTAINER[spec_.scope]][key][spec_.name]
+                    got = m_spmd[f"layer{layer}"][spec_.name][p, u]
+                    got = got[tuple(slice(0, s) for s in want.shape)]
+                    np.testing.assert_allclose(
+                        got, want, atol=1e-6, rtol=1e-4,
+                        err_msg=f"first gradient of {key}/{spec_.name}")
+                    checked += 1
+    assert checked >= 2 * len(plan.module.specs)
+    np.testing.assert_allclose(m_spmd["head"]["w"], m_van["head"]["w"],
+                               atol=1e-6, rtol=1e-4)
+
+
 def test_stacked_agg_disabled_is_oracle():
     mod, stacks, slot_np, slot_u, h, q, mask = _module_case(
         "rgcn", rb=3, n=8, f=3, di=10, dd=10, hidden=16, nh=4, seed=4
@@ -427,3 +478,250 @@ def test_raf_spmd_fused_forward_bit_equal_rgcn():
     vmap_root = run(KernelOptions(enabled=False))
     fused_root = run(KernelOptions(interpret=True))
     np.testing.assert_array_equal(np.asarray(fused_root), np.asarray(vmap_root))
+
+
+# --------------------------------------------------------------------------
+# the attention backward (stacked_attn_bwd_pallas): against jax.vjp of the
+# plain jnp aggregation, and what it keeps and builds
+# --------------------------------------------------------------------------
+
+
+def _attn_bwd_case(model, nh, dh, rb=4, n=21, f=5, di=23, dd=17, seed=0):
+    """Non-block-multiple shapes (21 rows in blocks of 8, fanout 5 padded to
+    8, input width 23 in chunks of 16), an empty neighborhood in every slot,
+    and slots 0 and 1 sharing every stack row."""
+    mod, stacks, slot_np, _, h, q, mask = _module_case(
+        model, rb=rb, n=n, f=f, di=di, dd=dd, hidden=nh * dh, nh=nh, seed=seed)
+    m = np.asarray(mask).copy()
+    m[:, 3, :] = False
+    slot_u = {s: jnp.asarray(np.where(np.arange(rb) < 2, 0, v))
+              for s, v in slot_np.items()}
+    return mod, stacks, slot_u, h, q, jnp.asarray(m)
+
+
+ATTN_BWD_CASES = [
+    ("hgt", 8, 32, True),
+    ("hgt", 8, 32, False),
+    ("hgt", 4, 16, True),
+    ("hgt", 4, 16, False),
+    ("rgat", 4, 16, True),
+    ("rgat", 4, 16, False),
+]
+
+
+@pytest.mark.parametrize("model,nh,dh,h_grad", ATTN_BWD_CASES)
+def test_attn_bwd_matches_aggregate_vjp(model, nh, dh, h_grad):
+    """The fused epilogue's backward equals ``jax.vjp`` of the module's plain
+    ``aggregate`` (vmapped over the slots) for every weight, the queries and,
+    where they are differentiated (``h_grad``), the neighbor rows."""
+    mod, stacks, slot_u, h, q, mask = _attn_bwd_case(model, nh, dh)
+    g = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (h.shape[0], h.shape[1], nh * dh)), jnp.float32)
+
+    def fused(st, h_, q_):
+        return stacked_agg(mod, st, slot_u, h_, q_, mask, opts=OPTS_ON,
+                           block_n=8, block_in=16)
+
+    def plain(st, h_, q_):
+        return stacked_agg_ref(mod, st, slot_u, h_, q_, mask)
+
+    with jax.default_matmul_precision("highest"):
+        if h_grad:
+            out_f, vjp_f = jax.vjp(fused, stacks, h, q)
+            out_r, vjp_r = jax.vjp(plain, stacks, h, q)
+        else:
+            out_f, vjp_f = jax.vjp(lambda st, q_: fused(st, h, q_), stacks, q)
+            out_r, vjp_r = jax.vjp(lambda st, q_: plain(st, h, q_), stacks, q)
+        gf, gr = vjp_f(g), vjp_r(g)
+    np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_r),
+                               atol=1e-5, rtol=1e-5)
+    names = ["stacks/" + "/".join(str(k.key) for k in path)
+             for path, _ in jax.tree_util.tree_flatten_with_path(gr[0])[0]]
+    names += ["h", "q"] if h_grad else ["q"]
+    leaves_f = jax.tree.leaves(gf)
+    leaves_r = jax.tree.leaves(gr)
+    assert len(leaves_f) == len(leaves_r) == len(names)
+    for name, a, c in zip(names, leaves_f, leaves_r):
+        scale = float(np.abs(np.asarray(c)).max())
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   atol=2e-5 * max(scale, 1.0), rtol=1e-4,
+                                   err_msg=f"{model} {nh}x{dh} d{name}")
+
+
+def _subjaxprs(eqn):
+    """The jaxprs in an equation's parameters (jit, shard_map, custom_vjp
+    bodies, ...)."""
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (tuple, list)) else (v,):
+            if isinstance(x, jax.extend.core.ClosedJaxpr):
+                yield x.jaxpr
+            elif isinstance(x, jax.extend.core.Jaxpr):
+                yield x
+
+
+def _bwd_pallas_eqns(jaxpr):
+    """The ``stacked_attn_bwd_pallas`` calls anywhere in a closed jaxpr."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            if eqn.params.get("name") == "stacked_attn_bwd_pallas":
+                out.append(eqn)
+            continue
+        for sub in _subjaxprs(eqn):
+            out.extend(_bwd_pallas_eqns(sub))
+    return out
+
+
+@pytest.mark.parametrize("h_grad", [True, False])
+def test_attn_bwd_writes_row_gradients_only_where_rows_train(h_grad):
+    """The neighbor-row gradient is an output of the backward only when the
+    rows are differentiated (the leaf level of a cell whose features stay
+    fixed is not): one kernel call, with or without a ``[rb, n, f, d_in]``
+    output."""
+    mod, stacks, slot_u, h, q, mask = _attn_bwd_case("hgt", 4, 16)
+
+    def loss(st, h_):
+        return jnp.sum(stacked_agg(mod, st, slot_u, h_, q, mask, opts=OPTS_ON,
+                                   block_n=8) ** 2)
+
+    fn = jax.grad(loss, argnums=(0, 1) if h_grad else 0)
+    calls = _bwd_pallas_eqns(jax.make_jaxpr(fn)(stacks, h).jaxpr)
+    assert len(calls) == 1
+    rows = [v for v in calls[0].outvars if v.aval.ndim == 4]
+    assert len(rows) == int(h_grad)
+
+
+
+@pytest.mark.parametrize("learn_feats", [False, True])
+def test_train_step_writes_leaf_row_gradients_only_where_features_train(
+        learn_feats):
+    """Through the whole SPMD train step (shard_map, jit), the attention
+    backward sees which rows are differentiated: the inner level's always,
+    the leaf level's feature rows only where the features train."""
+    from repro.core import raf_spmd
+    from repro.core.hgnn import HGNNConfig, init_hgnn_params
+    from repro.core.meta_partition import meta_partition
+    from repro.core.raf import assign_branches
+    from repro.graph.sampler import NeighborSampler, SampleSpec
+    from repro.graph.synthetic import ogbn_mag_like
+    from repro.optim.adam import AdamConfig, adam_init
+
+    g = ogbn_mag_like(scale=0.002)
+    mp = meta_partition(g, 2, num_layers=2)
+    spec = SampleSpec.from_metatree(mp.metatree, (3, 2))
+    b = NeighborSampler(g, spec, 8, seed=1).sample_batch(g.train_nodes[:8])
+    cfg = HGNNConfig(model="hgt", hidden=32, num_heads=4, num_layers=2,
+                     num_classes=g.num_classes)
+    feat_dims = {t: g.feat_dim(t) for t in g.num_nodes if g.feat_dim(t)}
+    params = init_hgnn_params(jax.random.PRNGKey(0), cfg, spec, feat_dims)
+    plan = raf_spmd.build_plan(spec, assign_branches(spec, mp).fold(1, spec),
+                               cfg, feat_dims)
+    stacks = raf_spmd.stack_params_from_dict(plan, params)
+    tables = {t: np.asarray(f) for t, f in g.features.items()}
+    for t in g.num_nodes:
+        tables.setdefault(
+            t, np.zeros((g.num_nodes[t], cfg.learnable_dim), np.float32))
+    arrays = raf_spmd.stack_batch(plan, b, tables)
+    step = raf_spmd.make_train_step(
+        plan, make_mesh((1, 1), ("data", "model")), AdamConfig(),
+        learn_feats=learn_feats, kernels=OPTS_ON)
+    calls = _bwd_pallas_eqns(
+        jax.make_jaxpr(step)(stacks, adam_init(stacks), arrays).jaxpr)
+    assert len(calls) == 2  # one per level
+    with_rows = sum(any(v.aval.ndim == 4 for v in c.outvars) for c in calls)
+    assert with_rows == 1 + int(learn_feats)
+
+def _float_arrays(tree):
+    return [a for a in jax.tree.leaves(tree)
+            if hasattr(a, "dtype") and jnp.issubdtype(a.dtype, jnp.floating)]
+
+
+def test_attn_vjp_residuals_hold_nothing_per_edge():
+    """``_ae_vjp_fwd`` saves the kernel's own inputs, not the projections: no
+    residual of the whole aggregation (the q-side projection's included)
+    has ``rb*n*f*H`` elements, one row per edge slot and ``H`` columns, or
+    more — with the input rows narrower than ``H``, only such an array
+    could."""
+    from jax.custom_derivatives import CustomVJPPrimal
+
+    from repro.kernels.stacked_relation_agg import ops as agg_ops
+
+    mod, stacks, slot_u, h, q, mask = _attn_bwd_case("hgt", 4, 16, di=23)
+    rb, n, f, _ = h.shape
+    H = 64
+    _, vjp_fn = jax.vjp(
+        lambda st, h_: stacked_agg(mod, st, slot_u, h_, q, mask, opts=OPTS_ON),
+        stacks, h)
+    sizes = [a.size for a in _float_arrays(vjp_fn)]
+    assert sizes and max(sizes) < rb * n * f * H, sorted(sizes)[-3:]
+
+    # and _ae_vjp_fwd's own residuals
+    epi = mod.attn_epilogue(stacks, slot_u, q, linear=lambda w, u, x: jnp.einsum(
+        "rnd,rdk->rnk", x, w[u]))
+    us = jnp.stack([slot_u["src_type"], slot_u["src_type"], slot_u["etype"]])
+    cfg = agg_ops._AECfg(bn=8, bc=23, bb=8, nh=4, dh=16, scale=0.25, slope=None,
+                         has_eb=False, has_post=True, shared_v=False,
+                         interpret=True)
+    dummy = jnp.zeros((1, 1, 1), jnp.float32)
+    _, res = agg_ops._ae_vjp_fwd(cfg, *(
+        CustomVJPPrimal(x, True)
+        for x in (h, mask, epi.qv, dummy, epi.we, epi.wv, epi.pe, epi.pv, us)))
+    sizes = [a.size for a in _float_arrays(res)]
+    assert max(sizes) < rb * n * f * H, sorted(sizes)[-3:]
+
+
+def test_attn_bwd_builds_nothing_per_edge_outside_its_kernel():
+    """Outside the Pallas calls, the gradient program builds no float array
+    with ``rb*n*f*H`` elements or more (the input rows, narrower than ``H``,
+    and their padded copy are the largest)."""
+    mod, stacks, slot_u, h, q, mask = _attn_bwd_case("hgt", 4, 16, di=23)
+    rb, n, f, _ = h.shape
+    H = 64
+
+    def loss(st, h_):
+        return jnp.sum(stacked_agg(mod, st, slot_u, h_, q, mask, opts=OPTS_ON) ** 2)
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for v in eqn.outvars:
+                aval = v.aval
+                if (hasattr(aval, "dtype") and jnp.issubdtype(aval.dtype, jnp.floating)
+                        and np.prod(aval.shape) >= rb * n * f * H):
+                    raise AssertionError(f"{eqn.primitive.name} builds {aval}")
+            for sub in _subjaxprs(eqn):
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(stacks, h).jaxpr)
+
+
+@pytest.mark.parametrize("model,f,d_in", [
+    ("hgt", 20, 128), ("hgt", 25, 256), ("hgt", 24, 128), ("hgt", 32, 256),
+    ("rgat", 20, 128), ("rgat", 25, 256),
+])
+def test_attn_bwd_vmem_fits_at_the_cells_shapes(model, f, d_in):
+    """At ogbn-mag's shapes under HGT's published widths (H 256, 8 heads;
+    25,600 parents with fanout 20 at input width 128, 1,024 with fanout 25
+    at 256, padded to 24 and 32), the backward's node block keeps its working
+    set within its budget, half the scoped VMEM both attention kernels ask
+    for, and stays a whole number of sublane tiles that divides the
+    forward's."""
+    from repro.kernels.stacked_relation_agg import (
+        stacked_attn_bwd_block,
+        stacked_attn_bwd_vmem_bytes,
+    )
+    from repro.kernels.stacked_relation_agg.ops import _BWD_VMEM_BUDGET
+
+    kw = dict(shared_v=model == "rgat", has_post=model == "hgt",
+              has_eb=model == "rgat")
+    n = 25600 if d_in == 128 else 1024
+    bb = stacked_attn_bwd_block(n, f, d_in, 8, 32, block_n=128, **kw)
+    assert bb % 8 == 0 and 128 % bb == 0
+    used = stacked_attn_bwd_vmem_bytes(n, f, d_in, 8, 32, block_n=bb, **kw)
+    assert used <= _BWD_VMEM_BUDGET
+    # the halving is what brings it under: a twice larger block would not fit
+    # wherever the block was cut
+    if bb < 128:
+        assert stacked_attn_bwd_vmem_bytes(
+            n, f, d_in, 8, 32, block_n=2 * bb, **kw) > _BWD_VMEM_BUDGET

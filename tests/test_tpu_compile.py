@@ -9,7 +9,8 @@ parity tests (``test_stacked_kernels.py``, ``test_kernels.py``) cover
 results.
 
 Widths are those of the ogbn-mag training configuration: batch 1024,
-fanout 25, input width 128, hidden 64, 4 heads.  ``kernel_choice`` picks the
+fanout 25, input width 128, hidden 64, 4 heads; and HGT's published 256
+hidden and 8 heads at both levels of the ogbn-mag cell.  ``kernel_choice`` picks the
 compiled kernels only when the backend is a TPU, so each test steers it by
 reporting ``"tpu"`` from ``jax.default_backend``.
 
@@ -120,10 +121,25 @@ def test_stacked_agg_forward_compiles_for_v5e(model, one_chip, on_tpu):
     assert set(kernels) == (MEAN_LINEAR if model == "rgcn" else ATTENTION)
 
 
+# kernels in one compiled gradient of an aggregation w.r.t. the stacks and
+# the neighbor rows: rgcn's forward and its ``dh`` kernel (its weight
+# gradient is XLA's); the attention family's forward, its backward (which
+# writes the neighbor rows' gradient too) and the q-side projection's
+# forward (the queries need no gradient, so its ``dh`` kernel is dropped)
+VJP_KERNELS = {
+    "rgcn": {"stacked_mean_linear_pallas": 1, "stacked_mean_linear_dh_pallas": 1},
+    "rgat": {"stacked_attn_epilogue_pallas": 1, "stacked_attn_bwd_pallas": 1,
+             "stacked_mean_linear_pallas": 1},
+    "hgt": {"stacked_attn_epilogue_pallas": 1, "stacked_attn_bwd_pallas": 1,
+            "stacked_mean_linear_pallas": 1},
+}
+
+
 @pytest.mark.parametrize("model", ["rgcn", "rgat", "hgt"])
 def test_stacked_agg_vjp_compiles_for_v5e(model, one_chip, on_tpu):
-    """The custom VJPs: forward-with-residuals plus the scalar-prefetch
-    ``dh`` kernels, gradients w.r.t. the stacks and the neighbor rows."""
+    """The custom VJPs: the forward kernels plus rgcn's scalar-prefetch
+    ``dh`` kernel or the attention backward, gradients w.r.t. the stacks
+    and the neighbor rows."""
     module, stacks, slot_u, h, q, mask = _agg_operands(model)
 
     def loss(stacks, h, slot_u, q, mask):
@@ -133,10 +149,40 @@ def test_stacked_agg_vjp_compiles_for_v5e(model, one_chip, on_tpu):
 
     vjp = jax.grad(loss, argnums=(0, 1))
     kernels = _compile(vjp, one_chip, stacks, h, slot_u, q, mask)
-    assert sum(kernels.values()) >= 2
-    assert set(kernels) == (
-        MEAN_LINEAR | {"stacked_mean_linear_dh_pallas"} if model == "rgcn"
-        else ATTENTION | {"stacked_attn_dh_pallas"})
+    assert dict(kernels) == VJP_KERNELS[model]
+
+
+# HGT at its published widths (arXiv:2003.01332: hidden 256, 8 heads) at
+# both levels of the ogbn-mag cell: 3 slots of 1,024 parents with 25
+# neighbors at input width 256 (the inner level, whose rows need a
+# gradient), 6 slots of 25,600 parents with 20 at 128 (the leaf level, rows
+# fixed)
+HGT_LEVELS = [(3, 1024, 25, 256, True), (6, 25600, 20, 128, False)]
+
+
+@pytest.mark.parametrize("rb,n,f,d_in,h_grad", HGT_LEVELS)
+def test_hgt_published_widths_attention_compiles_for_v5e(
+        rb, n, f, d_in, h_grad, one_chip, on_tpu):
+    """The fused attention forward and backward at 256 hidden and 8 heads
+    fit the scoped VMEM they ask for; the backward writes the neighbor rows'
+    gradient only at the level that needs it."""
+    module = get_relation_module("hgt")
+    sc = ShapeCtx(hidden=256, num_heads=8, head_dim=32, d_src=d_in, d_dst=D_IN)
+    stacks = {s.name: jnp.zeros((rb,) + tuple(s.shape(sc)), jnp.float32)
+              for s in module.specs}
+    slot_u = {scope: jnp.zeros((rb,), jnp.int32) for scope in module.scopes}
+    h = jnp.zeros((rb, n, f, d_in), jnp.float32)
+    q = jnp.zeros((rb, n, D_IN), jnp.float32)
+    mask = jnp.zeros((rb, n, f), bool)
+
+    def loss(stacks, h, slot_u, q, mask):
+        out = stacked_agg(module, stacks, slot_u, h, q, mask,
+                          opts=KernelOptions())
+        return jnp.sum(out * out)
+
+    grad = jax.grad(loss, argnums=(0, 1) if h_grad else 0)
+    kernels = _compile(grad, one_chip, stacks, h, slot_u, q, mask)
+    assert dict(kernels) == VJP_KERNELS["hgt"]
 
 
 def test_attn_parts_softmax_combine_compiles_for_v5e(one_chip, on_tpu):
