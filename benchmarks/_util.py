@@ -86,15 +86,24 @@ def dram_random_time(bytes_: float) -> float:
     return bytes_ / DRAM_RANDOM_BPS
 
 
-def timed_fit(sess, steps: int, warmup: int = 2):
+def timed_fit(sess, steps: int, warmup: int = 2, serial: bool = False):
     """Warm up a compiled :class:`repro.api.Heta` session, then time
     ``fit(steps)``: returns ``(wall_per_step_s, overlap_fraction)`` over the
     timed steps only (the session's cumulative ``overlap_fraction`` would
-    fold in the compile-dominated warmup)."""
-    sess.fit(warmup)
+    fold in the compile-dominated warmup).  ``fit`` overlaps host and
+    device; ``serial`` times a loop of ``Heta.step()`` instead, the serial
+    baseline (host stages in line)."""
+    def run(n):
+        if serial:
+            for _ in range(n):
+                sess.step()
+        else:
+            sess.fit(n)
+
+    run(warmup)
     n0 = len(sess.step_times)
     t0 = time.perf_counter()
-    sess.fit(steps)
+    run(steps)
     wall = time.perf_counter() - t0
     serial = sum(sess.host_times[n0:]) + sum(sess.step_times[n0:])
     overlap = max(0.0, 1.0 - wall / serial) if serial > 0 else 0.0

@@ -8,9 +8,9 @@ projected network time for remote features; Heta's stages are all local
 Built entirely on the public session + staged-executor surface
 (``Executor.stage`` / ``Executor.step_staged`` — no private imports, no
 hand-rolled training loop), and reports the async-pipeline overlap: the
-``pipelined`` mode re-runs the same steps through ``pipeline.enabled`` and
-emits serial vs overlapped step time plus the overlap fraction
-(host work hidden behind the device step).
+``pipelined`` mode re-runs the same steps through ``Heta.step`` (serial)
+and ``Heta.fit`` (overlapped) and emits both step times plus the overlap
+fraction (host work hidden behind the device step).
 
 ``--num-workers 0,1,2,4`` adds the multi-worker sampling sweep
 (DESIGN.md §9): pure sampling throughput of the thread producer vs N-process
@@ -119,10 +119,10 @@ def run_pipelined(scale: float = 0.002, batch: int = 32, fanouts=(5, 4),
     process-warm jit cache would bias whichever mode runs second (the
     per-stage loop in :func:`run` still measures the learnable path)."""
     results = {}
-    for mode, pipeline in (("serial", {}), ("overlapped", dict(enabled=True))):
-        sess = _session(scale, batch, fanouts, steps, train_learnable=False,
-                        **pipeline)
-        wall_per_step, overlap = timed_fit(sess, steps)
+    for mode in ("serial", "overlapped"):
+        sess = _session(scale, batch, fanouts, steps, train_learnable=False)
+        wall_per_step, overlap = timed_fit(sess, steps,
+                                           serial=mode == "serial")
         results[mode] = dict(wall_per_step_s=wall_per_step,
                              overlap_fraction=overlap)
         emit(f"breakdown/pipeline/{mode}_step", wall_per_step * 1e6,

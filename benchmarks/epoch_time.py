@@ -71,9 +71,10 @@ def _measured_step(model: str, local: bool) -> float:
 
 def _measured_fit(pipelined: bool, steps: int = 16,
                   num_workers: int = 0) -> tuple:
-    """End-to-end fit wall time per step, async host pipeline on vs off —
-    identical batches either way (per-batch sampler RNG), so the difference
-    is purely the sample+stage work hidden behind the device step.  On a
+    """End-to-end wall time per step, a ``Heta.step()`` loop (host stages
+    in line) vs ``Heta.fit`` (sample+stage overlapped) — identical batches
+    either way (per-batch sampler RNG), so the difference is purely the
+    sample+stage work hidden behind the device step.  On a
     CPU-only host the win is modest (the producer shares cores + the GIL
     with the jitted step); the breakdown benchmark reports the overlap
     fraction the stream actually achieved.  ``num_workers`` selects the
@@ -99,7 +100,7 @@ def _measured_fit(pipelined: bool, steps: int = 16,
     try:
         # warmup inside timed_fit spawns the pool; the timed fit reuses it,
         # so the figure is steady-state, not worker spawn cost
-        wall, overlap = timed_fit(sess, steps)
+        wall, overlap = timed_fit(sess, steps, serial=not pipelined)
         return wall, overlap, sess.results()["queue_bytes_per_step"]
     finally:
         sess.close_pipeline()
@@ -112,7 +113,7 @@ def run_worker_fit_sweep(workers=(0, 1, 2, 4), steps: int = 16):
     import os
 
     t_serial, _, _ = _measured_fit(pipelined=False, steps=steps)
-    emit("pipeline/fit/serial_step", t_serial * 1e6, "no pipeline",
+    emit("pipeline/fit/serial_step", t_serial * 1e6, "Heta.step loop",
          workers=-1, kind="fit", batch_size=32,
          queue_bytes_per_step=0, cpus=os.cpu_count())
     for w in workers:
@@ -138,7 +139,7 @@ def run():
         emit(f"epoch/measured/{model}/naive_step", t_naive * 1e6,
              "naive placement (adds inner-level exchange; ~equal on 1 device)")
 
-    # ablation: async host pipeline on vs off (same batches, same model)
+    # ablation: a Heta.step loop vs the overlapped Heta.fit (same batches)
     t_serial, _, _ = _measured_fit(pipelined=False)
     t_pipe, overlap, _ = _measured_fit(pipelined=True)
     emit("epoch/pipeline/serial_step", t_serial * 1e6, "host stages in line")

@@ -7,9 +7,10 @@ the regime Heta's cache targets (paper §2.3: learnable-feature updates are
 24-35% of DGL's epoch time).
 
 Run:  PYTHONPATH=src python examples/train_100m.py [--steps 200]
-      (add --pipeline for the async host pipeline, --num-workers N to feed
-      the device from N sampler processes over the shared-memory graph
-      store — DESIGN.md §9)
+      (fit overlaps host sampling with the device step on a thread either
+      way; add --num-workers N to feed the device from N sampler processes
+      over the shared-memory graph store — DESIGN.md §9, --pipeline to
+      stage the learnable tables ahead too, bounded-stale)
 """
 
 import argparse
@@ -28,7 +29,7 @@ def main():
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch-size", type=int, default=128)
     ap.add_argument("--pipeline", action="store_true",
-                    help="overlap host sampling+staging with the device step")
+                    help="stage learnable tables ahead, bounded-stale")
     ap.add_argument("--num-workers", type=int, default=0,
                     help="sampler worker processes (0 = one thread)")
     args = ap.parse_args()

@@ -42,9 +42,9 @@ The three execution models all satisfy one staged-step protocol
 (``build_plan / init_state / stage / step_staged / loss_and_metrics``, with
 ``step`` as the serial ``stage``+``step_staged`` composition) and are
 selected by name through the registry.  The ``stage``/``step_staged`` split
-is the seam the async host pipeline (``repro.data``, enabled via
-``PipelineConfig``) uses to overlap sampling + feature staging with the
-device step::
+is the seam ``Heta.fit`` drives through the async host pipeline
+(``repro.data``) to overlap sampling + feature staging with the device
+step::
 
     from repro.api import executors
     executors.available()                  # ("raf", "raf_spmd", "vanilla")

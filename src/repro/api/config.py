@@ -205,15 +205,18 @@ class RunConfig:
 
 @dataclasses.dataclass(frozen=True)
 class PipelineConfig:
-    """Async host pipeline: overlap sampling + feature staging with the
-    device step (see the ``repro.data`` package docstring for the design
-    and the staleness semantics of ``snapshot``).
+    """Async host pipeline.  ``Heta.fit`` always overlaps sampling and
+    staging with the device step on one producer thread, bit-exact (see
+    the ``repro.data`` package docstring); ``depth`` bounds its lookahead.
 
-    ``num_workers`` selects the producer: 0 (default) keeps the single
-    background thread; N > 0 runs a pool of N sampler *processes* over a
-    shared-memory graph store (``repro.data.worker_pool``, DESIGN.md §9) —
-    bit-identical batches for any worker count, ``depth`` prefetched items
-    per worker.
+    ``enabled`` no longer gates that overlap.  It opts into what changes
+    results or starts processes: with ``snapshot="stale"``, staging that
+    reads learnable tables runs ahead on the producer against
+    bounded-stale tables (``"fresh"`` keeps it on the consumer, as the
+    default does); ``num_workers`` N > 0 runs a pool of N sampler
+    *processes* over a shared-memory graph store
+    (``repro.data.worker_pool``, DESIGN.md §9) — bit-identical batches for
+    any worker count, ``depth`` prefetched items per worker.
 
     ``arena`` (pool mode only) moves batch payloads off the queues into a
     fixed-slot shared-memory ring buffer (the batch arena, DESIGN.md §11):
@@ -248,8 +251,9 @@ class PipelineConfig:
         if self.num_workers > 0 and not self.enabled:
             raise ValueError(
                 "pipeline.num_workers > 0 requires pipeline.enabled "
-                "(pass --pipeline / pipeline=dict(enabled=True, ...)); a "
-                "worker pool only exists inside the async host pipeline"
+                "(pass --pipeline / pipeline=dict(enabled=True, ...)): "
+                "sampler worker processes are an opt-in; fit overlaps "
+                "sampling and staging on one thread without it"
             )
 
 
@@ -704,7 +708,10 @@ _CLI_OVERRIDES: Dict[Tuple[str, str], Tuple[str, Optional[Callable], str]] = {
         "--readmit-every", int,
         "online cache re-admission period in steps (0 = one-shot)"),
     ("run", "mesh_shape"): ("--mesh", _parse_mesh, "DATAxMODEL mesh, e.g. 2x4"),
-    ("pipeline", "enabled"): ("--pipeline", None, "async host pipeline on/off"),
+    ("pipeline", "enabled"): (
+        "--pipeline", None,
+        "opt into stale learnable staging and sampler worker pools (fit "
+        "overlaps sampling and staging with the device step either way)"),
     ("pipeline", "depth"): ("--prefetch-depth", int, "pipeline prefetch depth"),
     ("pipeline", "snapshot"): (
         "--snapshot-policy", str, f"learnable-table snapshot policy {SNAPSHOT_POLICIES}"),

@@ -434,20 +434,27 @@ class Heta:
         """Train for ``steps`` (default ``RunConfig.steps``); returns the
         result dict (same keys the legacy ``train_hgnn`` returned).
 
-        With ``pipeline.enabled`` the loop is driven by a
-        :class:`repro.data.SampleStream`: sampling + staging for batch
-        *i+1* runs in the background while batch *i* trains, under the
-        configured snapshot staleness policy — in one producer thread by
-        default, or in ``pipeline.num_workers`` sampler processes over a
-        shared-memory graph store (DESIGN.md §9), batches flowing through
-        the zero-pickle batch arena (DESIGN.md §11) unless
-        ``pipeline.arena`` is off.  The pool + store + arena persist
-        across consecutive ``fit()`` calls (spawn cost amortizes; see
-        :meth:`close_pipeline`) and are torn down on error.  Batches are
-        bit-identical to the serial path for any worker count (per-batch
-        RNG); losses are bit-identical too except pooled learnable
-        training under ``snapshot="stale"``, where workers stage against
-        bounded-stale tables (staleness ≤ ring depth)."""
+        Wherever the executor implements the staged-step seam, the loop is
+        driven by a :class:`repro.data.SampleStream`: sampling and staging
+        of batch *i+1* run on a producer thread while batch *i* trains.
+        Staging runs there only where it reads no table that trains
+        (``stage_reads_tables`` False: frozen rows, or the resident
+        path); otherwise the producer only samples and the consumer
+        stages against the current tables.  So batches, staged arrays and
+        losses are bit-identical to a loop of :meth:`step`; only when the
+        work happens changes.  Executors without the seam keep that loop.
+
+        ``pipeline.enabled`` opts into what changes results or starts
+        processes: ``snapshot="stale"`` stages learnable tables on the
+        producer too, bounded-stale (staleness ≤ depth + 1), and
+        ``pipeline.num_workers`` sampler processes over a shared-memory
+        graph store (DESIGN.md §9) take the thread's place, batches
+        flowing through the zero-pickle batch arena (DESIGN.md §11)
+        unless ``pipeline.arena`` is off.  The pool + store + arena
+        persist across consecutive ``fit()`` calls (spawn cost amortizes;
+        see :meth:`close_pipeline`) and are torn down on error.  Batches
+        are bit-identical for any worker count (per-batch RNG); losses
+        are too except learnable training under ``snapshot="stale"``."""
         self._require("state", "compile", "fit")
         steps = self.config.run.steps if steps is None else steps
         if steps and self.config.scale.enabled:
@@ -467,18 +474,20 @@ class Heta:
 
         t_wall = time.perf_counter()
         n0 = len(self.step_times)
-        if steps and self.config.pipeline.enabled:
-            if not self._staged_protocol():
-                raise HetaStageError(
-                    f"executor {self.executor.name!r} does not implement the "
-                    "staged-step protocol (stage/step_staged) required by "
-                    "pipeline.enabled; disable the pipeline or implement it"
-                )
+        pcfg = self.config.pipeline
+        staged = self._staged_protocol()
+        if steps and pcfg.enabled and not staged:
+            raise HetaStageError(
+                f"executor {self.executor.name!r} does not implement the "
+                "staged-step protocol (stage/step_staged) required by "
+                "pipeline.enabled; disable the pipeline or implement it"
+            )
+        if steps and staged:
             from repro.data.sample_stream import SampleStream
 
-            pcfg = self.config.pipeline
             start = self._steps_done
-            defer = (pcfg.snapshot == "fresh"
+            stale = pcfg.enabled and pcfg.snapshot == "stale"
+            defer = (not stale
                      and self.executor.stage_reads_tables(self, self.plan))
             stream_kw = {}
             arena = None
@@ -535,8 +544,8 @@ class Heta:
     def evaluate(self, num_batches: int = 1, use_full_graph: bool = False) -> Dict:
         """Mean held-out-batch loss via the executor's eval path (no update).
 
-        With ``pipeline.enabled``, batches are prefetched in the background
-        — by a thread, or by ``pipeline.num_workers`` sampler processes
+        Batches are prefetched in the background — by a thread, or, with
+        ``pipeline.enabled``, by ``pipeline.num_workers`` sampler processes
         over a shared-memory graph store (eval staging never trains tables,
         so any producer is always bit-exact).
 
@@ -607,7 +616,7 @@ class Heta:
                 finally:
                     if arena is not None:
                         arena.unlink()
-        elif pcfg.enabled:
+        else:
             from repro.data.prefetch import Prefetcher
 
             with Prefetcher(
@@ -617,10 +626,6 @@ class Heta:
             ) as pf:
                 for b in pf:
                     metrics = consume(b)
-        else:
-            it = sampler.epoch(shuffle=True, seed=eval_seed)
-            for _ in range(n):
-                metrics = consume(next(it))
         return {"loss": float(np.mean(losses)), "num_batches": len(losses),
                 **{k: v for k, v in metrics.items() if k != "loss"}}
 

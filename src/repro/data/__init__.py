@@ -59,19 +59,20 @@ composition for callers that don't pipeline.
 ``batch_at`` is a pure function of position and pipeline-on/off produce
 bit-identical batches regardless of prefetch depth or thread scheduling.
 
-**Snapshot staleness policy** (``PipelineConfig.snapshot``).  With frozen
-feature tables staging is time-invariant, so the pipeline is bit-exact.
-When learnable tables train (``ModelConfig.train_learnable`` with an
-executor whose staging reads them, e.g. ``raf_spmd``), staging batch *i+k*
-in the background observes tables before steps *i..i+k-1* wrote back:
-
-* ``"stale"`` (default) — stage in the producer against a snapshot that may
-  lag by at most ``depth + 1`` steps (the queue bound).  Maximum overlap;
-  losses track the serial path within optimization noise, the standard
-  bounded-staleness trade every async-pipeline system makes.
-* ``"fresh"`` — producer only samples; table-reading staging runs on the
-  consumer right before the step.  Bit-exact parity with the serial loop,
-  overlapping only the sampling stage.
+**Snapshot staleness policy.**  ``Heta.fit`` drives every staged executor
+through a thread ``SampleStream``.  With frozen feature tables (or the
+resident path, whose staging reads no table) staging is time-invariant, so
+the producer stages too and the stream is bit-exact.  When learnable
+tables train (``ModelConfig.train_learnable`` with an executor whose
+staging reads them, e.g. ``raf_spmd`` on the host path), staging batch
+*i+k* in the background would observe tables before steps *i..i+k-1*
+wrote back, so by default the producer only samples and table-reading
+staging runs on the consumer right before the step: bit-exact parity with
+the serial loop, overlapping only the sampling stage.  The opt-in
+``PipelineConfig(enabled=True, snapshot="stale")`` stages in the producer
+instead, against a snapshot that may lag by at most ``depth + 1`` steps
+(the queue bound): losses track the serial path within optimization
+noise, the bounded-staleness trade every async-pipeline system makes.
 """
 
 from repro.data.pipeline import SyntheticCorpus, TokenPipeline

@@ -16,11 +16,17 @@ rather than hand-rolling thread + queue management:
     thread; ``__next__`` after ``close()`` raises :class:`RuntimeError`
     instead of blocking on an empty queue;
   * a finite ``num_items`` ends iteration with ``StopIteration`` once the
-    producer is exhausted (infinite when ``None``).
+    producer is exhausted (infinite when ``None``);
+  * ``last_ready`` says whether the item ``__next__`` returned last was
+    already waiting in the queue, i.e. the consumer did not block for it;
+  * on glibc, freed heap pages stay mapped (:func:`_keep_heap_pages`): a
+    producer thread's large short-lived arrays otherwise fault fresh pages
+    in every item, and the stream runs slower than the same work in line.
 """
 
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 from typing import Callable, Optional
@@ -39,6 +45,21 @@ class _Failure:
 
     def __init__(self, exc: BaseException):
         self.exc = exc
+
+
+@functools.cache
+def _keep_heap_pages() -> None:
+    """glibc only, once per process: a fixed 32 MiB mmap threshold and a
+    256 MiB top pad, so arrays under 32 MiB come from the thread's arena
+    and freed arena memory stays mapped for the next item to reuse."""
+    import ctypes
+
+    try:
+        mallopt = ctypes.CDLL("libc.so.6").mallopt
+    except (OSError, AttributeError):
+        return
+    mallopt(-3, 32 << 20)  # M_MMAP_THRESHOLD
+    mallopt(-2, 256 << 20)  # M_TOP_PAD
 
 
 class Prefetcher:
@@ -62,8 +83,10 @@ class Prefetcher:
         self.depth = depth
         self.num_items = num_items
         self._q: "queue.Queue" = queue.Queue(maxsize=depth)
+        self.last_ready = False
         self._stop = threading.Event()
         self._closed = False
+        _keep_heap_pages()
         self._thread = threading.Thread(target=self._producer, name=name,
                                         daemon=True)
         self._thread.start()
@@ -111,10 +134,14 @@ class Prefetcher:
     def __next__(self):
         if self._closed:
             raise RuntimeError("Prefetcher is closed")
+        ready = True
         while True:
             try:
-                item = self._q.get(timeout=_POLL_S)
+                item = self._q.get(not ready, _POLL_S)
             except queue.Empty:
+                if ready:  # nothing waiting: from here on, block and poll
+                    ready = False
+                    continue
                 if self._closed:
                     raise RuntimeError("Prefetcher is closed") from None
                 if not self._thread.is_alive():
@@ -130,6 +157,7 @@ class Prefetcher:
             if isinstance(item, _Failure):
                 self.close()
                 raise item.exc
+            self.last_ready = ready
             return item
 
     # -- lifecycle -----------------------------------------------------------

@@ -46,7 +46,9 @@ fraction (host work that ran concurrently with the device step costs no
 wall time).  In this process it is the summed duration of the item's
 spans (``repro.obs``: ``heta.sample``, ``heta.stage``), which carry the
 item's global step ``first_step + i`` and the caller's ``session``; the
-consumer's wait for an item is the span ``heta.pipeline.wait``.
+consumer's wait for an item is the span ``heta.pipeline.wait``, and where
+the item was already waiting (the consumer did not block) the counter
+``heta.pipeline.ready`` counts 1 in that span.
 Exceptions raised in any producer — thread or process — surface in the
 consumer's ``__next__``; ``close()`` joins everything and is idempotent.
 """
@@ -164,7 +166,10 @@ class SampleStream:
     @staticmethod
     def _draw(source):
         with obs.span("heta.pipeline.wait") as wait:
-            return next(source), wait
+            item = next(source)
+            if source.last_ready:
+                obs.count("heta.pipeline.ready")
+            return item, wait
 
     def _next_item(self) -> Tuple[object, object, float, obs.span]:
         if self._pool is not None:

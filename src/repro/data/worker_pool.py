@@ -186,6 +186,8 @@ class WorkerPool:
     ``restarts`` records one event dict per respawn —
     ``{"wid", "item", "exitcode", "attempt", "downtime_s"}`` — the
     recovery-time figure ``benchmarks/fault_drill.py`` reports.
+    ``last_ready`` says whether the item ``__next__`` returned last was
+    already waiting in its worker's queue (the consumer did not block).
     """
 
     def __init__(
@@ -225,6 +227,7 @@ class WorkerPool:
         self._next = 0
         self._closed = False
         self._done = False
+        self.last_ready = False
         try:
             with _spawnable_main():
                 for w in range(num_workers):
@@ -252,12 +255,16 @@ class WorkerPool:
         if self._done:
             raise StopIteration
         w = self._next % self.num_workers
+        ready = True
         while True:
             q, proc = self._queues[w], self._procs[w]
             try:
-                item = q.get(timeout=_POLL_S)
+                item = q.get(not ready, _POLL_S)
                 break
             except _queue.Empty:
+                if ready:  # nothing waiting: from here on, block and poll
+                    ready = False
+                    continue
                 if not proc.is_alive():
                     # a last put may still be in flight in the feeder pipe
                     try:
@@ -288,6 +295,7 @@ class WorkerPool:
                     f"worker traceback:\n{item.tb}")
             raise item.exc
         self._next += 1
+        self.last_ready = ready
         return item
 
     # -- supervision ---------------------------------------------------------

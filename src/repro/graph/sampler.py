@@ -158,15 +158,21 @@ def sample_neighbors(
     Degree-0 parents (and invalid parents) yield masked slots pointing at 0.
     """
     n = len(parents)
-    deg = csr.indptr[parents + 1] - csr.indptr[parents]  # [n]
+    start = csr.indptr[parents]
+    deg = csr.indptr[parents + 1] - start  # [n]
     valid = (deg > 0) & parent_mask
     if csr.num_edges == 0:
         return np.zeros((n, fanout), np.int64), np.zeros((n, fanout), bool)
-    safe_deg = np.maximum(deg, 1)
-    offs = (rng.random((n, fanout)) * safe_deg[:, None]).astype(np.int64)
-    raw = csr.indptr[parents][:, None] + offs
-    raw = np.minimum(raw, csr.num_edges - 1)  # clamp degree-0 tail slots
-    idx = np.where(valid[:, None], csr.indices[raw], 0)
+    # in place where it can be: each [n, fanout] temporary is megabytes
+    offs = rng.random((n, fanout))
+    offs *= np.maximum(deg, 1)[:, None]
+    raw = offs.astype(np.int64)
+    del offs
+    raw += start[:, None]
+    np.minimum(raw, csr.num_edges - 1, out=raw)  # clamp degree-0 tail slots
+    idx = csr.indices[raw]
+    del raw
+    idx[~valid] = 0
     mask = np.broadcast_to(valid[:, None], (n, fanout)).copy()
     return idx, mask
 

@@ -591,3 +591,120 @@ def test_seedless_epochs_vary_but_replay_deterministically():
     assert not np.array_equal(e1a.levels[0].nids, e1b.levels[0].nids)
     _assert_batches_equal(e1a, next(s2.epoch()))
     _assert_batches_equal(e1b, next(s2.epoch()))
+
+
+# --------------------------------------------------------------------------
+# Heta.fit overlaps by default: with no pipeline option it drives a thread
+# SampleStream, bit-identical to a loop of the serial Heta.step()
+# --------------------------------------------------------------------------
+
+
+def _fit_cfg(executor="raf_spmd", cache_mb=6, learnable=False):
+    from repro.api import (CacheConfig, DataConfig, HetaConfig, ModelConfig,
+                           PartitionConfig, RunConfig)
+
+    return HetaConfig(
+        data=DataConfig(dataset="ogbn-mag", scale=0.002, fanouts=(3, 2),
+                        batch_size=16),
+        partition=PartitionConfig(num_partitions=2),
+        model=ModelConfig(hidden=32, train_learnable=learnable),
+        cache=CacheConfig(cache_mb=cache_mb),
+        run=RunConfig(executor=executor, steps=4, lr=1e-2, seed=0),
+    )
+
+
+def _compiled(cfg):
+    from repro.api import Heta
+
+    sess = Heta(cfg)
+    sess.build_graph()
+    sess.partition()
+    sess.profile_and_cache()
+    sess.compile()
+    return sess
+
+
+# (executor, cache MiB, learnable, the resident gather let engage on the
+# CPU): 6 MiB holds the whole tiny graph, 2 MiB part of it
+FIT_PATHS = {
+    "spmd-resident-frozen": ("raf_spmd", 6, False, True),
+    "spmd-resident-learnable": ("raf_spmd", 6, True, True),
+    "spmd-host-frozen": ("raf_spmd", 2, False, False),
+    "spmd-host-learnable": ("raf_spmd", 2, True, False),
+    "vanilla": ("vanilla", 6, True, False),
+}
+
+
+def _fit_path_cfg(path, monkeypatch):
+    from repro.api import executors
+
+    executor, cache_mb, learnable, resident = FIT_PATHS[path]
+    if resident:
+        monkeypatch.setattr(executors, "HOST_MEMORY_PLATFORMS", frozenset())
+    return _fit_cfg(executor, cache_mb, learnable)
+
+
+@pytest.mark.parametrize("path", list(FIT_PATHS))
+def test_fit_is_bit_identical_to_a_loop_of_step(path, monkeypatch):
+    import jax
+
+    executor, _, learnable, resident = FIT_PATHS[path]
+    cfg = _fit_path_cfg(path, monkeypatch)
+    fitted, stepped = _compiled(cfg), _compiled(cfg)
+    assert not cfg.pipeline.enabled
+    fitted.fit(steps=4)
+    for _ in range(4):
+        stepped.step()
+    assert fitted.losses == stepped.losses
+    for a, b in zip(jax.tree.leaves(fitted.state), jax.tree.leaves(stepped.state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    tables = [s.engine.state_snapshot()["tables"] for s in (fitted, stepped)]
+    assert sorted(tables[0]) == sorted(tables[1])
+    for t in tables[0]:
+        np.testing.assert_array_equal(tables[0][t], tables[1][t])
+    if learnable and executor == "raf_spmd":
+        # the rows trained, so a stale staging would have shown
+        assert not np.array_equal(tables[0]["author"],
+                                  _compiled(cfg).engine.table("author"))
+    rows = fitted.results()["counters"]
+    assert ("heta.stage.device_rows" in rows) == resident
+    assert fitted.results()["spans"]["heta.pipeline.wait"]["count"] == 4
+
+
+@pytest.mark.parametrize("path,on_producer", [
+    ("spmd-host-frozen", True),
+    ("spmd-host-learnable", False),
+    ("spmd-resident-learnable", True),
+])
+def test_fit_stages_on_the_producer_exactly_when_staging_reads_no_table(
+        path, on_producer, monkeypatch):
+    sess = _compiled(_fit_path_cfg(path, monkeypatch))
+    assert sess.executor.stage_reads_tables(sess, sess.plan) is not on_producer
+    staged_on = []
+    real = sess.executor.stage
+
+    def stage(*args):
+        staged_on.append(threading.get_ident())
+        return real(*args)
+
+    sess.executor.stage = stage
+    sess.fit(steps=3)
+    assert len(staged_on) == 3
+    here = threading.get_ident()
+    assert all((t != here) is on_producer for t in staged_on)
+
+
+def test_fit_raises_what_the_producer_raised():
+    sess = _compiled(_fit_cfg(cache_mb=2))
+    real = sess._batch_for_step
+
+    def sample(s):
+        if s == 2:
+            raise KeyError("no batch 2")
+        return real(s)
+
+    sess._batch_for_step = sample
+    with pytest.raises(KeyError, match="no batch 2"):
+        sess.fit(steps=4)
+    assert len(sess.losses) == 2
+    assert not [t for t in threading.enumerate() if t.name == "sample-stream"]

@@ -174,12 +174,16 @@ def _step_books(sess, recs):
 
 
 def test_session_books_are_span_durations_on_the_serial_path():
+    """``Heta.step`` is the serial surface: each step samples, stages and
+    runs the device step inside its own ``heta.step``."""
     sess = Heta(_tiny())
     sess.build_graph()
     sess.partition()
     sess.profile_and_cache()
     sess.compile()
-    m = sess.fit(steps=3)
+    for _ in range(3):
+        sess.step()
+    m = sess.results()
     recs = _mine(sess.obs_session)
     host, step = _step_books(sess, recs)
     assert sess.host_times == host
@@ -206,10 +210,11 @@ def test_session_books_are_span_durations_on_the_serial_path():
         t.nbytes for t in snap.values())
 
 
-def test_session_books_on_the_thread_pipeline():
-    """Pipelined: the device step's span carries the step; the host time is
-    the stream's, the summed sampling and staging spans of the item."""
-    sess = Heta(_tiny(enabled=True, depth=2))
+def _stream_books(**pipeline):
+    """The session's books after ``fit(steps=4)``: the device step's span
+    carries the step; the host time is the stream's, the summed sampling
+    and staging spans of the item."""
+    sess = Heta(_tiny(**pipeline))
     sess.build_graph()
     sess.partition()
     sess.profile_and_cache()
@@ -223,6 +228,68 @@ def test_session_books_on_the_thread_pipeline():
                 and r.name in ("heta.sample", "heta.stage")]
         assert sorted(r.name for r in host) == ["heta.sample", "heta.stage"]
         assert sess.host_times[s] == pytest.approx(sum(r.seconds for r in host))
+    return sess
+
+
+def test_session_books_on_the_thread_pipeline():
+    sess = _stream_books(enabled=True, depth=2)
+    assert sess.results()["pipeline"]
+
+
+def test_session_books_of_fit_without_the_opt_in_are_the_streams():
+    """``fit`` overlaps with no pipeline option too; ``results`` still
+    reports the opt-in as off."""
+    sess = _stream_books()
+    m = sess.results()
+    assert not m["pipeline"]
+    assert m["spans"]["heta.pipeline.wait"]["count"] == 4
+
+
+def _ready_counts(sess):
+    """Per ``heta.pipeline.wait`` span of ``sess``: (step, its ready count)."""
+    return [(r.step, (r.counts or {}).get("heta.pipeline.ready", 0))
+            for r in _mine(sess.obs_session) if r.name == "heta.pipeline.wait"]
+
+
+@pytest.mark.parametrize("slow", ["neither", "producer", "consumer"])
+def test_pipeline_ready_counts_the_items_the_consumer_did_not_wait_for(slow):
+    """``heta.pipeline.ready`` counts 1, in the wait span of the item's
+    step, where the item was already in the queue: never when sampling is
+    slower than the step, and after the first item when the step is."""
+    sess = Heta(_tiny())
+    sess.build_graph()
+    sess.partition()
+    sess.profile_and_cache()
+    sess.compile()
+    sess.fit(steps=2)  # compiles the step (twice over its first two)
+    if slow == "producer":
+        sample = sess._batch_for_step
+
+        def late(s):
+            time.sleep(0.3)
+            return sample(s)
+
+        sess._batch_for_step = late
+    elif slow == "consumer":
+        step = sess.executor.step_staged
+
+        def slow_step(*args):
+            time.sleep(0.3)
+            return step(*args)
+
+        sess.executor.step_staged = slow_step
+    sess.fit(steps=4)
+    counts = _ready_counts(sess)
+    assert [s for s, _ in counts] == list(range(6))
+    assert all(n in (0, 1) for _, n in counts)
+    ready = sum(n for s, n in counts if s >= 2)
+    assert obs.counters(sess.obs_session).get("heta.pipeline.ready", 0) == sum(
+        n for _, n in counts)
+    assert ready <= 4
+    if slow == "producer":
+        assert ready == 0
+    elif slow == "consumer":
+        assert ready >= 3
 
 
 @pytest.mark.parametrize("cache_mb,path", [(6, "device"), (2, "host")],

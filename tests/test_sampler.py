@@ -115,3 +115,37 @@ def test_all_datasets_sample():
         sampler = NeighborSampler(g, spec, 4, seed=0)
         b = sampler.sample_batch(g.train_nodes[:4])
         assert b.total_sampled() > 4
+
+
+def _plain_sample_neighbors(csr, parents, parent_mask, fanout, rng):
+    """The draw written out with a fresh array per operation."""
+    deg = csr.indptr[parents + 1] - csr.indptr[parents]
+    valid = (deg > 0) & parent_mask
+    offs = (rng.random((len(parents), fanout))
+            * np.maximum(deg, 1)[:, None]).astype(np.int64)
+    raw = np.minimum(csr.indptr[parents][:, None] + offs, csr.num_edges - 1)
+    idx = np.where(valid[:, None], csr.indices[raw], 0)
+    return idx, np.broadcast_to(valid[:, None], idx.shape)
+
+
+@pytest.mark.parametrize("num_dst,num_edges,fanout,masked", [
+    (50, 400, 6, False),   # every parent has neighbors
+    (300, 40, 5, False),   # most parents have degree 0
+    (80, 300, 3, True),    # some parents masked out
+    (60, 300, 1, True),
+])
+def test_sample_neighbors_is_the_plain_draw_bit_for_bit(num_dst, num_edges,
+                                                         fanout, masked):
+    g = np.random.default_rng(num_dst + num_edges)
+    csr = CSR.from_edges(g.integers(0, 40, num_edges),
+                         g.integers(0, num_dst, num_edges), num_dst)
+    parents = g.integers(0, num_dst, 97)
+    pm = g.random(97) < 0.7 if masked else np.ones(97, bool)
+    idx, mask = sample_neighbors(csr, parents, pm, fanout,
+                                 np.random.default_rng(7))
+    ref_idx, ref_mask = _plain_sample_neighbors(csr, parents, pm, fanout,
+                                                np.random.default_rng(7))
+    assert idx.dtype == ref_idx.dtype and mask.dtype == ref_mask.dtype
+    np.testing.assert_array_equal(idx, ref_idx)
+    np.testing.assert_array_equal(mask, ref_mask)
+    assert mask.flags.writeable
