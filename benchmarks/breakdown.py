@@ -135,8 +135,7 @@ def run_pipelined(scale: float = 0.002, batch: int = 32, fanouts=(5, 4),
 
 def run_worker_sweep(scale: float = 0.01, batch: int = 64, fanouts=(10, 10),
                      steps: int = 32, workers=(0, 1, 2, 4),
-                     repeats: int = 3, arena: bool = True,
-                     legacy_diagnosis: bool = True):
+                     repeats: int = 3):
     """Sampling-throughput scaling of the host pipeline's producer.
 
     Every configuration materializes the *same* batches for the same
@@ -151,16 +150,9 @@ def run_worker_sweep(scale: float = 0.01, batch: int = 64, fanouts=(10, 10),
     container cannot show more than 2x of *aggregate* CPU — though it can
     exceed 2x vs a 1-worker baseline that leaves the consumer core idle.
 
-    ``arena=True`` (default) routes pool batches through the shm batch
-    arena (DESIGN.md §11): the queue carries ~10^2-byte SlotRef
-    descriptors instead of ~10^6-byte pickled batches.  The pickle
-    transport was exactly the ``workers1`` regression of the PR-5 rows
-    (50k vs 99.5k samples/s): one worker pays serialize + pipe-write and
-    the consumer pays read + deserialize of the full batch it could have
-    sampled itself — pure overhead the arena removes.
-    ``legacy_diagnosis=True`` re-times ``workers=1`` over the pickle path
-    and emits the ``queue_bytes_per_item`` of both transports so the
-    regression (and its fix) stays visible in ``BENCH_pipeline.json``."""
+    Pool batches flow through the shm batch arena (DESIGN.md §11): the
+    queue carries ~10^2-byte SlotRef descriptors, recorded per row as
+    ``queue_bytes_per_item``."""
     import pickle
 
     from repro.data.prefetch import Prefetcher
@@ -183,8 +175,8 @@ def run_worker_sweep(scale: float = 0.01, batch: int = 64, fanouts=(10, 10),
     sched = EpochSchedule(7, E)
     warm = 2
 
-    def time_config(w, use_arena):
-        """(samples/s, mean queue item bytes) for one producer config."""
+    def time_config(w):
+        """(samples/s, queue item bytes) for one producer config."""
         n = steps * repeats + warm
         store = ring = None
         qbytes = []
@@ -198,15 +190,12 @@ def run_worker_sweep(scale: float = 0.01, batch: int = 64, fanouts=(10, 10),
             src = Prefetcher(make, depth=2, num_items=n, name="sweep-thread")
         else:
             store = share_graph(g, include_features=False)
-            if use_arena:
-                probe = NeighborSampler(g, spec, batch,
-                                        seed=1).batch_at(0, epoch_seed=7)
-                ring = create_arena(arena_fields(probe), num_workers=w,
-                                    depth=2)
+            probe = NeighborSampler(g, spec, batch,
+                                    seed=1).batch_at(0, epoch_seed=7)
+            ring = create_arena(arena_fields(probe), num_workers=w, depth=2)
             task = SampleStageTask(
                 handle=store.handle, spec=spec, batch_size=batch,
-                sampler_seed=1, schedule=sched,
-                arena=ring.handle if ring is not None else None)
+                sampler_seed=1, schedule=sched, arena=ring.handle)
             src = WorkerPool(task, num_workers=w, depth=2, num_items=n)
         try:
             it = iter(src)
@@ -218,8 +207,6 @@ def run_worker_sweep(scale: float = 0.01, batch: int = 64, fanouts=(10, 10),
                         qbytes.append(len(pickle.dumps(item)))
                     unpack_slot(ring.resolve(item.slot, item.use), spec)
                     ring.release(item.slot, item.use)
-                elif w > 0 and not qbytes:
-                    qbytes.append(len(pickle.dumps(item)))
 
             for _ in range(warm):
                 draw()
@@ -239,27 +226,15 @@ def run_worker_sweep(scale: float = 0.01, batch: int = 64, fanouts=(10, 10),
 
     results = {}
     for w in workers:
-        sps, qb = time_config(w, use_arena=arena)
+        sps, qb = time_config(w)
         results[w] = sps
         emit(f"pipeline/sampling/workers{w}", batch / sps * 1e6,
              f"{sps:,.0f} samples/s"
              + (f", {qb} B/queue item" if w else ""),
              workers=w, samples_per_s=round(sps, 1), batch_size=batch,
              fanouts=list(fanouts), kind="sampling",
-             queue_bytes_per_item=qb, arena=bool(arena and w),
+             queue_bytes_per_item=qb, arena=bool(w),
              cpus=os.cpu_count())
-    if legacy_diagnosis and arena and 1 in results:
-        sps, qb = time_config(1, use_arena=False)
-        emit("pipeline/sampling/workers1_legacy", batch / sps * 1e6,
-             f"{sps:,.0f} samples/s over the pickle queue "
-             f"({qb} B/item — the PR-5 workers1 regression)",
-             workers=1, samples_per_s=round(sps, 1), batch_size=batch,
-             fanouts=list(fanouts), kind="sampling",
-             queue_bytes_per_item=qb, arena=False, cpus=os.cpu_count())
-        emit("pipeline/sampling/workers1_arena_vs_legacy", 0.0,
-             f"{results[1] / sps:.2f}x from descriptor-only queues",
-             workers=1, speedup_vs_legacy=round(results[1] / sps, 3),
-             kind="sampling_scaling", cpus=os.cpu_count())
     base = results.get(1)
     if base:
         for w in sorted(results):
@@ -333,82 +308,6 @@ def run_consumer_completion(scale: float = 0.01, batch: int = 64,
     return {"stage_from_host_s": t_free, "copying_s": t_copy}
 
 
-def run_pinning_probe(scale: float = 0.01, batch: int = 64, fanouts=(10, 10),
-                      steps: int = 32, workers: int = 2, repeats: int = 3):
-    """``pipeline.pin_workers`` on/off over the arena pool, same batches.
-
-    Pinning helps when the scheduler migrates sampler workers across cores
-    mid-epoch (cold caches); on a container with fewer cores than workers
-    it is expected to be a wash or a small loss — the rows record whichever
-    way it goes, stamped with ``cpus`` so readers can tell the two regimes
-    apart.  No timing gate anywhere."""
-    from repro.data.prefetch import Prefetcher  # noqa: F401  (parity import)
-    from repro.data.staging import arena_fields, unpack_slot
-    from repro.data.worker_pool import (EpochSchedule, SampleStageTask,
-                                        WorkerPool)
-    from repro.graph.sampler import NeighborSampler
-    from repro.graph.shm import create_arena, share_graph
-
-    sess = Heta(HetaConfig(
-        data=DataConfig(dataset="ogbn-mag", scale=scale, fanouts=fanouts,
-                        batch_size=batch),
-        partition=PartitionConfig(num_partitions=2),
-        run=RunConfig(seed=3),
-    ))
-    sess.build_graph()
-    sess.partition()
-    g, spec = sess.graph, sess.spec
-    E = NeighborSampler(g, spec, batch).steps_per_epoch()
-    sched = EpochSchedule(7, E)
-    warm = 2
-
-    def time_pool(pin: bool) -> float:
-        n = steps * repeats + warm
-        store = share_graph(g, include_features=False)
-        probe = NeighborSampler(g, spec, batch, seed=1).batch_at(0,
-                                                                 epoch_seed=7)
-        ring = create_arena(arena_fields(probe), num_workers=workers, depth=2)
-        task = SampleStageTask(handle=store.handle, spec=spec,
-                               batch_size=batch, sampler_seed=1,
-                               schedule=sched, arena=ring.handle,
-                               pin_cpus=pin)
-        src = WorkerPool(task, num_workers=workers, depth=2, num_items=n)
-        try:
-            it = iter(src)
-
-            def draw():
-                item = next(it)
-                unpack_slot(ring.resolve(item.slot, item.use), spec)
-                ring.release(item.slot, item.use)
-
-            for _ in range(warm):
-                draw()
-            wall = float("inf")
-            for _ in range(repeats):
-                t0 = time.perf_counter()
-                for _ in range(steps):
-                    draw()
-                wall = min(wall, time.perf_counter() - t0)
-        finally:
-            src.close()
-            store.unlink()
-            ring.unlink()
-        return steps * batch / wall
-
-    base = time_pool(False)
-    pinned = time_pool(True)
-    for name, sps, pin in (("unpinned", base, False), ("pinned", pinned, True)):
-        emit(f"pipeline/sampling/workers{workers}_{name}", batch / sps * 1e6,
-             f"{sps:,.0f} samples/s", workers=workers, pin_workers=pin,
-             samples_per_s=round(sps, 1), batch_size=batch,
-             fanouts=list(fanouts), kind="sampling_pinning")
-    emit(f"pipeline/sampling/pinning_effect_w{workers}", 0.0,
-         f"{pinned / base:.2f}x pinned vs unpinned ({os.cpu_count()} cpus)",
-         workers=workers, speedup_pinned=round(pinned / base, 3),
-         kind="sampling_pinning")
-    return {"unpinned": base, "pinned": pinned}
-
-
 def _parse_workers(s: str):
     return tuple(int(x) for x in s.split(","))
 
@@ -427,23 +326,15 @@ if __name__ == "__main__":
                          "(e.g. BENCH_pipeline.json)")
     ap.add_argument("--skip-stages", action="store_true",
                     help="only the worker sweep, skip the per-stage breakdown")
-    ap.add_argument("--no-arena", action="store_true",
-                    help="sweep over the legacy pickle queues instead of the "
-                         "shm batch arena")
     ap.add_argument("--consumer", action="store_true",
                     help="probe the consumer completion (device-put-free "
                          "stage_from_host vs the copying reference)")
-    ap.add_argument("--pin-probe", type=int, default=0, metavar="W",
-                    help="probe pipeline.pin_workers on/off with W workers")
     args = ap.parse_args()
     if not args.skip_stages:
         run()
     if args.num_workers is not None:
-        run_worker_sweep(steps=args.sweep_steps, workers=args.num_workers,
-                         arena=not args.no_arena)
+        run_worker_sweep(steps=args.sweep_steps, workers=args.num_workers)
     if args.consumer:
         run_consumer_completion()
-    if args.pin_probe:
-        run_pinning_probe(workers=args.pin_probe)
     if args.records_out:
         write_records(args.records_out)

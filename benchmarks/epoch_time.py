@@ -89,9 +89,8 @@ def _measured_fit(pipelined: bool, steps: int = 16,
         run=RunConfig(executor="raf_spmd", mesh_shape=(1, 1), seed=1,
                       steps=steps),
     )
-    if pipelined:
-        cfg = cfg.updated(pipeline=dict(enabled=True,
-                                        num_workers=num_workers))
+    if num_workers:
+        cfg = cfg.updated(pipeline=dict(num_workers=num_workers))
     sess = Heta(cfg)
     sess.build_graph()
     sess.partition()

@@ -45,8 +45,7 @@ def _config(steps: int, pooled: bool):
         model=ModelConfig(hidden=32),
         cache=CacheConfig(cache_mb=2, presample_epochs=1),
         run=RunConfig(executor="raf_spmd", steps=steps, lr=1e-2, seed=0),
-        pipeline=PipelineConfig(enabled=pooled, num_workers=2 if pooled else 0,
-                                depth=2, snapshot="fresh"),
+        pipeline=PipelineConfig(num_workers=2 if pooled else 0, depth=2),
         faults=FaultConfig(max_worker_restarts=2, worker_backoff_s=0.01),
     )
 

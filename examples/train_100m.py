@@ -7,9 +7,9 @@ the regime Heta's cache targets (paper §2.3: learnable-feature updates are
 24-35% of DGL's epoch time).
 
 Run:  PYTHONPATH=src python examples/train_100m.py [--steps 200]
-      (fit overlaps host sampling with the device step on a thread either
-      way; add --num-workers N to feed the device from N sampler processes
-      over the shared-memory graph store — DESIGN.md §9, --pipeline to
+      (fit overlaps host sampling with the device step on a thread; add
+      --num-workers N to feed the device from N sampler processes over the
+      shared-memory graph store — DESIGN.md §9, --snapshot-policy stale to
       stage the learnable tables ahead too, bounded-stale)
 """
 
@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.api import (
     CacheConfig, DataConfig, Heta, HetaConfig, ModelConfig, PartitionConfig,
-    RunConfig,
+    PipelineConfig, RunConfig,
 )
 
 
@@ -28,8 +28,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch-size", type=int, default=128)
-    ap.add_argument("--pipeline", action="store_true",
-                    help="stage learnable tables ahead, bounded-stale")
+    ap.add_argument("--snapshot-policy", choices=("fresh", "stale"),
+                    default="fresh",
+                    help="stale: stage learnable tables ahead, bounded-stale")
     ap.add_argument("--num-workers", type=int, default=0,
                     help="sampler worker processes (0 = one thread)")
     args = ap.parse_args()
@@ -41,10 +42,9 @@ def main():
         model=ModelConfig(model="rgat", hidden=64),
         cache=CacheConfig(cache_mb=32),
         run=RunConfig(executor="raf_spmd", steps=args.steps, log_every=10),
+        pipeline=PipelineConfig(snapshot=args.snapshot_policy,
+                                num_workers=args.num_workers),
     )
-    if args.pipeline or args.num_workers:
-        cfg = cfg.updated(pipeline=dict(enabled=True,
-                                        num_workers=args.num_workers))
     sess = Heta(cfg)
 
     g = sess.build_graph()
@@ -63,10 +63,9 @@ def main():
     print(f"\nloss: first-{k}-avg {np.mean(losses[:k]):.4f} -> "
           f"last-{k}-avg {np.mean(losses[-k:]):.4f}")
     print(f"total {dt/60:.1f} min, median step {m['step_time_s']*1e3:.0f} ms")
-    if m["pipeline"]:
-        print(f"pipeline: {m['sampler_workers']} workers, "
-              f"{m['samples_per_s']:,.0f} samples/s, "
-              f"overlap {m['overlap_fraction']:.2f}")
+    print(f"pipeline: {m['sampler_workers']} sampler workers, "
+          f"{m['samples_per_s']:,.0f} samples/s, "
+          f"overlap {m['overlap_fraction']:.2f}")
     print(f"cache hit rates: { {t: round(r, 2) for t, r in m['hit_rates'].items()} }")
 
 

@@ -9,7 +9,8 @@ section dataclasses mirroring the pipeline stages:
   * :class:`CacheConfig`     — miss-penalty cache budget + profiling knobs
   * :class:`RunConfig`       — executor, mesh, steps, lr, seed
   * :class:`PipelineConfig`  — async host pipeline (prefetch depth, snapshot
-    staleness policy; see the ``repro.data`` package docstring)
+    staleness policy, sampler worker processes; see the ``repro.data``
+    package docstring)
   * :class:`KernelConfig`    — fused Pallas kernel layer (per-op toggles,
     interpret override; see ``repro.kernels`` and DESIGN.md §8)
   * :class:`ServeConfig`     — online inference tier (layer-wise inference
@@ -205,37 +206,30 @@ class RunConfig:
 
 @dataclasses.dataclass(frozen=True)
 class PipelineConfig:
-    """Async host pipeline.  ``Heta.fit`` always overlaps sampling and
-    staging with the device step on one producer thread, bit-exact (see
-    the ``repro.data`` package docstring); ``depth`` bounds its lookahead.
+    """Async host pipeline.  ``Heta.fit`` samples and stages the next
+    batches in the background while the device runs the current one (see
+    the ``repro.data`` package docstring); ``depth`` bounds the lookahead
+    (per sampler worker, in pool mode).
 
-    ``enabled`` no longer gates that overlap.  It opts into what changes
-    results or starts processes: with ``snapshot="stale"``, staging that
-    reads learnable tables runs ahead on the producer against
-    bounded-stale tables (``"fresh"`` keeps it on the consumer, as the
-    default does); ``num_workers`` N > 0 runs a pool of N sampler
-    *processes* over a shared-memory graph store
-    (``repro.data.worker_pool``, DESIGN.md §9) — bit-identical batches for
-    any worker count, ``depth`` prefetched items per worker.
+    ``num_workers`` picks the producer of every sampling loop of the
+    session (``fit``, ``evaluate``, ``profile_and_cache``): one thread at
+    0, else a pool of that many sampler *processes* over a shared-memory
+    graph store, batches flowing through the batch arena
+    (``repro.data.worker_pool``, DESIGN.md §9/§11).  Batches are
+    bit-identical for any worker count.
 
-    ``arena`` (pool mode only) moves batch payloads off the queues into a
-    fixed-slot shared-memory ring buffer (the batch arena, DESIGN.md §11):
-    workers write sampled + pre-staged arrays straight into seqlock-stamped
-    slots and the queues carry only slot descriptors — zero pickled
-    ndarrays on the hot path.  With the arena and ``snapshot="stale"``,
-    learnable-table staging runs *inside* workers against bounded-stale
-    table snapshots republished each step (staleness ≤ ring depth); with
-    ``snapshot="fresh"`` (or ``arena=False``) learnable staging stays on
-    the consumer and is bit-exact."""
+    ``snapshot`` says how fresh the tables that staging reads must be
+    while they train.  ``"fresh"`` stages such batches on the consumer,
+    against the tables of the step before: bit-exact with a loop of
+    ``Heta.step``.  ``"stale"`` lets the producer stage them ahead
+    against bounded-stale tables (at most ``depth + 1`` steps behind on
+    the thread; the arena's ring depth in pool mode).  Where staging reads
+    no table that trains (frozen rows, or the resident path) both are the
+    same."""
 
-    enabled: bool = False
     depth: int = 2  # prefetched batches kept ready ahead of the device step
-    snapshot: str = "stale"  # stale (max overlap) | fresh (bit-exact staging)
+    snapshot: str = "fresh"  # fresh (bit-exact staging) | stale (max overlap)
     num_workers: int = 0  # 0 = thread producer; N > 0 = sampler process pool
-    arena: bool = True  # pool mode: shm ring-buffer slots, descriptor queues
-    # opt-in CPU-affinity pin: sampler worker w sticks to core (w+1) % ncpu,
-    # biasing core 0 toward the consumer (best-effort; Linux only)
-    pin_workers: bool = False
 
     def __post_init__(self):
         if self.depth < 1:
@@ -247,13 +241,6 @@ class PipelineConfig:
         if self.num_workers < 0:
             raise ValueError(
                 f"num_workers must be >= 0, got {self.num_workers}"
-            )
-        if self.num_workers > 0 and not self.enabled:
-            raise ValueError(
-                "pipeline.num_workers > 0 requires pipeline.enabled "
-                "(pass --pipeline / pipeline=dict(enabled=True, ...)): "
-                "sampler worker processes are an opt-in; fit overlaps "
-                "sampling and staging on one thread without it"
             )
 
 
@@ -645,12 +632,9 @@ _FLAT_MAP: Dict[str, Tuple[str, str, Callable, Callable]] = {
     "lr": ("run", "lr", float, float),
     "seed": ("run", "seed", int, int),
     "log_every": ("run", "log_every", int, int),
-    "pipeline": ("pipeline", "enabled", bool, bool),
     "prefetch_depth": ("pipeline", "depth", int, int),
     "snapshot_policy": ("pipeline", "snapshot", str, str),
     "num_workers": ("pipeline", "num_workers", int, int),
-    "batch_arena": ("pipeline", "arena", bool, bool),
-    "pin_workers": ("pipeline", "pin_workers", bool, bool),
     "kernels": ("kernels", "enabled", bool, bool),
     "kernel_stacked_agg": ("kernels", "stacked_agg", bool, bool),
     "kernel_relation_agg": ("kernels", "relation_agg", bool, bool),
@@ -708,20 +692,11 @@ _CLI_OVERRIDES: Dict[Tuple[str, str], Tuple[str, Optional[Callable], str]] = {
         "--readmit-every", int,
         "online cache re-admission period in steps (0 = one-shot)"),
     ("run", "mesh_shape"): ("--mesh", _parse_mesh, "DATAxMODEL mesh, e.g. 2x4"),
-    ("pipeline", "enabled"): (
-        "--pipeline", None,
-        "opt into stale learnable staging and sampler worker pools (fit "
-        "overlaps sampling and staging with the device step either way)"),
     ("pipeline", "depth"): ("--prefetch-depth", int, "pipeline prefetch depth"),
     ("pipeline", "snapshot"): (
         "--snapshot-policy", str, f"learnable-table snapshot policy {SNAPSHOT_POLICIES}"),
     ("pipeline", "num_workers"): (
         "--num-workers", int, "sampler worker processes (0 = single thread)"),
-    ("pipeline", "arena"): (
-        "--batch-arena", None, "shm ring-buffer batch arena (pool mode)"),
-    ("pipeline", "pin_workers"): (
-        "--pin-workers", None,
-        "pin sampler workers to distinct CPU cores (Linux, best-effort)"),
     ("kernels", "enabled"): ("--kernels", None, "fused Pallas kernel layer on/off"),
     ("kernels", "stacked_agg"): (
         "--kernel-stacked-agg", None, "stacked relation-aggregation kernel"),

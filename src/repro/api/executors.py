@@ -39,21 +39,18 @@ exposes graph / spec / assignment / engine / hgnn_cfg):
       spans split it (``repro.obs``): ``heta.device.wait`` (the host
       blocked on the device), ``heta.update.pull`` and ``heta.update.adam``
       (the sparse update of learnable rows).
-  ``step(sess, plan, state, batch) -> (state, loss, step_time_s)``
-      the serial composition ``step_staged(..., stage(...))`` — kept for
-      callers that don't pipeline.
   ``stage_reads_tables(sess, plan) -> bool``
-      whether ``stage`` reads the learnable feature tables (drives the
-      pipeline's snapshot staleness policy; see ``repro.data``).
+      whether ``stage`` reads feature tables that train.  A fact, not a
+      policy: the session alone decides from it and
+      ``pipeline.snapshot`` whether a producer may stage ahead (see
+      ``repro.data``).
   ``worker_stage_recipe(sess, plan) -> picklable | None``
       a picklable recipe with which a *sampler worker process* can perform
-      the host part of ``stage`` against tables exported into the
-      shared-memory graph store or batch arena
-      (``repro.data.staging.stack_batch_host``), or None when staging must
-      stay consumer-side (default; also when staging reads learnable tables
-      that train, *unless* the batch arena's seqlock'd table region carries
-      republished bounded-stale snapshots under the ``"stale"`` policy —
-      DESIGN.md §9/§11).  Drives the worker pool's staging placement.
+      the host part of ``stage`` against tables copied into the batch
+      arena (``repro.data.staging.stack_batch_host``), or None when
+      staging has no host part a worker could run (default; also the
+      resident path).  The session hands it to the workers only where
+      they may stage ahead (DESIGN.md §9/§11).
   ``stage_from_host(sess, plan, batch, host_arrays) -> arrays``
       consumer-side completion of worker staging: device placement of the
       host arrays a worker produced under the recipe; with
@@ -128,19 +125,14 @@ class Executor:
     def step_staged(self, sess, plan, state, batch, arrays):
         raise NotImplementedError
 
-    def step(self, sess, plan, state, batch):
-        """Serial stage + device step (the pre-pipeline surface)."""
-        return self.step_staged(sess, plan, state, batch,
-                                self.stage(sess, plan, batch))
-
     def stage_reads_tables(self, sess, plan) -> bool:
-        """True when ``stage`` snapshots the learnable feature tables, i.e.
-        background staging can observe stale rows (see ``repro.data``)."""
+        """True when ``stage`` reads feature tables that train, i.e.
+        staging ahead of the step can observe stale rows."""
         return False
 
     def worker_stage_recipe(self, sess, plan):
         """Picklable host-staging recipe for sampler worker processes, or
-        None when staging must stay consumer-side (the default)."""
+        None when staging has no host part they could run (the default)."""
         return None
 
     def stage_from_host(self, sess, plan, batch, host_arrays):
@@ -401,11 +393,11 @@ class RafSpmdExecutor(Executor):
           taken.
         * host — otherwise (a partial cache, no cache, a multi-device
           mesh, the CPU backend): snapshot the tables and gather the
-          feature rows on the host (``raf_spmd.stack_batch``).  When the pipeline pre-stages
-          in a producer thread and learnable tables are training, the
-          snapshot may lag the device step by up to ``pipeline.depth + 1``
-          steps — the documented ``"stale"`` policy
-          (``stage_reads_tables`` tells the stream when this applies)."""
+          feature rows on the host (``raf_spmd.stack_batch``).  Where a
+          producer stages ahead while learnable tables train (the session's
+          ``"stale"`` snapshot policy), the snapshot may lag the device
+          step by up to ``pipeline.depth + 1`` steps
+          (``stage_reads_tables`` tells the session when this applies)."""
         from repro.core import raf_spmd
         from repro.data.staging import stack_batch_nids
 
@@ -449,24 +441,15 @@ class RafSpmdExecutor(Executor):
     def worker_stage_recipe(self, sess, plan):
         """On the resident path (see :meth:`stage`) None: staging only
         writes node ids and stays with the consumer, so workers only
-        sample.  Otherwise, with frozen tables the whole host side of
-        :meth:`stage` — the padded feature gathers of ``stack_batch`` — can
-        run inside sampler workers against tables exported into the shm
-        store or batch arena; the consumer only device-puts.
-
-        While learnable tables train, workers normally cannot see the
-        trainer's row updates, so staging stays consumer-side (None) —
-        *except* under the batch arena with the ``"stale"`` snapshot
-        policy: the session republishes learnable tables into the arena's
-        seqlock'd table region after every step, so workers stage against
-        bounded-stale snapshots (staleness ≤ ring depth, DESIGN.md §11 —
-        the same contract the thread pipeline's ``"stale"`` policy makes)."""
+        sample.  Otherwise the host side of :meth:`stage` — the padded
+        feature gathers of ``stack_batch`` — can run inside sampler
+        workers against tables copied into the batch arena; the consumer
+        only device-puts.  Where those tables train
+        (:meth:`stage_reads_tables`), the session passes the recipe on only
+        under ``snapshot="stale"``, republishing the tables into the
+        arena after every step (DESIGN.md §11)."""
         if self._resident(sess, plan) is not None:
             return None
-        if plan.learn_feats:
-            p = sess.config.pipeline
-            if not (p.arena and p.num_workers > 0 and p.snapshot == "stale"):
-                return None
         from repro.core import raf_spmd
 
         return raf_spmd.stack_recipe(plan.plan)
@@ -517,7 +500,7 @@ class RafSpmdExecutor(Executor):
 class ServeExecutor(Executor):
     """Score batches against the materialized embedding store (DESIGN.md §10).
 
-    Not a training executor: ``step``/``step_staged`` raise.  ``build_plan``
+    Not a training executor: ``step_staged`` raises.  ``build_plan``
     requires :meth:`Heta.infer_all` to have materialized the store;
     ``loss_and_metrics`` answers through the micro-batching
     :class:`~repro.serve.server.EmbeddingServer` (same NLL as the training

@@ -22,6 +22,7 @@ materialized store (``close_serving()`` releases both).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -128,6 +129,7 @@ class Heta:
         self._fit_steps = 0
         self._steps_done = 0
         self._queue_bytes: List[int] = []  # pooled fits: per-item queue size
+        self._workers_ran = 0  # sampler processes of the last loop (0: a thread)
         # persistent sampler pool:
         # [store, arena, pool, next_global_step, workers]
         # (spawn + shm export amortize across fit() calls; see _acquire_pool)
@@ -308,29 +310,23 @@ class Heta:
     def profile_and_cache(self) -> CacheReport:
         """Pre-sample hotness, profile miss penalties, allocate the cache.
 
-        With ``pipeline.num_workers > 0`` the §6 pre-sampling epoch — the
-        same ``batch_at`` sweep the training pool runs — fans out over a
-        worker pool (bit-identical counts; visit counting is an
+        The §6 pre-sampling epochs — the same ``batch_at`` sweep training
+        runs — are sampled by the session's producer
+        (:meth:`_sampler_workers`): in line, or over a pool of sampler
+        processes (bit-identical counts; visit counting is an
         order-independent sum)."""
         from repro.embed import EmbedEngine, profile_miss_penalties
-        from repro.embed.profiler import presample_hotness, presample_hotness_pooled
+        from repro.embed.profiler import presample_hotness_pooled
 
         self._require("spec", "partition", "profile_and_cache")
         cfg = self.config
-        if cfg.pipeline.enabled and cfg.pipeline.num_workers > 0:
-            hotness = presample_hotness_pooled(
-                self.graph, self.spec, cfg.data.batch_size,
-                num_workers=cfg.pipeline.num_workers,
-                epochs=cfg.cache.presample_epochs,
-                max_batches=cfg.cache.presample_max_batches,
-                seed=cfg.run.seed, depth=cfg.pipeline.depth,
-            )
-        else:
-            hotness = presample_hotness(
-                self.graph, self.spec, cfg.data.batch_size,
-                epochs=cfg.cache.presample_epochs,
-                max_batches=cfg.cache.presample_max_batches, seed=cfg.run.seed,
-            )
+        hotness = presample_hotness_pooled(
+            self.graph, self.spec, cfg.data.batch_size,
+            num_workers=self._sampler_workers(),
+            epochs=cfg.cache.presample_epochs,
+            max_batches=cfg.cache.presample_max_batches,
+            seed=cfg.run.seed, depth=cfg.pipeline.depth,
+        )
         penalties = profile_miss_penalties(
             self.graph, learnable_dim=cfg.model.learnable_dim,
             measured=cfg.cache.measured_penalties,
@@ -377,27 +373,16 @@ class Heta:
         executor's device step (span ``heta.device``: compute + sparse
         update, host staging excluded) — matching the historical
         ``train_hgnn`` accounting — and its host time, in ``host_times``,
-        is the time ``heta.step`` spent before it (sampling + staging)."""
+        is the time ``heta.step`` spent before it (sampling + staging).
+        The serial surface: sampling, staging and the device step in line,
+        where :meth:`fit` overlaps them."""
         self._require("state", "compile", "step")
         with obs.span(obs.STEP, step=self._steps_done,
                       session=self.obs_session) as root:
             if batch is None:
                 batch = self._next_batch()
-            if not self._staged_protocol():
-                # legacy executor: only the composed step() is overridden
-                host_s = root.child_s
-                self.state, loss, dt = self.executor.step(
-                    self, self.plan, self.state, batch)
-                self._record(loss, host_s, dt)
-                return loss
             arrays = self.executor.stage(self, self.plan, batch)
             return self._consume(batch, arrays, root)
-
-    def _staged_protocol(self) -> bool:
-        """Whether the executor implements the staged-step seam (custom
-        executors registered before the pipeline may only override the
-        composed ``step``; they keep working on the serial path)."""
-        return type(self.executor).stage is not _executors.Executor.stage
 
     def _consume(self, batch, arrays, root, host_s: Optional[float] = None) -> float:
         """Run the device step on pre-staged arrays inside the open step
@@ -434,27 +419,20 @@ class Heta:
         """Train for ``steps`` (default ``RunConfig.steps``); returns the
         result dict (same keys the legacy ``train_hgnn`` returned).
 
-        Wherever the executor implements the staged-step seam, the loop is
-        driven by a :class:`repro.data.SampleStream`: sampling and staging
-        of batch *i+1* run on a producer thread while batch *i* trains.
-        Staging runs there only where it reads no table that trains
-        (``stage_reads_tables`` False: frozen rows, or the resident
-        path); otherwise the producer only samples and the consumer
-        stages against the current tables.  So batches, staged arrays and
+        A :class:`repro.data.SampleStream` drives the loop: batch *i+1* is
+        sampled and staged by the session's producer
+        (:meth:`_sampler_workers`: one thread, or ``pipeline.num_workers``
+        sampler processes over a shared-memory graph store and the batch
+        arena, DESIGN.md §9/§11) while batch *i* trains.  The producer
+        stages too where :meth:`_stages_ahead` allows it; otherwise it only
+        samples and the consumer stages against the current tables.  So
+        under ``snapshot="fresh"`` (the default) batches, staged arrays and
         losses are bit-identical to a loop of :meth:`step`; only when the
-        work happens changes.  Executors without the seam keep that loop.
-
-        ``pipeline.enabled`` opts into what changes results or starts
-        processes: ``snapshot="stale"`` stages learnable tables on the
-        producer too, bounded-stale (staleness ≤ depth + 1), and
-        ``pipeline.num_workers`` sampler processes over a shared-memory
-        graph store (DESIGN.md §9) take the thread's place, batches
-        flowing through the zero-pickle batch arena (DESIGN.md §11)
-        unless ``pipeline.arena`` is off.  The pool + store + arena
+        work happens changes.  ``snapshot="stale"`` stages learnable
+        tables ahead too, bounded-stale.  A pool, its store and arena
         persist across consecutive ``fit()`` calls (spawn cost amortizes;
         see :meth:`close_pipeline`) and are torn down on error.  Batches
-        are bit-identical for any worker count (per-batch RNG); losses
-        are too except learnable training under ``snapshot="stale"``."""
+        are bit-identical for any worker count (per-batch RNG)."""
         self._require("state", "compile", "fit")
         steps = self.config.run.steps if steps is None else steps
         if steps and self.config.scale.enabled:
@@ -474,52 +452,35 @@ class Heta:
 
         t_wall = time.perf_counter()
         n0 = len(self.step_times)
-        pcfg = self.config.pipeline
-        staged = self._staged_protocol()
-        if steps and pcfg.enabled and not staged:
-            raise HetaStageError(
-                f"executor {self.executor.name!r} does not implement the "
-                "staged-step protocol (stage/step_staged) required by "
-                "pipeline.enabled; disable the pipeline or implement it"
-            )
-        if steps and staged:
+        if steps:
             from repro.data.sample_stream import SampleStream
 
             start = self._steps_done
-            stale = pcfg.enabled and pcfg.snapshot == "stale"
-            defer = (not stale
-                     and self.executor.stage_reads_tables(self, self.plan))
-            stream_kw = {}
-            arena = None
-            if pcfg.num_workers > 0:
+            pool = arena = None
+            if self._sampler_workers():
                 pool, arena = self._acquire_pool(start)
-                stream_kw = dict(
-                    num_workers=pcfg.num_workers,
-                    pool=pool,
-                    arena=arena,
-                    spec=self.spec,
-                    finish_stage=lambda b, host: self.executor.stage_from_host(
-                        self, self.plan, b, host),
-                )
             # learnable-"stale" worker staging: after every consumed step,
             # republish the updated learnable tables into the arena's
             # seqlock'd region so workers stage batch i+k against tables at
             # most the ring depth behind the trainer (DESIGN.md §11)
-            republish = (arena is not None and arena.handle.tables_mutable)
+            republish = arena is not None and arena.handle.tables_mutable
             try:
                 with SampleStream(
                     lambda i: self._batch_for_step(start + i),
                     lambda b: self.executor.stage(self, self.plan, b),
-                    num_steps=steps, depth=pcfg.depth, defer_stage=defer,
+                    num_steps=steps, depth=self.config.pipeline.depth,
+                    defer_stage=not self._stages_ahead(),
+                    pool=pool, arena=arena, spec=self.spec,
+                    finish_stage=lambda b, host: self.executor.stage_from_host(
+                        self, self.plan, b, host),
                     first_step=start, session=self.obs_session,
-                    **stream_kw,
                 ) as stream:
                     for batch, arrays, host_s in stream:
                         with obs.span(obs.STEP, step=self._steps_done,
                                       session=self.obs_session) as root:
                             loss = self._consume(batch, arrays, root, host_s)
                         logged(loss)
-                        if self._pool_cache is not None and stream_kw:
+                        if pool is not None:
                             self._pool_cache[3] += 1  # pool stays in sync
                         if republish:
                             arena.publish_tables({
@@ -533,9 +494,6 @@ class Heta:
                 # next fit starts a fresh, aligned pool
                 self.close_pipeline()
                 raise
-        else:
-            for _ in range(steps):
-                logged(self.step())
         self._fit_wall_s += time.perf_counter() - t_wall
         self._fit_steps += len(self.step_times) - n0
         self._fit_serial_s += sum(self.host_times[n0:]) + sum(self.step_times[n0:])
@@ -544,10 +502,10 @@ class Heta:
     def evaluate(self, num_batches: int = 1, use_full_graph: bool = False) -> Dict:
         """Mean held-out-batch loss via the executor's eval path (no update).
 
-        Batches are prefetched in the background — by a thread, or, with
-        ``pipeline.enabled``, by ``pipeline.num_workers`` sampler processes
-        over a shared-memory graph store (eval staging never trains tables,
-        so any producer is always bit-exact).
+        Batches are prefetched in the background by the session's producer
+        (:meth:`_sampler_workers`): a thread, or ``pipeline.num_workers``
+        sampler processes over a shared-memory graph store (eval trains no
+        table, so any producer is bit-exact).
 
         ``use_full_graph=True`` scores the *same* held-out batches against
         the embeddings :meth:`infer_all` materialized instead of running the
@@ -586,45 +544,31 @@ class Heta:
             losses.append(loss)
             return m
 
-        pcfg = self.config.pipeline
-        if pcfg.enabled and pcfg.num_workers > 0:
-            from repro.data.sample_stream import SampleStream
-            from repro.data.worker_pool import EpochSchedule, WorkerPool
+        from repro.data.sample_stream import SampleStream
+        from repro.data.worker_pool import EpochSchedule, WorkerPool
 
-            store, arena, task = self._pool_task(
-                EpochSchedule(eval_seed, sampler.steps_per_epoch()),
-                eval_seed,
-            )
-            try:
-                with WorkerPool(task, num_workers=pcfg.num_workers,
-                                depth=pcfg.depth, num_items=n,
-                                name="eval-pool",
-                                **self._supervision_kw(arena)) as pool:
-                    # the stream resolves arena SlotRefs (and passes legacy
-                    # tuples through); eval consumes raw batches, so the
-                    # consumer-side completion is a no-op
-                    with SampleStream(
-                        num_steps=n, num_workers=pcfg.num_workers,
-                        pool=pool, arena=arena, spec=self.spec,
-                        finish_stage=lambda b, host: None,
-                    ) as stream:
-                        for b, _, _ in stream:
-                            metrics = consume(b)
-            finally:
-                try:
-                    store.unlink()
-                finally:
-                    if arena is not None:
-                        arena.unlink()
-        else:
-            from repro.data.prefetch import Prefetcher
-
-            with Prefetcher(
+        depth = self.config.pipeline.depth
+        with contextlib.ExitStack() as owned:
+            pool = arena = None
+            if self._sampler_workers():
+                store, arena, task = self._pool_task(
+                    EpochSchedule(eval_seed, sampler.steps_per_epoch()),
+                    eval_seed,
+                )
+                owned.callback(arena.unlink)
+                owned.callback(store.unlink)
+                pool = owned.enter_context(WorkerPool(
+                    task, num_workers=self.config.pipeline.num_workers,
+                    depth=depth, num_items=n, name="eval-pool",
+                    **self._supervision_kw(arena)))
+            # eval consumes raw batches: no staging on either side
+            with SampleStream(
                 lambda i: sampler.batch_at(i, epoch_seed=eval_seed),
-                depth=pcfg.depth, num_items=n,
-                name="eval-stream",
-            ) as pf:
-                for b in pf:
+                lambda b: None, num_steps=n, depth=depth,
+                pool=pool, arena=arena, spec=self.spec,
+                finish_stage=lambda b, host: None,
+            ) as stream:
+                for b, _, _ in stream:
                     metrics = consume(b)
         return {"loss": float(np.mean(losses)), "num_batches": len(losses),
                 **{k: v for k, v in metrics.items() if k != "loss"}}
@@ -898,13 +842,11 @@ class Heta:
             "step_time_s": float(np.median(timed)),
             "host_time_s": float(np.median(self.host_times or [0.0])),
             "setup_s": setup,
-            "pipeline": bool(self.config.pipeline.enabled),
-            "sampler_workers": (self.config.pipeline.num_workers
-                                if self.config.pipeline.enabled else 0),
+            "sampler_workers": self._workers_ran,
             "samples_per_s": float(samples_per_s),
             "overlap_fraction": float(overlap),
-            # mean pickled bytes per worker→consumer queue item — ~1e2 with
-            # the batch arena (SlotRef descriptors), ~1e6 legacy (ndarrays)
+            # mean pickled bytes per worker→consumer queue item: ~1e2, the
+            # batch arena's SlotRef descriptors
             "queue_bytes_per_step": (
                 float(np.mean(self._queue_bytes)) if self._queue_bytes
                 else 0.0),
@@ -947,6 +889,23 @@ class Heta:
             epoch_seed, i = self._schedule().seed_and_index(s)
             return self.sampler.batch_at(i, epoch_seed=epoch_seed)
 
+    def _sampler_workers(self) -> int:
+        """The producer of the session's sampling loops — :meth:`fit`,
+        :meth:`evaluate` and :meth:`profile_and_cache` all ask here: a pool
+        of ``pipeline.num_workers`` sampler processes over a shared-memory
+        graph store where that is > 0, else 0, one thread.  What it
+        answers is what ``results()["sampler_workers"]`` reports."""
+        self._workers_ran = self.config.pipeline.num_workers
+        return self._workers_ran
+
+    def _stages_ahead(self) -> bool:
+        """Whether a producer, thread or sampler worker, may stage a batch
+        ahead of its step: where staging reads no table that trains, or
+        under ``pipeline.snapshot="stale"`` (bounded-stale tables).  The
+        one place the session decides staleness."""
+        return (self.config.pipeline.snapshot == "stale"
+                or not self.executor.stage_reads_tables(self, self.plan))
+
     def _acquire_pool(self, start_step: int):
         """The persistent sampler pool positioned at ``start_step``.
 
@@ -967,10 +926,11 @@ class Heta:
                     and not pool._closed):
                 return pool, arena
             self.close_pipeline()
+        recipe = (self.executor.worker_stage_recipe(self, self.plan)
+                  if self._stages_ahead() else None)
         store, arena, task = self._pool_task(
             self._schedule(start_step), self.config.run.seed + 1,
-            recipe=self.executor.worker_stage_recipe(self, self.plan),
-            faults=self.fault_plan,
+            recipe=recipe, faults=self.fault_plan,
         )
         pool = WorkerPool(task, num_workers=pcfg.num_workers,
                           depth=pcfg.depth, num_items=None,
@@ -1018,8 +978,7 @@ class Heta:
             try:
                 store.unlink()
             finally:
-                if arena is not None:
-                    arena.unlink()
+                arena.unlink()
 
     def _supervision_kw(self, arena) -> Dict:
         """WorkerPool supervision kwargs from ``FaultConfig`` (DESIGN.md
@@ -1027,11 +986,9 @@ class Heta:
         dead worker's arena sub-ring so stale ``SlotRef``\\ s fail loudly
         before the replacement replays the stripe."""
         fcfg = self.config.faults
-        kw = dict(max_restarts=fcfg.max_worker_restarts,
-                  restart_backoff_s=fcfg.worker_backoff_s)
-        if arena is not None:
-            kw["on_worker_death"] = arena.invalidate_worker_slots
-        return kw
+        return dict(max_restarts=fcfg.max_worker_restarts,
+                    restart_backoff_s=fcfg.worker_backoff_s,
+                    on_worker_death=arena.invalidate_worker_slots)
 
     def _pool_task(self, schedule, sampler_seed: int, recipe=None,
                    faults=None):
@@ -1043,12 +1000,11 @@ class Heta:
         read travel with the batch pipeline; with ``recipe=None`` workers
         sample only and staging stays consumer-side.
 
-        With ``pipeline.arena`` (default) batches flow through a
-        fixed-slot shm ring buffer (DESIGN.md §11): the tables live in the
-        arena segment — seqlock-republishable when learnable tables train
-        under the ``"stale"`` policy — and the queues carry only
-        :class:`SlotRef` descriptors.  ``arena=False`` keeps the legacy
-        pickle path (tables exported read-only into the graph store)."""
+        Batches flow through a fixed-slot shm ring buffer, the batch arena
+        (DESIGN.md §11): the tables live in the arena segment —
+        seqlock-republishable when learnable tables train under the
+        ``"stale"`` policy — and the queues carry only :class:`SlotRef`
+        descriptors."""
         from repro.data.staging import arena_fields
         from repro.data.worker_pool import SampleStageTask
         from repro.graph.shm import create_arena, share_graph
@@ -1058,20 +1014,15 @@ class Heta:
         if recipe is not None:
             snapshot = self.engine.tables_snapshot()
             tables = {t: snapshot[t] for t in recipe.table_types()}
-        arena = None
-        if pcfg.arena:
-            store = share_graph(self.graph, include_features=False)
-            probe = self._batch_for_step(0)  # padded shapes: any step works
-            mutable = (recipe is not None
-                       and bool(getattr(self.plan, "learn_feats", False)))
-            arena = create_arena(
-                arena_fields(probe, recipe=recipe, tables=tables),
-                num_workers=pcfg.num_workers, depth=pcfg.depth,
-                tables=tables, tables_mutable=mutable,
-            )
-        else:
-            store = share_graph(self.graph, include_features=False,
-                                tables=tables)
+        store = share_graph(self.graph, include_features=False)
+        probe = self._batch_for_step(0)  # padded shapes: any step works
+        mutable = (recipe is not None
+                   and bool(getattr(self.plan, "learn_feats", False)))
+        arena = create_arena(
+            arena_fields(probe, recipe=recipe, tables=tables),
+            num_workers=pcfg.num_workers, depth=pcfg.depth,
+            tables=tables, tables_mutable=mutable,
+        )
         task = SampleStageTask(
             handle=store.handle,
             spec=self.spec,
@@ -1079,10 +1030,9 @@ class Heta:
             sampler_seed=sampler_seed,
             schedule=schedule,
             recipe=recipe,
-            arena=arena.handle if arena is not None else None,
+            arena=arena.handle,
             faults=faults,
             write_timeout_s=self.config.faults.arena_write_timeout_s,
-            pin_cpus=pcfg.pin_workers,
         )
         return store, arena, task
 

@@ -431,8 +431,8 @@ class EpochSchedule:
 class SlotRef:
     """Queue descriptor of one arena-staged item (DESIGN.md §11).
 
-    This ~200-byte record is the *entire* queue payload when the batch arena
-    is active — the batch and staged arrays live in the slot it names.
+    This ~200-byte record is the *entire* queue payload — the batch and
+    staged arrays live in the batch-arena slot it names.
     ``table_version`` stamps which staging-table publish the worker staged
     against (the staleness bound check); ``staged`` says whether ``h/``
     arrays are present."""
@@ -450,15 +450,13 @@ class SampleStageTask:
     """The pool task of the HGNN host pipeline: sample (and optionally
     stage) the batch at one global step.
 
-    ``handle`` names the shared-memory graph store; ``recipe`` (a
-    :class:`~repro.data.staging.StackRecipe`, or None) moves the host
-    staging into the worker — its feature tables must have been exported
-    into the store (``share_graph(..., tables=...)``) or, with an arena,
-    into the arena's table region.  Without ``arena`` each item returns
-    ``(batch, host_arrays | None, host_seconds)``, mirroring the thread
-    stream's payload; with an :class:`~repro.graph.shm.ArenaHandle` the
-    arrays are written straight into the item's ring slot and only a
+    ``handle`` names the shared-memory graph store and ``arena`` (an
+    :class:`~repro.graph.shm.ArenaHandle`) the batch arena: each item's
+    arrays are written straight into its ring slot and only a
     :class:`SlotRef` crosses the queue (zero pickled ndarrays).
+    ``recipe`` (a :class:`~repro.data.staging.StackRecipe`, or None) moves
+    the host staging into the worker, against the feature tables copied
+    into the arena's table region.
 
     ``faults`` (a :class:`~repro.data.faults.FaultPlan`, or None) arms
     deterministic chaos drills: a scheduled ``kill_worker`` exits the
@@ -476,11 +474,10 @@ class SampleStageTask:
     batch_size: int
     sampler_seed: int
     schedule: EpochSchedule
+    arena: object  # repro.graph.shm.ArenaHandle
     recipe: object = None
-    arena: object = None  # repro.graph.shm.ArenaHandle
     faults: object = None  # repro.data.faults.FaultPlan
     write_timeout_s: float = 60.0
-    pin_cpus: bool = False  # opt-in: pin worker w to core (w+1) % ncpu
 
     def bind_stop(self, stop) -> None:
         """Called by the pool runner so the arena backpressure wait can
@@ -498,29 +495,16 @@ class SampleStageTask:
         from repro.graph.sampler import NeighborSampler
         from repro.graph.shm import attach_arena
 
-        if self.pin_cpus:
-            # opt-in affinity pin (pipeline.pin_workers): worker w sticks to
-            # core (w+1) % ncpu, biasing core 0 toward the consumer — spares
-            # the samplers' cache/NUMA locality from scheduler migration.
-            # Best-effort: unsupported platforms (macOS) just skip it.
-            try:
-                ncpu = os.cpu_count() or 1
-                os.sched_setaffinity(
-                    0, {(getattr(self, "_wid", 0) + 1) % ncpu})
-            except (AttributeError, OSError):
-                pass
-
         self._attached = attach_any(self.handle)
         self._sampler = NeighborSampler(
             self._attached.graph, self.spec, self.batch_size,
             seed=self.sampler_seed,
         )
-        self._tables = self._attached.tables
-        self._arena = attach_arena(self.arena) if self.arena is not None else None
-        if self._arena is not None and self._arena.handle.tables:
-            if not self._arena.handle.tables_mutable:
-                # frozen tables: zero-copy views, read once
-                self._tables, _ = self._arena.read_tables()
+        self._arena = attach_arena(self.arena)
+        self._tables = None
+        if self._arena.handle.tables and not self._arena.handle.tables_mutable:
+            # frozen tables: zero-copy views, read once
+            self._tables, _ = self._arena.read_tables()
 
     def __call__(self, i: int):
         from repro.data.staging import (HOST_PREFIX, pack_batch_into,
@@ -541,13 +525,6 @@ class SampleStageTask:
         epoch_seed, idx = self.schedule.seed_and_index(i)
         batch = self._sampler.batch_at(
             idx, epoch_seed=epoch_seed, shuffle=self.schedule.shuffle)
-        if self._arena is None:
-            host = (
-                stack_batch_host(self.recipe, batch, self._tables)
-                if self.recipe is not None else None
-            )
-            return batch, host, time.perf_counter() - t0
-
         a = self._arena
         slot, use = a.handle.slot_for(i)
         # backpressure: the sub-ring is full until the consumer releases

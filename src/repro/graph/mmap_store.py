@@ -10,7 +10,7 @@ shm contract into that shape, keeping its discipline intact:
 * **Same attach contract.**  A picklable :class:`MmapGraphHandle` (same
   array-key scheme as :class:`~repro.graph.shm.GraphHandle` —
   ``rel/<i>/indptr|indices``, ``labels``, ``train_nodes``,
-  ``feat/<ntype>``, ``table/<name>``); :func:`attach_mmap` rebuilds a
+  ``feat/<ntype>``); :func:`attach_mmap` rebuilds a
   read-only :class:`~repro.graph.hetgraph.HetGraph` of zero-copy views,
   exactly like :func:`repro.graph.shm.attach`.  :func:`attach_any`
   dispatches on handle type so pool/trainer code accepts either store.
@@ -98,11 +98,6 @@ class MmapGraphHandle:
     num_classes: int
     graph_name: str
     arrays: Tuple[Tuple[str, ArrayRef], ...]
-
-    @property
-    def table_names(self) -> Tuple[str, ...]:
-        return tuple(k[len("table/"):] for k, _ in self.arrays
-                     if k.startswith("table/"))
 
 
 def _handle_to_json(handle: MmapGraphHandle) -> str:
@@ -258,8 +253,7 @@ class AttachedMmapGraph:
     """A trainer's zero-copy, read-only view of a committed mmap store.
 
     ``graph`` is a fully functional read-only HetGraph whose arrays page
-    in lazily from ``data.bin``; ``tables`` maps exported staging-table
-    names to read-only views.  Same surface as
+    in lazily from ``data.bin``.  Same surface as
     :class:`~repro.graph.shm.AttachedHetGraph`."""
 
     def __init__(self, handle: MmapGraphHandle):
@@ -288,16 +282,11 @@ class AttachedMmapGraph:
             train_nodes=_view(self._mm, refs["train_nodes"]),
             name=handle.graph_name,
         )
-        self.tables: Dict[str, np.ndarray] = {
-            k[len("table/"):]: _view(self._mm, r)
-            for k, r in refs.items() if k.startswith("table/")
-        }
 
     def close(self) -> None:
         if not self._closed:
             self._closed = True
             self.graph = None
-            self.tables = {}
             self._mm.close()
 
     def __enter__(self) -> "AttachedMmapGraph":
@@ -378,7 +367,6 @@ def create_store_writer(
 def mmap_share_graph(
     graph: HetGraph,
     include_features: bool = True,
-    tables: Optional[Dict[str, np.ndarray]] = None,
     root: Optional[str] = None,
 ) -> MmapHetGraph:
     """Export an in-RAM graph into an mmap store (disk-backed twin of
@@ -395,8 +383,6 @@ def mmap_share_graph(
     if include_features:
         for t, f in graph.features.items():
             arrays[f"feat/{t}"] = np.ascontiguousarray(f)
-    for tname, tab in (tables or {}).items():
-        arrays[f"table/{tname}"] = np.ascontiguousarray(tab)
 
     spec = {k: (tuple(a.shape), a.dtype.str) for k, a in arrays.items()}
     writer = create_store_writer(

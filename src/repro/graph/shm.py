@@ -3,7 +3,7 @@
 The multi-worker sampling pool (``repro.data.worker_pool``, DESIGN.md §9)
 feeds N sampler processes from **one** copy of the graph: topology
 (``indptr``/``indices`` per mono-relation CSR), labels, the train-node set,
-and optionally frozen feature tables are exported once into a single
+and optionally the graph's feature arrays are exported once into a single
 :mod:`multiprocessing.shared_memory` segment, and each worker maps them
 zero-copy — no pickling of the graph per task, no per-worker replicas.
 
@@ -18,8 +18,8 @@ Three pieces:
 
 :func:`attach`
     Worker side.  Maps the segment named by a :class:`GraphHandle` and
-    rebuilds a read-only :class:`HetGraph` (plus any exported staging tables)
-    whose numpy arrays are views into the shared buffer.  Attaching never
+    rebuilds a read-only :class:`HetGraph` whose numpy arrays are views
+    into the shared buffer.  Attaching never
     registers with the ``resource_tracker`` (workers must not unlink the
     owner's segment at exit, nor warn about "leaked" memory they don't own).
 
@@ -88,8 +88,7 @@ class GraphHandle:
     Workers receive this (a few hundred bytes) instead of the graph itself;
     :func:`attach` turns it back into a :class:`HetGraph` of zero-copy views.
     Array keys: ``rel/<i>/indptr|indices`` (relation order matches
-    :attr:`relations`), ``labels``, ``train_nodes``, ``feat/<ntype>`` and
-    ``table/<name>`` for exported staging tables.
+    :attr:`relations`), ``labels``, ``train_nodes`` and ``feat/<ntype>``.
     """
 
     segment: str
@@ -100,11 +99,6 @@ class GraphHandle:
     num_classes: int
     graph_name: str
     arrays: Tuple[Tuple[str, ArrayRef], ...]
-
-    @property
-    def table_names(self) -> Tuple[str, ...]:
-        return tuple(k[len("table/"):] for k, _ in self.arrays
-                     if k.startswith("table/"))
 
 
 def _layout(arrays: Dict[str, np.ndarray]) -> Tuple[Dict[str, ArrayRef], int]:
@@ -194,9 +188,8 @@ class SharedHetGraph:
 class AttachedHetGraph:
     """A worker's zero-copy view of a shared graph segment.
 
-    ``graph`` is a fully functional read-only :class:`HetGraph`; ``tables``
-    maps exported staging-table names to read-only arrays.  Keep this object
-    alive as long as any view is in use; ``close()`` unmaps."""
+    ``graph`` is a fully functional read-only :class:`HetGraph`.  Keep
+    this object alive as long as any view is in use; ``close()`` unmaps."""
 
     def __init__(self, handle: GraphHandle):
         self.handle = handle
@@ -223,16 +216,11 @@ class AttachedHetGraph:
             train_nodes=_view(self._shm.buf, refs["train_nodes"]),
             name=handle.graph_name,
         )
-        self.tables: Dict[str, np.ndarray] = {
-            k[len("table/"):]: _view(self._shm.buf, r)
-            for k, r in refs.items() if k.startswith("table/")
-        }
 
     def close(self) -> None:
         if not self._closed:
             self._closed = True
             self.graph = None
-            self.tables = {}
             self._shm.close()
 
     def __enter__(self) -> "AttachedHetGraph":
@@ -251,16 +239,15 @@ class AttachedHetGraph:
 def share_graph(
     graph: HetGraph,
     include_features: bool = True,
-    tables: Optional[Dict[str, np.ndarray]] = None,
     name: Optional[str] = None,
 ) -> SharedHetGraph:
-    """Export ``graph`` (and optional staging ``tables``) into one segment.
+    """Export ``graph`` into one segment.
 
     ``include_features=False`` skips the graph's dense feature arrays —
-    sampler-only pools never read them, and staging pools read the
-    authoritative ``tables`` snapshot instead (which includes frozen
-    learnable rows the graph doesn't carry).  Transactional: a failure while
-    populating closes and unlinks the segment before re-raising.
+    sampler pools never read them (staging workers read the tables copied
+    into the batch arena, which include learnable rows the graph doesn't
+    carry).  Transactional: a failure while populating closes and unlinks
+    the segment before re-raising.
     """
     rel_list: List[Tuple[Relation, CSR]] = sorted(
         graph.relations.items(), key=lambda rc: rc[0]
@@ -274,8 +261,6 @@ def share_graph(
     if include_features:
         for t, f in graph.features.items():
             arrays[f"feat/{t}"] = np.ascontiguousarray(f)
-    for tname, tab in (tables or {}).items():
-        arrays[f"table/{tname}"] = np.ascontiguousarray(tab)
 
     refs, total = _layout(arrays)
     segment = name or f"{SEGMENT_PREFIX}{os.getpid():x}-{secrets.token_hex(4)}"

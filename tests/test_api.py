@@ -15,6 +15,7 @@ from repro.api import (
     HetaStageError,
     ModelConfig,
     PartitionConfig,
+    PipelineConfig,
     RunConfig,
     add_config_args,
     config_from_args,
@@ -250,21 +251,24 @@ def test_train_hgnn_wrapper_result_keys():
 
 
 def _pipe_config(executor, train_learnable=True, **pipeline):
+    """``tiny_config(executor)`` under the "stale" snapshot policy unless
+    ``pipeline`` says otherwise."""
     cfg = tiny_config(executor)
     if not train_learnable:
         cfg = cfg.updated(model=dict(train_learnable=False))
-    return cfg.updated(pipeline=dict(enabled=True, **pipeline))
+    return cfg.updated(pipeline={"snapshot": "stale", **pipeline})
 
 
 @pytest.mark.parametrize("executor", ["vanilla", "raf", "raf_spmd"])
 def test_pipeline_parity_frozen_features(executor):
-    """With frozen feature tables, staging is time-invariant: pipeline on/off
-    must produce bit-identical losses for every executor."""
+    """With frozen feature tables, staging is time-invariant: the "stale"
+    and "fresh" snapshot policies must produce bit-identical losses for
+    every executor."""
     off = Heta(tiny_config(executor).updated(
         model=dict(train_learnable=False))).run()
     on = Heta(_pipe_config(executor, train_learnable=False)).run()
     assert off["losses"] == on["losses"]  # bit-identical
-    assert on["pipeline"] and not off["pipeline"]
+    assert on["sampler_workers"] == off["sampler_workers"] == 0
     assert "overlap_fraction" in on and on["overlap_fraction"] >= 0.0
 
 
@@ -278,27 +282,12 @@ def test_pipeline_parity_learnable_dense_executors(executor):
 
 
 def test_pipeline_learnable_spmd_stale_within_tolerance():
-    """raf_spmd staging snapshots learnable tables; under the default
-    "stale" policy background staging may lag by <= depth+1 steps, so
-    losses track the serial path within optimization noise."""
+    """raf_spmd staging snapshots learnable tables; under the "stale"
+    policy background staging may lag by <= depth+1 steps, so losses track
+    the serial path within optimization noise."""
     off = Heta(tiny_config("raf_spmd")).run()
     on = Heta(_pipe_config("raf_spmd")).run()
     np.testing.assert_allclose(off["losses"], on["losses"], atol=5e-2)
-
-
-def test_pipeline_learnable_spmd_fresh_is_bit_exact():
-    """The "fresh" snapshot policy defers table-reading staging to the
-    consumer -> bit-exact parity even while learnable tables train."""
-    off = Heta(tiny_config("raf_spmd")).run()
-    on = Heta(_pipe_config("raf_spmd", snapshot="fresh")).run()
-    assert off["losses"] == on["losses"]
-
-
-def test_pipeline_evaluate_parity():
-    s_off = Heta(tiny_config("vanilla").updated(model=dict(train_learnable=False)))
-    s_on = Heta(_pipe_config("vanilla", train_learnable=False))
-    s_off.run(), s_on.run()
-    assert s_off.evaluate(3) == s_on.evaluate(3)
 
 
 def test_kernel_config_round_trips():
@@ -381,8 +370,10 @@ def test_fit_loop_triggers_online_readmission():
 
 
 def test_pipeline_config_round_trips():
-    cfg = HetaConfig().updated(pipeline=dict(enabled=True, depth=3,
-                                             snapshot="fresh", num_workers=4))
+    assert HetaConfig().pipeline == PipelineConfig(depth=2, snapshot="fresh",
+                                                   num_workers=0)
+    cfg = HetaConfig().updated(pipeline=dict(depth=3, snapshot="stale",
+                                             num_workers=4))
     assert HetaConfig.from_dict(cfg.to_dict()) == cfg
     assert HetaConfig.from_flat_kwargs(**cfg.to_flat_kwargs()) == cfg
     with pytest.raises(ValueError, match="snapshot"):
@@ -394,13 +385,38 @@ def test_pipeline_config_round_trips():
     # derived CLI flags
     ap = argparse.ArgumentParser()
     add_config_args(ap)
-    args = ap.parse_args(["--pipeline", "--prefetch-depth", "4",
-                          "--snapshot-policy", "fresh", "--num-workers", "2"])
+    args = ap.parse_args(["--prefetch-depth", "4",
+                          "--snapshot-policy", "stale", "--num-workers", "2"])
     got = config_from_args(args)
-    assert got.pipeline.enabled and got.pipeline.depth == 4
-    assert got.pipeline.snapshot == "fresh"
+    assert got.pipeline.depth == 4
+    assert got.pipeline.snapshot == "stale"
     assert got.pipeline.num_workers == 2
-    assert config_from_args(ap.parse_args([])).pipeline.num_workers == 0
+    assert config_from_args(ap.parse_args([])).pipeline == PipelineConfig()
+
+
+@pytest.mark.parametrize("field,flat,flag", [
+    ("enabled", "pipeline", "--pipeline"),
+    ("arena", "batch_arena", "--batch-arena"),
+    ("pin_workers", "pin_workers", "--pin-workers"),
+])
+def test_removed_pipeline_fields_are_refused(field, flat, flag):
+    """``pipeline.enabled``, ``arena`` and ``pin_workers`` are gone: a
+    dict, flat kwargs, an update or a CLI flag that names one is refused,
+    not silently dropped."""
+    d = HetaConfig().to_dict()
+    d["pipeline"][field] = True
+    with pytest.raises(TypeError, match=field):
+        HetaConfig.from_dict(d)
+    with pytest.raises(TypeError, match=flat):
+        HetaConfig.from_flat_kwargs(**{flat: True})
+    assert flat not in HetaConfig().to_flat_kwargs()
+    with pytest.raises(TypeError, match=field):
+        HetaConfig().updated(pipeline={field: True})
+    ap = argparse.ArgumentParser()
+    add_config_args(ap)
+    for arg in (flag, "--no-" + flag[2:]):
+        with pytest.raises(SystemExit):
+            ap.parse_args([arg])
 
 
 def test_checkpoint_and_fault_config_round_trips():
@@ -445,34 +461,3 @@ def test_checkpoint_and_fault_config_round_trips():
     assert got.serve.deadline_ms == 100.0
     assert got.serve.flush_retries == 1
     assert got.serve.breaker_threshold == 5
-
-
-def test_legacy_step_only_executor_still_works():
-    """Executors registered before the staged-step seam (override step()
-    only) keep working on the serial path; the pipeline names them as the
-    reason it can't run."""
-
-    @executors.register("_test_legacy")
-    class Legacy(executors.Executor):
-        def build_plan(self, sess):
-            return executors.get("vanilla").build_plan(sess)
-
-        def init_state(self, sess, plan):
-            return executors.get("vanilla").init_state(sess, plan)
-
-        def step(self, sess, plan, state, batch):
-            return executors.get("vanilla").step(sess, plan, state, batch)
-
-        def loss_and_metrics(self, sess, plan, state, batch):
-            return executors.get("vanilla").loss_and_metrics(
-                sess, plan, state, batch)
-
-    try:
-        m = Heta(tiny_config("_test_legacy")).run()
-        assert len(m["losses"]) == 3 and np.all(np.isfinite(m["losses"]))
-        sess = Heta(tiny_config("_test_legacy").updated(
-            pipeline=dict(enabled=True)))
-        with pytest.raises(HetaStageError, match="staged-step"):
-            sess.run()
-    finally:
-        del executors._REGISTRY["_test_legacy"]

@@ -29,9 +29,11 @@ from repro.data.worker_pool import (
 )
 from repro.graph.sampler import NeighborSampler, SampleSpec
 from repro.graph.shm import (
+    SEGMENT_PREFIX,
     attach_arena,
     create_arena,
     live_segments,
+    share_arrays,
     share_graph,
 )
 from repro.graph.synthetic import ogbn_mag_like
@@ -49,6 +51,13 @@ def _mag():
 
 def _probe_fields():
     return {"x": np.zeros((4, 3), np.float32), "y": np.zeros(4, np.int64)}
+
+
+def _own_segments():
+    """The shared-memory segments this test process created and still
+    holds (segment names carry their creator's pid): the leak check, blind
+    to the segments of tests running beside it in other processes."""
+    return live_segments(f"{SEGMENT_PREFIX}{os.getpid():x}-")
 
 
 def _assert_batches_equal(a, b):
@@ -295,7 +304,7 @@ def test_pool_arena_descriptors_stay_tiny_and_batches_match_serial():
     finally:
         store.unlink()
         arena.unlink()
-    assert not live_segments()
+    assert not _own_segments()
 
 
 @dataclasses.dataclass
@@ -343,7 +352,7 @@ def test_worker_crash_surfaces_and_leaks_no_segments():
         assert all(not p.is_alive() for p in pool._procs)
     finally:
         arena.unlink()
-    assert not live_segments()  # owner-side unlink survives worker death
+    assert not _own_segments()  # owner-side unlink survives worker death
 
 
 def test_create_arena_validates_and_is_transactional():
@@ -355,4 +364,20 @@ def test_create_arena_validates_and_is_transactional():
     with pytest.raises(AttributeError):
         create_arena(_probe_fields(), num_workers=1, depth=1,
                      tables={"t": object()})
-    assert not live_segments()
+    assert not _own_segments()
+
+
+def test_the_leak_check_sees_this_process_and_no_other():
+    """``_own_segments`` catches a segment this process leaves behind, and
+    passes over one named for another process (pid 0: no process's own
+    check, nor the stale-segment janitor, ever takes it)."""
+    other = share_arrays({"x": np.zeros(4)},
+                         name=f"{SEGMENT_PREFIX}0-{os.getpid():x}")
+    leaked = create_arena(_probe_fields(), num_workers=1, depth=1)
+    try:
+        assert _own_segments() == [leaked.handle.segment]
+        assert other.handle.segment in live_segments()
+    finally:
+        leaked.unlink()
+        other.unlink()
+    assert not _own_segments()

@@ -1,6 +1,7 @@
 """Host data pipelines: Prefetcher lifecycle, token pipeline determinism /
 sharding / liveness, per-batch sampler RNG, and the async SampleStream."""
 
+import os
 import threading
 import time
 
@@ -8,6 +9,15 @@ import numpy as np
 import pytest
 
 from repro.data import Prefetcher, SampleStream, SyntheticCorpus, TokenPipeline
+
+
+def _own_segments():
+    """The shared-memory segments this test process created and still
+    holds (segment names carry their creator's pid), so that the leak
+    checks do not see those of tests running beside it."""
+    from repro.graph.shm import SEGMENT_PREFIX, live_segments
+
+    return live_segments(f"{SEGMENT_PREFIX}{os.getpid():x}-")
 
 
 def test_corpus_deterministic_and_shifted():
@@ -283,8 +293,9 @@ def test_sample_stream_shutdown_on_exception():
     ("hgt", "raf"),
 ])
 def test_pipeline_parity_models(model, executor):
-    """With frozen feature tables staging is time-invariant, so pipeline
-    on/off must be bit-identical for every (model, executor) pair."""
+    """With frozen feature tables staging is time-invariant, so the "stale"
+    and "fresh" snapshot policies must be bit-identical for every (model,
+    executor) pair."""
     from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
                            PartitionConfig, RunConfig)
 
@@ -296,31 +307,34 @@ def test_pipeline_parity_models(model, executor):
             model=ModelConfig(model=model, hidden=32, train_learnable=False),
             run=RunConfig(executor=executor, steps=3, lr=1e-2, seed=0),
         )
-        return c.updated(pipeline=dict(enabled=True)) if pipelined else c
+        return c.updated(pipeline=dict(snapshot="stale")) if pipelined else c
 
     off = Heta(cfg(False)).run()
     on = Heta(cfg(True)).run()
     assert off["losses"] == on["losses"]  # bit-identical
-    assert on["pipeline"] and not off["pipeline"]
+    assert on["sampler_workers"] == off["sampler_workers"] == 0
     assert np.all(np.isfinite(on["losses"]))
 
 
 def test_sample_stream_facade_validates_modes():
     s = _mag_sampler(seed=2)
-    with pytest.raises(ValueError, match="worker_task"):
+    finish = lambda b, host: host  # noqa: E731
+    with pytest.raises(ValueError, match="arena"):
         SampleStream(lambda i: s.batch_at(i, epoch_seed=1), lambda b: b,
-                     num_workers=2)
+                     pool=object(), spec=s.spec, finish_stage=finish)
+    with pytest.raises(ValueError, match="spec"):
+        SampleStream(pool=object(), arena=object(), finish_stage=finish)
+    with pytest.raises(ValueError, match="finish_stage"):
+        SampleStream(pool=object(), arena=object(), spec=s.spec)
     with pytest.raises(ValueError, match="make_batch"):
-        SampleStream(stage=lambda b: b, num_workers=0)
-    with pytest.raises(ValueError, match="num_workers"):
-        SampleStream(lambda i: i, lambda b: b, num_workers=-1)
+        SampleStream(stage=lambda b: b)
 
 
 # --------------------------------------------------------------------------
-# multi-worker host pipeline (process pool over the shm graph store):
-# workers ∈ {0, 1, 4} must be bit-identical to the serial loop on every
-# executor — frozen tables make staging time-invariant, and batch_at purity
-# makes the stripe decomposition invisible (DESIGN.md §9)
+# multi-worker host pipeline (process pool over the shm graph store and the
+# batch arena): workers ∈ {0, 1, 4} must be bit-identical to the serial
+# loop on every executor — frozen tables make staging time-invariant, and
+# batch_at purity makes the stripe decomposition invisible (DESIGN.md §9)
 # --------------------------------------------------------------------------
 
 
@@ -351,7 +365,6 @@ def _only_sampled_in_workers(sess) -> bool:
 def test_worker_pool_parity_all_executors(executor):
     from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
                            PartitionConfig, RunConfig)
-    from repro.graph.shm import live_segments
 
     def run(workers):
         c = HetaConfig(
@@ -362,7 +375,7 @@ def test_worker_pool_parity_all_executors(executor):
             run=RunConfig(executor=executor, steps=3, lr=1e-2, seed=0),
         )
         if workers is not None:
-            c = c.updated(pipeline=dict(enabled=True, num_workers=workers))
+            c = c.updated(pipeline=dict(num_workers=workers))
         sess = Heta(c)
         try:
             r = sess.run()
@@ -378,27 +391,15 @@ def test_worker_pool_parity_all_executors(executor):
         assert serial["losses"] == r["losses"], (executor, w)
         assert r["sampler_workers"] == w
         assert r["samples_per_s"] > 0
-        if w:  # arena mode: queue carries SlotRef descriptors, not arrays
+        if w:  # the queue carries SlotRef descriptors, not arrays
             assert 0 < r["queue_bytes_per_step"] < 1024, (executor, w)
     assert serial["sampler_workers"] == 0
-    assert not live_segments()  # every run released its store
+    assert not _own_segments()  # every run released its store and arena
 
 
-def test_worker_pool_legacy_pickle_path_still_bit_identical():
-    """``pipeline.arena=False`` keeps the PR-5 pickle transport: same
-    batches, same losses, megabyte queue items (the cost the arena
-    removes)."""
-    from repro.graph.shm import live_segments
-
-    (on, on_staged), (off, off_staged) = (_pickle_or_arena_run(True),
-                                          _pickle_or_arena_run(False))
-    assert on_staged and off_staged
-    assert on["losses"] == off["losses"]
-    assert on["queue_bytes_per_step"] < 1024 < off["queue_bytes_per_step"]
-    assert not live_segments()
-
-
-def _pickle_or_arena_run(arena, check=_staged_in_workers):
+def _pooled_run(check=_staged_in_workers):
+    """A frozen raf_spmd fit over two sampler workers, and ``check`` of
+    its session."""
     from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
                            PartitionConfig, RunConfig)
 
@@ -408,7 +409,7 @@ def _pickle_or_arena_run(arena, check=_staged_in_workers):
         partition=PartitionConfig(num_partitions=2),
         model=ModelConfig(hidden=32, train_learnable=False),
         run=RunConfig(executor="raf_spmd", steps=3, lr=1e-2, seed=0),
-    ).updated(pipeline=dict(enabled=True, num_workers=2, arena=arena))
+    ).updated(pipeline=dict(num_workers=2))
     sess = Heta(c)
     try:
         return sess.run(), check(sess)
@@ -418,8 +419,8 @@ def _pickle_or_arena_run(arena, check=_staged_in_workers):
 
 def test_worker_pool_resident_cache_pickle_and_arena_bit_identical(
         device_gather_on_cpu):
-    """With a cache that holds every row the workers only sample, by either
-    transport, and both train as the serial loop does."""
+    """With a cache that holds every row the workers only sample, over the
+    batch arena, and train as the serial loop does."""
     from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
                            PartitionConfig, RunConfig)
 
@@ -430,17 +431,14 @@ def test_worker_pool_resident_cache_pickle_and_arena_bit_identical(
         model=ModelConfig(hidden=32, train_learnable=False),
         run=RunConfig(executor="raf_spmd", steps=3, lr=1e-2, seed=0),
     )).run()
-    for arena in (True, False):
-        r, sampled_only = _pickle_or_arena_run(arena,
-                                               check=_only_sampled_in_workers)
-        assert sampled_only, arena
-        assert r["losses"] == serial["losses"], arena
+    r, sampled_only = _pooled_run(check=_only_sampled_in_workers)
+    assert sampled_only
+    assert r["losses"] == serial["losses"]
+    assert 0 < r["queue_bytes_per_step"] < 1024  # descriptors, not arrays
 
 
-def _learnable_run(workers, snapshot="stale", arena=True):
-    """A learnable raf_spmd fit; its result gains ``staged_in_workers`` and
-    ``only_sampled_in_workers``."""
-    from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
+def _learnable_cfg(workers=None, snapshot="stale"):
+    from repro.api import (DataConfig, HetaConfig, ModelConfig,
                            PartitionConfig, RunConfig)
 
     c = HetaConfig(
@@ -451,9 +449,16 @@ def _learnable_run(workers, snapshot="stale", arena=True):
         run=RunConfig(executor="raf_spmd", steps=3, lr=1e-2, seed=0),
     )
     if workers is not None:
-        c = c.updated(pipeline=dict(enabled=True, num_workers=workers,
-                                    snapshot=snapshot, arena=arena))
-    sess = Heta(c)
+        c = c.updated(pipeline=dict(num_workers=workers, snapshot=snapshot))
+    return c
+
+
+def _learnable_run(workers, snapshot="stale"):
+    """A learnable raf_spmd fit; its result gains ``staged_in_workers`` and
+    ``only_sampled_in_workers``."""
+    from repro.api import Heta
+
+    sess = Heta(_learnable_cfg(workers, snapshot))
     try:
         r = sess.run()
         r["staged_in_workers"] = _staged_in_workers(sess)
@@ -463,17 +468,23 @@ def _learnable_run(workers, snapshot="stale", arena=True):
         sess.close_pipeline()
 
 
-def test_worker_pool_learnable_fresh_is_bit_exact():
-    """Under the "fresh" snapshot policy pool workers only sample — staging
-    runs consumer-side against the just-updated tables, so pooled losses
-    are bit-exact at every worker count."""
-    serial = _learnable_run(None)
-    for w in (0, 1, 4):
-        assert serial["losses"] == _learnable_run(w, snapshot="fresh")["losses"], w
+@pytest.mark.parametrize("workers", [0, 1, 4])
+def test_learnable_fresh_is_bit_exact(workers):
+    """Under the "fresh" snapshot policy (the default) the producer, thread
+    or pool, only samples while learnable tables train: staging runs
+    consumer-side against the just-updated tables, so fit's losses are a
+    loop of ``Heta.step``'s, bit for bit, at every worker count."""
+    stepped = _compiled(_learnable_cfg())
+    for _ in range(3):
+        stepped.step()
+    got = _learnable_run(workers, snapshot="fresh")
+    assert not got["staged_in_workers"]
+    assert got["losses"] == stepped.losses
+    assert got["sampler_workers"] == workers
 
 
 def test_worker_pool_learnable_stale_stages_in_workers_bounded():
-    """Under the default "stale" policy with the batch arena, workers stage
+    """Under the "stale" policy with the batch arena, workers stage
     against seqlock-republished table snapshots at most the ring depth
     behind the trainer (DESIGN.md §11): the loss trajectory tracks the
     serial path within optimization noise, and the queue stays zero-pickle
@@ -501,8 +512,6 @@ def test_pool_persists_across_fits_and_stays_bit_identical():
     """Consecutive fit() calls reuse one pool + shm store (spawn amortized)
     and the two-fit pooled trajectory equals one serial fit of the same
     length; a serial step() in between forces a clean respawn."""
-    from repro.graph.shm import live_segments
-
     sess, serial = _two_fits_pooled()
     # the workers staged every pooled batch; the consumer only the serial one
     assert sess.executor.worker_stage_recipe(sess, sess.plan) is not None
@@ -511,7 +520,7 @@ def test_pool_persists_across_fits_and_stays_bit_identical():
     sess.close_pipeline()
     assert sess._pool_cache is None
     sess.close_pipeline()  # idempotent
-    assert not live_segments()
+    assert not _own_segments()
 
 
 def _two_fits_pooled():
@@ -530,7 +539,7 @@ def _two_fits_pooled():
                 run=RunConfig(executor="raf_spmd", steps=6, lr=1e-2, seed=0),
         )
         if workers is not None:
-            c = c.updated(pipeline=dict(enabled=True, num_workers=workers))
+            c = c.updated(pipeline=dict(num_workers=workers))
         return c
 
     serial = Heta(cfg()).run()
@@ -558,28 +567,79 @@ def test_pool_persists_across_fits_on_the_resident_path(device_gather_on_cpu):
         sess.close_pipeline()
 
 
-def test_evaluate_with_workers_matches_serial():
+@pytest.mark.parametrize("workers", [0, 2])
+def test_evaluate_matches_serial(workers):
+    """``evaluate`` from its producer, thread or pool, scores the held-out
+    batches a loop of the executor's eval path scores, to the bit."""
     from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
                            PartitionConfig, RunConfig)
+    from repro.graph.sampler import NeighborSampler
 
-    def build(workers):
-        c = HetaConfig(
-            data=DataConfig(dataset="ogbn-mag", scale=0.002, fanouts=(3, 2),
-                            batch_size=16),
-            partition=PartitionConfig(num_partitions=2),
-            model=ModelConfig(hidden=32, train_learnable=False),
-            run=RunConfig(executor="raf_spmd", steps=0, lr=1e-2, seed=0),
-        )
-        if workers is not None:
-            c = c.updated(pipeline=dict(enabled=True, num_workers=workers))
-        sess = Heta(c)
-        sess.run()
-        return sess
+    sess = Heta(HetaConfig(
+        data=DataConfig(dataset="ogbn-mag", scale=0.002, fanouts=(3, 2),
+                        batch_size=16),
+        partition=PartitionConfig(num_partitions=2),
+        model=ModelConfig(hidden=32, train_learnable=False),
+        run=RunConfig(executor="raf_spmd", steps=0, lr=1e-2, seed=0),
+    ).updated(pipeline=dict(num_workers=workers)))
+    sess.run()
+    got = sess.evaluate(num_batches=2)
+    eval_seed = sess.config.run.seed + 9999
+    sampler = NeighborSampler(sess.graph, sess.spec, 16, seed=eval_seed)
+    losses = [sess.executor.loss_and_metrics(
+        sess, sess.plan, sess.state,
+        sampler.batch_at(i, epoch_seed=eval_seed))[0] for i in range(2)]
+    assert got["loss"] == float(np.mean(losses))
+    assert got["num_batches"] == 2
+    assert sess.results()["sampler_workers"] == workers
+    assert not _own_segments()  # the eval pool released its store and arena
 
-    ref = build(None).evaluate(num_batches=2)
-    pooled = build(2).evaluate(num_batches=2)
-    assert ref["loss"] == pooled["loss"]
-    assert ref["num_batches"] == pooled["num_batches"] == 2
+
+@pytest.mark.parametrize("workers", [0, 2])
+@pytest.mark.parametrize("stage", ["profile_and_cache", "fit", "evaluate"])
+def test_a_pool_runs_exactly_when_num_workers_is_positive(stage, workers,
+                                                          monkeypatch):
+    """Every sampling loop of the session asks one helper for its
+    producer: a sampler pool of ``num_workers`` processes starts exactly
+    when that is > 0, and ``results()["sampler_workers"]`` says so."""
+    from repro.api import (DataConfig, Heta, HetaConfig, ModelConfig,
+                           PartitionConfig, RunConfig)
+    from repro.data.worker_pool import WorkerPool
+
+    sess = Heta(HetaConfig(
+        data=DataConfig(dataset="ogbn-mag", scale=0.002, fanouts=(3, 2),
+                        batch_size=16),
+        partition=PartitionConfig(num_partitions=2),
+        model=ModelConfig(hidden=32, train_learnable=False),
+        run=RunConfig(executor="raf_spmd", steps=2, lr=1e-2, seed=0),
+    ).updated(pipeline=dict(num_workers=workers)))
+    started, running = [], [None]
+    real = WorkerPool.__init__
+
+    def init(self, task, num_workers, *args, **kw):
+        started.append((running[0], num_workers))
+        real(self, task, num_workers, *args, **kw)
+
+    monkeypatch.setattr(WorkerPool, "__init__", init)
+    calls = {"profile_and_cache": sess.profile_and_cache,
+             "fit": sess.fit,
+             "evaluate": lambda: sess.evaluate(num_batches=2)}
+    sess.build_graph()
+    sess.partition()
+    try:
+        for name in calls:
+            if name == "fit":
+                sess.compile()
+            running[0] = name
+            calls[name]()
+            if name == stage:
+                break
+        assert sess.results()["sampler_workers"] == workers
+    finally:
+        sess.close_pipeline()
+    assert [w for at, w in started if at == stage] == (
+        [workers] if workers else [])
+    assert not _own_segments()
 
 
 def test_seedless_epochs_vary_but_replay_deterministically():
@@ -651,7 +711,7 @@ def test_fit_is_bit_identical_to_a_loop_of_step(path, monkeypatch):
     executor, _, learnable, resident = FIT_PATHS[path]
     cfg = _fit_path_cfg(path, monkeypatch)
     fitted, stepped = _compiled(cfg), _compiled(cfg)
-    assert not cfg.pipeline.enabled
+    assert cfg.pipeline.num_workers == 0 and cfg.pipeline.snapshot == "fresh"
     fitted.fit(steps=4)
     for _ in range(4):
         stepped.step()

@@ -215,28 +215,41 @@ def _assert_batches_equal(a, b):
 def test_sampler_kill_replay_bit_identical_to_serial():
     """A killed sampler worker's stripe is replayed by its replacement:
     every delivered batch still matches the serial sampler bit-for-bit."""
+    from repro.data import SampleStream
+    from repro.data.staging import arena_fields
+
     g, spec = _mag()
     serial = NeighborSampler(g, spec, 8, seed=5)
     E = serial.steps_per_epoch()
     store = share_graph(g, include_features=False)
+    arena = create_arena(arena_fields(serial.batch_at(0, epoch_seed=0)),
+                         num_workers=2, depth=2)
     try:
         task = SampleStageTask(
             handle=store.handle, spec=spec, batch_size=8, sampler_seed=5,
-            schedule=EpochSchedule(77, E),
+            schedule=EpochSchedule(77, E), arena=arena.handle,
             faults=FaultPlan((FaultSpec("kill_worker", step=3),)),
         )
         n = 6
+        # as the session wires it: a death poisons the dead worker's
+        # sub-ring before its replacement replays the stripe
         with WorkerPool(task, num_workers=2, depth=2, num_items=n,
-                        max_restarts=1, restart_backoff_s=0.01) as pool:
-            for i, (batch, host, host_s) in enumerate(pool):
+                        max_restarts=1, restart_backoff_s=0.01,
+                        on_worker_death=arena.invalidate_worker_slots) as pool, \
+                SampleStream(num_steps=n, pool=pool, arena=arena, spec=spec,
+                             finish_stage=lambda b, host: host) as stream:
+            for i, (batch, host, host_s) in enumerate(stream):
                 seed, idx = EpochSchedule(77, E).seed_and_index(i)
                 _assert_batches_equal(batch, serial.batch_at(idx, epoch_seed=seed))
                 assert host is None and host_s >= 0.0
+            assert i == n - 1
             assert len(pool.restarts) == 1
             assert pool.restarts[0]["exitcode"] == KILL_EXIT_CODE
     finally:
         store.unlink()
+        arena.unlink()
     assert not live_segments(store.handle.segment)
+    assert not live_segments(arena.handle.segment)
 
 
 def _probe_fields():
@@ -331,8 +344,7 @@ def _chaos_config():
         model=ModelConfig(hidden=32),
         cache=CacheConfig(cache_mb=2, presample_epochs=1),
         run=RunConfig(executor="raf_spmd", steps=10, lr=1e-2, seed=0),
-        pipeline=PipelineConfig(enabled=True, num_workers=2, depth=2,
-                                snapshot="fresh"),
+        pipeline=PipelineConfig(num_workers=2, depth=2),
         faults=FaultConfig(max_worker_restarts=2, worker_backoff_s=0.01),
     )
 

@@ -231,17 +231,14 @@ def _stream_books(**pipeline):
     return sess
 
 
-def test_session_books_on_the_thread_pipeline():
-    sess = _stream_books(enabled=True, depth=2)
-    assert sess.results()["pipeline"]
-
-
-def test_session_books_of_fit_without_the_opt_in_are_the_streams():
-    """``fit`` overlaps with no pipeline option too; ``results`` still
-    reports the opt-in as off."""
-    sess = _stream_books()
+@pytest.mark.parametrize("snapshot", ["fresh", "stale"])
+def test_session_books_of_fit_are_the_streams(snapshot):
+    """``fit`` runs the thread stream under either snapshot policy (frozen
+    rows: the producer stages in both), and ``results`` reports no sampler
+    worker."""
+    sess = _stream_books(snapshot=snapshot)
     m = sess.results()
-    assert not m["pipeline"]
+    assert m["sampler_workers"] == 0
     assert m["spans"]["heta.pipeline.wait"]["count"] == 4
 
 

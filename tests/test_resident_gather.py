@@ -200,7 +200,7 @@ def test_workers_only_sample_on_the_resident_path(learnable,
     """On the resident path the pool gets no staging recipe and staging
     reads no table; a pooled fit stages on the consumer and trains as the
     serial fit does."""
-    pooled = _session(_cfg(learnable=learnable, enabled=True, num_workers=1,
+    pooled = _session(_cfg(learnable=learnable, num_workers=1,
                            snapshot="stale"))
     try:
         ex = pooled.executor

@@ -29,8 +29,7 @@ def _spec(g, fanouts=(3, 2)):
 
 def test_attach_round_trip_bit_equal():
     g = _graph()
-    tables = {"paper": g.features["paper"].astype(np.float32)}
-    with share_graph(g, include_features=True, tables=tables) as store:
+    with share_graph(g, include_features=True) as store:
         att = attach(store.handle)
         assert att.graph.num_nodes == g.num_nodes
         assert att.graph.target_type == g.target_type
@@ -44,7 +43,6 @@ def test_attach_round_trip_bit_equal():
         np.testing.assert_array_equal(g.train_nodes, att.graph.train_nodes)
         for t, f in g.features.items():
             np.testing.assert_array_equal(f, att.graph.features[t])
-        np.testing.assert_array_equal(tables["paper"], att.tables["paper"])
         att.close()
     assert not live_segments(store.handle.segment)
 
@@ -113,9 +111,9 @@ def test_unshareable_dtype_rejected_without_segment():
     g = _graph()
     before = live_segments()
     # object arrays are pointers — meaningless in another process
-    bad = {"paper": np.array([[object()]], dtype=object)}
+    g.features["paper"] = np.array([[object()]], dtype=object)
     with pytest.raises(ValueError, match="object dtype"):
-        share_graph(g, tables=bad)
+        share_graph(g, include_features=True)
     assert live_segments() == before
 
 

@@ -11,13 +11,15 @@ import numpy as np
 import pytest
 
 from repro.core.metatree import build_metatree
+from repro.data import SampleStream
+from repro.data.staging import arena_fields
 from repro.data.worker_pool import (
     EpochSchedule,
     SampleStageTask,
     WorkerPool,
 )
 from repro.graph.sampler import NeighborSampler, SampleSpec
-from repro.graph.shm import live_segments, share_graph
+from repro.graph.shm import create_arena, live_segments, share_graph
 from repro.graph.synthetic import ogbn_mag_like
 
 pytestmark = pytest.mark.skipif(
@@ -158,27 +160,47 @@ def test_epoch_schedule_matches_session_formula():
     assert sched.seed_and_index(0) == (42, 3)
 
 
+def _store_and_arena(g, spec, num_workers, depth, recipe=None, tables=None):
+    """The shared graph store and a batch arena sized for its batches."""
+    store = share_graph(g, include_features=False)
+    probe = NeighborSampler(g, spec, 8, seed=5).batch_at(0, epoch_seed=0)
+    arena = create_arena(arena_fields(probe, recipe=recipe, tables=tables),
+                         num_workers=num_workers, depth=depth, tables=tables)
+    return store, arena
+
+
+def _stream(pool, arena, spec, n):
+    """The pool's first ``n`` items as ``(batch, host_arrays, host_s)``,
+    resolved from their arena slots."""
+    return SampleStream(num_steps=n, pool=pool, arena=arena, spec=spec,
+                        finish_stage=lambda batch, host: host)
+
+
 @pytest.mark.parametrize("num_workers", [1, 3])
 def test_pool_batches_bit_identical_to_serial(num_workers):
     g, spec = _mag()
     serial = NeighborSampler(g, spec, 8, seed=5)
     E = serial.steps_per_epoch()
-    store = share_graph(g, include_features=False)
+    store, arena = _store_and_arena(g, spec, num_workers, 2)
     try:
         task = SampleStageTask(
             handle=store.handle, spec=spec, batch_size=8, sampler_seed=5,
-            schedule=EpochSchedule(77, E),
+            schedule=EpochSchedule(77, E), arena=arena.handle,
         )
         n = min(E + 2, 6)  # cross an epoch boundary when the graph allows
         with WorkerPool(task, num_workers=num_workers, depth=2,
-                        num_items=n) as pool:
-            for i, (batch, host, host_s) in enumerate(pool):
+                        num_items=n) as pool, \
+                _stream(pool, arena, spec, n) as stream:
+            for i, (batch, host, host_s) in enumerate(stream):
                 seed, idx = EpochSchedule(77, E).seed_and_index(i)
                 _assert_batches_equal(batch, serial.batch_at(idx, epoch_seed=seed))
                 assert host is None and host_s >= 0.0
+            assert i == n - 1
     finally:
         store.unlink()
+        arena.unlink()
     assert not live_segments(store.handle.segment)
+    assert not live_segments(arena.handle.segment)
 
 
 def test_sampler_workers_never_import_jax():
@@ -186,18 +208,22 @@ def test_sampler_workers_never_import_jax():
     belongs to one process at a time: a worker that imported jax would
     claim or wait for the chip.  Sampling and staging must stay jax-free."""
     g, spec = _mag()
-    store = share_graph(g, include_features=False)
+    # two slots per worker: each of the 4 items has a slot of its own, so
+    # no write waits for a release the probe never makes
+    store, arena = _store_and_arena(g, spec, 2, 2)
     try:
         task = SampleStageTask(
             handle=store.handle, spec=spec, batch_size=8, sampler_seed=5,
             schedule=EpochSchedule(77, NeighborSampler(
                 g, spec, 8, seed=5).steps_per_epoch()),
+            arena=arena.handle,
         )
         with WorkerPool(JaxProbeTask(task), num_workers=2, depth=1,
                         num_items=4) as pool:
             assert list(pool) == [False] * 4
     finally:
         store.unlink()
+        arena.unlink()
 
 
 def test_worker_staging_matches_consumer_staging():
@@ -228,14 +254,17 @@ def test_worker_staging_matches_consumer_staging():
         for t in g.num_nodes
     }
     serial = NeighborSampler(g, spec, 8, seed=5)
-    store = share_graph(g, include_features=False, tables=tables)
+    store, arena = _store_and_arena(g, spec, 2, 2, recipe=recipe,
+                                    tables=tables)
     try:
         task = SampleStageTask(
             handle=store.handle, spec=spec, batch_size=8, sampler_seed=5,
             schedule=EpochSchedule(9, serial.steps_per_epoch()), recipe=recipe,
+            arena=arena.handle,
         )
-        with WorkerPool(task, num_workers=2, depth=2, num_items=3) as pool:
-            for i, (batch, host, _) in enumerate(pool):
+        with WorkerPool(task, num_workers=2, depth=2, num_items=3) as pool, \
+                _stream(pool, arena, spec, 3) as stream:
+            for i, (batch, host, _) in enumerate(stream):
                 assert host is not None
                 ref = stack_batch_host(
                     recipe, serial.batch_at(i, epoch_seed=9), tables)
@@ -246,23 +275,27 @@ def test_worker_staging_matches_consumer_staging():
                 dev = raf_spmd.stack_batch(plan, batch, tables)
                 for k in ref:
                     np.testing.assert_array_equal(np.asarray(dev[k]), ref[k])
+            assert i == 2
     finally:
         store.unlink()
+        arena.unlink()
 
 
 def test_pool_shutdown_leaves_no_processes_quickly():
     g, spec = _mag()
-    store = share_graph(g, include_features=False)
+    store, arena = _store_and_arena(g, spec, 2, 1)
     try:
         task = SampleStageTask(
             handle=store.handle, spec=spec, batch_size=8, sampler_seed=0,
             schedule=EpochSchedule(0, NeighborSampler(g, spec, 8).steps_per_epoch()),
+            arena=arena.handle,
         )
         pool = WorkerPool(task, num_workers=2, depth=1)  # infinite
         next(pool)
         t0 = time.perf_counter()
-        pool.close()
+        pool.close()  # a worker blocked on a full sub-ring stops too
         assert time.perf_counter() - t0 < 10.0
         assert all(not p.is_alive() for p in pool._procs)
     finally:
         store.unlink()
+        arena.unlink()
