@@ -38,7 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +59,8 @@ __all__ = [
     "stack_batch",
     "stack_recipe",
     "gather_resident",
+    "gather_leaf",
+    "feat_grads_as_staged",
     "raf_spmd_forward",
     "sync_stack_grads",
     "make_loss_fn",
@@ -333,37 +335,87 @@ def stack_batch(
         return {k: jnp.asarray(v) for k, v in host.items()}
 
 
-def gather_resident(plan: StackedPlan, arrays: Dict, tables=None) -> Dict:
+def gather_resident(plan: StackedPlan, arrays: Dict, tables=None,
+                    kernels=None) -> Dict:
     """The device half of resident staging (traceable; inside the compiled
     step).  ``arrays`` carry the int32 node ids ``qnid{d}``/``hnid{k}`` of
     ``repro.data.staging.stack_batch_nids``; ``tables`` maps each node type
     to its cache's device rows, which a cache that holds every row keeps in
-    node-id order.  Builds ``qfeat{d}``/``hfeat{k}`` with XLA's gather, one
-    ``take`` per branch slot from the slot's static source type, widths
-    below ``d_pad`` zero-padded and padding slots zero: the arrays
-    ``stack_batch_host`` builds on the host, bit for bit.  With ``tables=None`` the arrays already hold the features
-    and pass through unchanged."""
+    node-id order.
+
+    XLA's gather, one ``take`` per branch slot from the slot's static
+    source type, widths below ``d_pad`` zero-padded and padding slots zero.
+    ``qfeat{d}`` is the array ``stack_batch_host`` builds on the host; the
+    leaf level's ``hfeat{k}`` is taken straight in the layout its
+    aggregation reads (:func:`gather_leaf`), with ``kernels`` deciding the
+    padded fanout.  With ``tables=None`` the arrays already hold the
+    features and pass through unchanged."""
+    from repro.kernels.stacked_relation_agg import fanout_tile
+
     if tables is None:
         return arrays
     out = dict(arrays)
     k = plan.spec.num_layers
     for d in range(1, k + 1):
         sb = plan.levels[d - 1].slot_branch.reshape(-1)
-        for kind, types in (("q", plan.dst_types[d - 1]),
-                            ("h", plan.src_types[d - 1])):
-            if kind == "h" and d != k:
-                continue
-            nids = out.pop(f"{kind}nid{d}")  # [P*rb, n]
-            rows = []
-            for i, b in enumerate(sb):
-                if b < 0:
-                    rows.append(jnp.zeros((nids.shape[1], plan.d_pad), jnp.float32))
-                    continue
-                tab = tables[types[b]]
-                r = jnp.take(tab, nids[i], axis=0, mode="clip").astype(jnp.float32)
-                rows.append(jnp.pad(r, ((0, 0), (0, plan.d_pad - tab.shape[1]))))
-            out[f"{kind}feat{d}"] = jnp.stack(rows)
+        slots = [tables[plan.dst_types[d - 1][b]] if b >= 0 else None
+                 for b in sb]
+        out[f"qfeat{d}"] = _take_rows(slots, out.pop(f"qnid{d}"), plan.d_pad)
+    lp = plan.levels[k - 1]
+    tile = fanout_tile(plan.module, kernels)
+    out[f"hfeat{k}"] = gather_leaf(
+        [tables[plan.src_types[k - 1][b]] if b >= 0 else None
+         for b in lp.slot_branch.reshape(-1)],
+        out.pop(f"hnid{k}"), out[f"mask{k}"], fanout=lp.fanout,
+        f_k=-(-lp.fanout // tile) * tile, d_pad=plan.d_pad)
     return out
+
+
+def _take_rows(slots: Sequence, ids, d_pad: int, keep=None):
+    """``stack`` over slots of ``take(slots[s], ids[s])`` as float32, its
+    lanes zero-padded to ``d_pad``; zero where ``keep[s]`` is false and on a
+    padding slot (``None``)."""
+    rows = []
+    for s, (tab, i) in enumerate(zip(slots, ids)):
+        if tab is None:
+            rows.append(jnp.zeros(i.shape + (d_pad,), jnp.float32))
+            continue
+        r = jnp.take(tab, i, axis=0, mode="clip").astype(jnp.float32)
+        if keep is not None:
+            r = jnp.where(keep[s][..., None], r, 0.0)
+        rows.append(jnp.pad(r, [(0, 0)] * i.ndim + [(0, d_pad - tab.shape[1])]))
+    return jnp.stack(rows)
+
+
+def gather_leaf(slots: Sequence, nids, mask, *, fanout: int, f_k: int,
+                d_pad: int):
+    """The leaf level's rows as its aggregation reads them,
+    ``[P*rb, n_prev, f_k, d_pad]`` float32: ``nids`` and ``mask``
+    (``[P*rb, n_prev * fanout]``, as staged) are laid out ``[n_prev,
+    f_k]`` before the gather, so XLA takes each slot's rows straight into
+    that layout, with no relayout of fanout ``fanout`` into whole sublane
+    tiles and no padded copy to ``f_k``.  ``out[s, i, j]`` is row
+    ``nids[s, i*fanout + j]`` of ``slots[s]`` where ``mask`` keeps it, zero
+    on the rest, on the fanout padding ``j >= fanout`` and on a padding slot
+    (``None``): the slots the aggregation weights zero."""
+    S = nids.shape[0]
+    pad = ((0, 0), (0, 0), (0, f_k - fanout))
+    ids = jnp.pad(nids.reshape(S, -1, fanout), pad)
+    keep = jnp.pad(mask.reshape(S, -1, fanout), pad)
+    return _take_rows(slots, ids, d_pad, keep)
+
+
+def feat_grads_as_staged(plan: StackedPlan, gf: Dict) -> Dict:
+    """Gradients of the gathered feature arrays in the layout staging gives
+    them: a leaf ``hfeat{k}`` gathered 4-D (:func:`gather_resident`) has
+    its fanout padding cut and its gradient back to ``[P*rb, n_d, d_pad]``,
+    which is what ``executors.apply_feature_grads`` reads."""
+    k = plan.spec.num_layers
+    g = gf.get(f"hfeat{k}")
+    if g is None or g.ndim == 3:
+        return gf
+    f = plan.levels[k - 1].fanout
+    return {**gf, f"hfeat{k}": g[:, :, :f].reshape(g.shape[0], -1, g.shape[3])}
 
 
 # --------------------------------------------------------------------------
@@ -383,7 +435,7 @@ def _agg_level(plan: StackedPlan, lp: LevelPlan, stacks, h_in, qfeat, mask,
     The per-shard slot indices are *traced* (``shard_idx`` differs per
     shard), which is exactly what the scalar-prefetch indirection supports.
 
-    h_in  [rb, n_d, d_in] -> out [rb, n_prev, hidden]
+    h_in  [rb, n_d, d_in] or [rb, n_prev, f_k, d_in] -> out [rb, n_prev, hidden]
     """
     from repro.kernels.stacked_relation_agg import stacked_agg
 
@@ -394,11 +446,16 @@ def _agg_level(plan: StackedPlan, lp: LevelPlan, stacks, h_in, qfeat, mask,
     slot_u = {
         scope: jnp.asarray(lp.slot_u[scope])[shard_idx] for scope in module.scopes
     }  # each [rb]
-    rb, n_d, d_in = h_in.shape
     f = lp.fanout
-    n_prev = n_d // f
-    hg = h_in.reshape(rb, n_prev, f, d_in)
+    if h_in.ndim == 3:  # [rb, n_d, d_in] as staged, or a level's activations
+        rb, n_d, d_in = h_in.shape
+        hg = h_in.reshape(rb, n_d // f, f, d_in)
+    else:  # gathered on the chip at the padded fanout (gather_resident)
+        hg = h_in
+    rb, n_prev, f_k = hg.shape[:3]
     mg = mask.reshape(rb, n_prev, f)
+    if f_k > f:
+        mg = jnp.pad(mg, ((0, 0), (0, 0), (0, f_k - f)))
     out = stacked_agg(module, local, slot_u, hg, qfeat, mg, opts=kernels)
     return out * valid[:, None, None].astype(out.dtype)
 
@@ -494,7 +551,7 @@ def sync_stack_grads(plan: StackedPlan, grads: Dict) -> Dict:
 # --------------------------------------------------------------------------
 
 
-def _array_specs(plan: StackedPlan, data_axes, model_axis):
+def _array_specs(plan: StackedPlan, data_axes, model_axis, leaf_ndim: int = 3):
     k = plan.spec.num_layers
     specs = {"seeds": P(data_axes), "labels": P(data_axes)}
     for d in range(1, k + 1):
@@ -502,7 +559,10 @@ def _array_specs(plan: StackedPlan, data_axes, model_axis):
         specs[f"qfeat{d}"] = P(model_axis, data_axes, None)
         specs[f"qnid{d}"] = P(model_axis, data_axes)
         if d == k:
-            specs[f"hfeat{d}"] = P(model_axis, data_axes, None)
+            # [P*rb, n_d, d] as staged; [P*rb, n_prev, f_k, d] as the
+            # resident gather lays it out
+            specs[f"hfeat{d}"] = P(model_axis, data_axes, None,
+                                   *([None] * (leaf_ndim - 3)))
             specs[f"hnid{d}"] = P(model_axis, data_axes)
     return specs
 
@@ -549,12 +609,15 @@ def _build_loss_fn(
                 kernels,
             )
 
+        leaf = feats.get(f"hfeat{plan.spec.num_layers}")
+        feat_specs = _array_specs(plan, da, model_axis,
+                                  3 if leaf is None else leaf.ndim)
         return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
                 rel_specs,
-                {k2: arr_specs[k2] for k2 in feats},
+                {k2: feat_specs[k2] for k2 in feats},
                 {k2: arr_specs[k2] for k2 in rest},
             ),
             out_specs=P(da, None),
@@ -589,7 +652,8 @@ def make_loss_fn(
 
     @jax.jit
     def eval_loss(stacks, arrays, tables=None):
-        feats, rest = split_arrays(gather_resident(plan, arrays, tables))
+        feats, rest = split_arrays(
+            gather_resident(plan, arrays, tables, kernels))
         return loss_fn(stacks, feats, rest)
 
     return eval_loss
@@ -635,7 +699,8 @@ def make_train_step(
 
         @partial(jax.jit, donate_argnums=(0, 1))
         def step(stacks, opt_state, arrays, tables=None):
-            feats, rest = split_arrays(gather_resident(plan, arrays, tables))
+            feats, rest = split_arrays(
+                gather_resident(plan, arrays, tables, kernels))
             loss, grads = grad_fn(stacks, feats, rest)
             grads = sync_stack_grads(plan, grads)
             stacks, opt_state = adam_update(adam_cfg, stacks, grads, opt_state)
@@ -647,11 +712,12 @@ def make_train_step(
 
     @partial(jax.jit, donate_argnums=(0, 1))
     def step_feats(stacks, opt_state, arrays, tables=None):
-        feats, rest = split_arrays(gather_resident(plan, arrays, tables))
+        feats, rest = split_arrays(
+            gather_resident(plan, arrays, tables, kernels))
         loss, (gs, gf) = grad_fn2(stacks, feats, rest)
         gs = sync_stack_grads(plan, gs)
         stacks, opt_state = adam_update(adam_cfg, stacks, gs, opt_state)
-        return stacks, opt_state, loss, gf
+        return stacks, opt_state, loss, feat_grads_as_staged(plan, gf)
 
     return step_feats
 

@@ -226,8 +226,9 @@ def stack_batch_nids(recipe: StackRecipe, batch) -> Dict[str, np.ndarray]:
     ``[P*rb, n]`` in the layout of the features it replaces.  A cache that
     holds every row keeps it in node-id order, so the compiled step
     (``raf_spmd.gather_resident``) gathers the rows by node id on the
-    device, padding slots zero, and its arrays equal this function's host
-    twin bit for bit.
+    device, padding slots zero: its arrays equal this function's host twin
+    bit for bit, the leaf rows on every slot the mask keeps (the rest are
+    zero, laid out as the level's aggregation reads them).
 
     The fill is the span ``heta.stage.gather``; counter
     ``heta.stage.device_rows``: the rows the step will gather on the

@@ -9,6 +9,7 @@ the grouped "stacked XLA" oracle live in ``ref``.
 """
 
 from repro.kernels.stacked_relation_agg.ops import (  # noqa: F401
+    fanout_tile,
     stacked_agg,
     stacked_agg_grouped,
     stacked_agg_ref,

@@ -81,6 +81,7 @@ __all__ = [
     "stacked_attn_epilogue_vmem_bytes",
     "stacked_attn_bwd_vmem_bytes",
     "stacked_attn_bwd_block",
+    "fanout_tile",
 ]
 
 
@@ -111,6 +112,18 @@ def stacked_softmax_combine_vmem_bytes(
 # (an unaligned one compiles to relayouts, slowly and into more VMEM).
 # Padded slots are masked out, so they change nothing.
 _F_TILE = 8
+
+
+def fanout_tile(module, opts=None) -> int:
+    """The tile :func:`stacked_agg` pads a level's fanout to: ``_F_TILE``
+    where the module's attention runs the fused epilogue kernels, 1 (no
+    padding) elsewhere.  A producer that lays the neighbor rows out at the
+    padded fanout (``repro.core.raf_spmd.gather_leaf``) spares the op its
+    padded copy."""
+    use, _ = kernel_choice(opts, "stacked_agg")
+    fused = (use and module.fused == "softmax_combine"
+             and getattr(opts, "fuse_epilogue", True))
+    return _F_TILE if fused else 1
 
 
 def _lanes(x: int) -> int:

@@ -50,7 +50,7 @@ def _rows(sess):
 
 
 # --------------------------------------------------------------------------
-# the gathered arrays equal the host gather, bit for bit
+# the gathered arrays equal the host gather on every slot the mask keeps
 # --------------------------------------------------------------------------
 
 
@@ -59,7 +59,10 @@ def _rows(sess):
 def test_device_gather_equals_host_gather(parts, learnable_dim):
     """Mixed widths (64-d learnable rows against 128-d paper features)
     exercise the ``d_pad`` zero padding; more than one partition left
-    unfolded gives the recipe padding slots."""
+    unfolded gives the recipe padding slots.  The parents' rows equal the
+    host's bit for bit; the leaf rows arrive as ``[P*rb, n_prev, fanout,
+    d_pad]``, equal to the host's on every slot the mask keeps and zero on
+    the rest."""
     import jax
 
     from repro.core import raf_spmd
@@ -96,13 +99,73 @@ def test_device_gather_equals_host_gather(parts, learnable_dim):
     dev = raf_spmd.gather_resident(
         plan, {k: jnp.asarray(v) for k, v in nids.items()}, device)
     assert set(dev) == set(host)
-    for k, v in host.items():
-        if "feat" in k:
-            got = np.asarray(dev[k])
-            assert got.dtype == v.dtype and got.shape == v.shape, k
-        else:  # seeds, labels and masks as they are
-            got = nids[k]
-        assert np.array_equal(got, v), k
+    k = spec.num_layers
+    for key, v in host.items():
+        got = np.asarray(dev[key]) if "feat" in key else nids[key]
+        if key == f"hfeat{k}":
+            f = spec.fanouts[k - 1]
+            assert got.shape == (v.shape[0], v.shape[1] // f, f, v.shape[2])
+            got = got.reshape(v.shape)
+            keep = nids[f"mask{k}"]
+            assert keep.any() and not keep.all()
+            assert np.array_equal(got[keep], v[keep])
+            assert not got[~keep].any()
+        else:  # parents' rows, seeds, labels and masks as they are
+            assert got.dtype == v.dtype and got.shape == v.shape, key
+            assert np.array_equal(got, v), key
+
+
+def _take_oracle(tables, tab_of, nids, mask, fanout, f_k, d_pad):
+    """The contract of ``gather_leaf`` in plain numpy."""
+    S, n_d = nids.shape
+    out = np.zeros((S, n_d // fanout, f_k, d_pad), np.float32)
+    for s, t in enumerate(tab_of):
+        if t < 0:
+            continue
+        for r in np.flatnonzero(mask[s]):
+            row = np.asarray(tables[t])[nids[s, r]]
+            out[s, r // fanout, r % fanout, :row.shape[0]] = row
+    return out
+
+
+# widths of the slots' tables, fanout, the padded fanout f_k, parents per
+# slot; each with a padding slot
+GATHER_CASES = {
+    "attention-f20-tile24": ((128, 128, 128), 20, 24, 32),
+    "mean-linear-f20": ((128, 128), 20, 20, 37),
+    "f8-whole-tiles": ((128,), 8, 8, 24),
+    "f5-tile8": ((128, 128), 5, 8, 13),
+    "mixed-widths-64-128": ((64, 128), 3, 8, 10),
+}
+
+
+@pytest.mark.parametrize("case", list(GATHER_CASES))
+def test_stacked_gather_matches_take(case):
+    """``gather_leaf`` against a plain take: equal on every kept slot;
+    zero on masked-out slots, on the fanout padding, on padding slots and
+    on the lanes past a narrower table's width."""
+    from repro.core.raf_spmd import gather_leaf
+
+    widths, fanout, f_k, n = GATHER_CASES[case]
+    rng = np.random.default_rng(len(case))
+    tables = [jnp.asarray(rng.normal(size=(50 + 7 * t, w)).astype(np.float32))
+              for t, w in enumerate(widths)]
+    tab_of = [t for t in range(len(widths))] + [-1, 0]
+    nids = np.stack([rng.integers(0, tables[max(t, 0)].shape[0], n * fanout)
+                     for t in tab_of]).astype(np.int32)
+    mask = rng.random(nids.shape) < 0.8
+    mask[len(widths)] = False  # the padding slot's mask, as staged
+    d_pad = max(widths)
+
+    want = _take_oracle(tables, tab_of, nids, mask, fanout, f_k, d_pad)
+    got = np.asarray(gather_leaf(
+        [tables[t] if t >= 0 else None for t in tab_of], jnp.asarray(nids),
+        jnp.asarray(mask), fanout=fanout, f_k=f_k, d_pad=d_pad))
+    assert got.shape == want.shape and got.dtype == np.float32
+    assert np.array_equal(got, want)
+    keep = mask.reshape(len(tab_of), n, fanout)
+    assert not got[:, :, :fanout][~keep].any()
+    assert not got[:, :, fanout:].any() and not got[len(widths)].any()
 
 
 # --------------------------------------------------------------------------
@@ -130,6 +193,64 @@ def test_fit_losses_equal_on_both_paths(learnable, monkeypatch,
     assert resident.losses == host.losses
     for t in resident.engine.learnable_types:
         assert np.array_equal(resident.engine.table(t), host.engine.table(t)), t
+
+
+@pytest.mark.parametrize("model", ["rgat", "hgt"])
+def test_padded_leaf_layout_trains_as_the_host_path(model, monkeypatch,
+                                                     device_gather_on_cpu):
+    """The attention kernels (interpret mode) pad the fanout to whole
+    sublane tiles; the resident step gathers the leaf rows at that padded
+    fanout, and two steps train the same losses as the host path, whose
+    kernels pad the staged rows themselves."""
+    from repro.kernels.stacked_relation_agg import fanout_tile
+
+    cfg = _cfg().updated(model=dict(model=model, num_heads=2),
+                         kernels=dict(interpret=True))
+    resident = _session(cfg)
+    plan = resident.plan.plan
+    assert fanout_tile(plan.module, resident.config.kernels) == 8
+    assert plan.levels[-1].fanout == 2
+    resident.fit(2)
+
+    host = _session(cfg)
+    monkeypatch.setattr(type(host.executor), "_resident",
+                        lambda self, sess, plan: None)
+    host.fit(2)
+    assert resident.losses == host.losses
+
+
+def test_feature_grads_reach_the_tables_as_staged(monkeypatch,
+                                                  device_gather_on_cpu):
+    """On the learnable path the step's gradient of the 4-D ``hfeat{k}``
+    reaches ``apply_feature_grads`` as ``[P*rb, n_d, d_pad]``, fanout
+    padding cut, as host staging's would."""
+    from repro.api import executors
+    from repro.core import raf_spmd
+
+    seen = []
+    apply = executors.apply_feature_grads
+
+    def recording(engine, plan, batch, gf):
+        seen.append({key: v.shape for key, v in gf.items()})
+        apply(engine, plan, batch, gf)
+
+    monkeypatch.setattr(executors, "apply_feature_grads", recording)
+    sess = _session(_cfg(learnable=True))
+    sess.step()
+    plan = sess.plan.plan
+    k, lp = plan.spec.num_layers, plan.levels[-1]
+    n_d = sess._batch_for_step(0).levels[-1].nids.shape[1]
+    assert seen and seen[0][f"hfeat{k}"] == (
+        plan.num_shards * lp.rb, n_d, plan.d_pad)
+
+    # a leaf gathered at a padded fanout (the attention kernels' tile):
+    # fanout 5 laid out at 8
+    from types import SimpleNamespace as NS
+
+    g = jnp.arange(2 * 3 * 8 * 4, dtype=jnp.float32).reshape(2, 3, 8, 4)
+    padded = NS(spec=NS(num_layers=2), levels=[None, NS(fanout=5)])
+    cut = raf_spmd.feat_grads_as_staged(padded, {"hfeat2": g})["hfeat2"]
+    assert np.array_equal(cut, g[:, :, :5].reshape(2, 15, 4))
 
 
 def test_cpu_backend_stays_on_the_host_path():

@@ -14,6 +14,9 @@ hidden and 8 heads at both levels of the ogbn-mag cell.  ``kernel_choice`` picks
 compiled kernels only when the backend is a TPU, so each test steers it by
 reporting ``"tpu"`` from ``jax.default_backend``.
 
+The resident train step of both ogbn-mag cells compiles whole at the
+cells' shapes, its leaf rows taken straight into the aggregation's layout.
+
 Each kernel's ``pallas_call`` carries a ``name``, which the compiled HLO
 gives its custom call and a profiler trace its device operation; the
 benchmark's roofline readers find kernels by it, so the tests pin it.
@@ -208,3 +211,85 @@ def test_gather_rows_compiles_for_v5e(d, one_chip, on_tpu):
         return gather_rows_cfg(table, idx, KernelOptions())
 
     assert set(_compile(fetch, one_chip, table, idx)) == {"gather_rows_pallas"}
+
+
+# the ogbn-mag cells' deployment: OGB's node counts, 128-d rows on every
+# type, batch 1024, fanouts 25, 20, four meta-partitions folded onto 1x1
+MAG_ROWS = {"paper": 736_389, "author": 1_134_649, "institution": 8_740,
+            "field_of_study": 59_965}
+MAG_WIDTHS = {"rgcn": (64, 4), "hgt": (256, 8)}
+_BIG_OP = re.compile(r"\s*(?:ROOT )?%(\S+) = f32\[([\d,]*)\]\{[^}]*\} (\S+?)\(")
+
+
+@pytest.mark.parametrize("model", ["rgcn", "hgt"])
+def test_resident_step_takes_leaf_rows_in_the_aggregation_layout_for_v5e(
+        model, topo, on_tpu):
+    """The whole resident train step at the cells' shapes (level 2: 6
+    slots of 25,600 parents with 20 neighbors each): the leaf rows are
+    taken straight into the layout the aggregation reads
+    (``raf_spmd.gather_leaf``): at hgt's padded fanout no pad, copy or
+    relayout of a slot's leaf rows is left in the compiled step, only the
+    stacking of the slots' gathers (one dynamic-update-slice per slot)."""
+    import numpy as np
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+
+    from repro.core import raf_spmd
+    from repro.core.hgnn import HGNNConfig, init_hgnn_params
+    from repro.core.meta_partition import meta_partition
+    from repro.core.raf import assign_branches
+    from repro.graph.sampler import SampleSpec
+    from repro.graph.synthetic import ogbn_mag_like
+    from repro.optim.adam import AdamConfig, adam_init
+
+    g = ogbn_mag_like(scale=0.002)  # the schema; shapes below are OGB's
+    mp = meta_partition(g, 4, num_layers=2)
+    spec = SampleSpec.from_metatree(mp.metatree, [25, 20])
+    hidden, heads = MAG_WIDTHS[model]
+    cfg = HGNNConfig(model=model, hidden=hidden, num_heads=heads,
+                     num_layers=2, num_classes=349, learnable_dim=128)
+    plan = raf_spmd.build_plan(spec, assign_branches(spec, mp).fold(1, spec),
+                               cfg, {"paper": 128})
+    rb1, rb2 = (lp.rb for lp in plan.levels)
+    assert rb2 == 6
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=topo.devices[:1])
+    step = raf_spmd.make_train_step(plan, mesh, AdamConfig(lr=1e-3),
+                                    kernels=KernelOptions())
+    stacks = raf_spmd.stack_params_from_dict(
+        plan, init_hgnn_params(jax.random.PRNGKey(0), cfg, spec, {"paper": 128}))
+
+    def sds(x, pspec=P()):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                    sharding=NamedSharding(mesh, pspec))
+
+    specs = raf_spmd._array_specs(plan, ("data",), "model")
+    n1 = BATCH * 25
+    n2 = n1 * 20
+    shapes = {"seeds": (BATCH,), "labels": (BATCH,), "qnid1": (rb1, BATCH),
+              "mask1": (rb1, n1), "qnid2": (rb2, n1), "mask2": (rb2, n2),
+              "hnid2": (rb2, n2)}
+    arrays = {k: sds(jax.ShapeDtypeStruct(v, bool if "mask" in k else jnp.int32),
+                     specs[k]) for k, v in shapes.items()}
+    tables = {t: sds(jax.ShapeDtypeStruct((r, 128), jnp.float32))
+              for t, r in MAG_ROWS.items()}
+    text = step.lower(
+        jax.tree.map(sds, stacks, raf_spmd._stack_specs(plan),
+                     is_leaf=lambda x: isinstance(x, jnp.ndarray)),
+        jax.tree.map(sds, adam_init(stacks)), arrays, tables,
+    ).compile().as_text()
+
+    # the entry computation's ops as large as one slot's leaf rows
+    entry = text[text.index("ENTRY"):]
+    entry = entry[:entry.index("\n}\n")]
+    big = []
+    for line in entry.splitlines():
+        m = _BIG_OP.match(line)
+        if m and np.prod([int(x) for x in m.group(2).split(",") if x]) >= n2 * 128:
+            big.append((m.group(1), m.group(3)))
+    ops = Counter(op for _, op in big)
+    assert ops["pad"] == ops["copy"] == ops["transpose"] == 0, big
+    # fanout 20 fills no whole 8-row tiles: rgcn's mean-linear reads it
+    # unpadded, so each slot's rows are relaid once, as at the parent
+    assert ops["reshape"] == {"rgcn": rb2, "hgt": 0}[model], big
+    assert sum("dynamic-update-slice" in name for name, _ in big) == rb2, big
